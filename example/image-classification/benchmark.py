@@ -74,7 +74,7 @@ def run_cell(cell, args):
                 "wall_s": round(time.time() - t0, 1), "error": err}
     except subprocess.TimeoutExpired as e:
         # durable partial: speeds already printed before the timeout
-        # still count (the chip_window._run pattern)
+        # still count
         partial = e.stdout.decode("utf-8", "replace") \
             if isinstance(e.stdout, bytes) else (e.stdout or "")
         img_s, _ = scrape(partial)

@@ -1,7 +1,7 @@
 """Reconciliation probe: re-run the ROUND-1 bench configuration on the
 current stack (VERDICT r4 weak #7).
 
-BENCH_r01.json recorded 1834.78 img/s; round 4's best product-path
+BENCH_r01.json (git show 58f48c3:BENCH_r01.json) recorded 1834.78 img/s; round 4's best product-path
 number is 1577.63 (-14%).  The r01 bench (commit f8fc918) measured a
 THINNER path than today's product bench:
 
@@ -96,7 +96,8 @@ def main():
     out = {"metric": "resnet50_r01_config", "value": round(img_s, 2),
            "unit": "img/s", "r01_value": 1834.78,
            "vs_r01": round(img_s / 1834.78, 3)}
-    out.update(mfu(img_s))
+    if jax.devices()[0].platform == "tpu":  # a CPU run has no MFU
+        out.update(mfu(img_s))
     print(json.dumps(out))
 
 

@@ -7,7 +7,8 @@ XLA cost analyses (flops / transcendentals / bytes accessed) of
   raw  — experiments/layout_probe.py's hand-rolled train step (the
          measured on-chip ceiling), same layout/precision config
 
-Window-1 on-chip data (BENCH_WINDOW_r04.json vs LAYOUT_r04.json):
+Round-4 on-chip data (git show 58f48c3:BENCH_WINDOW_r04.json vs
+58f48c3:LAYOUT_r04.json):
 fw 1577 img/s vs raw-NCHW 1860 — a ~25 ms/step gap at BS=256, of which
 the dispatch probe attributed only ~4-5 ms to program-boundary costs.
 If fw flops ≈ raw flops the rest is per-op lowering quality; a flops

@@ -1,16 +1,16 @@
 """XLA flag sweep over the raw-JAX ResNet-50 step (VERDICT r4 #1b).
 
 Each configuration runs experiments/layout_probe.py in a SUBPROCESS
-(XLA_FLAGS must be set before backend init) under a watchdog, in the
+(XLA_FLAGS must be set before backend init) with a timeout, in the
 winning layout (NHWC bf16 by default).  The list is deliberately short
-— window minutes are the scarce resource — and centers on the two
+— chip minutes are the scarce resource — and centers on the two
 public knobs that move single-chip conv throughput:
 
   - latency-hiding scheduler (overlaps DMA with compute)
   - scoped VMEM limit (bigger fusion working sets)
 
-Prints one line per config + a winner line; chip_window captures the
-output as FLAGSWEEP_<tag>.txt.
+Prints one line per config + a winner line.  The parent never imports
+JAX: each child in turn is the one process that holds the chip.
 """
 import os
 import re

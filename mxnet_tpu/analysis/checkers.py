@@ -506,7 +506,7 @@ class AtomicWriteChecker:
 # itself is included so a PARTIAL scan (one file) never turns every
 # documented variable into a "stale row".  Paths are repo-relative.
 _ENV_EXTRA_ROOTS = ("mxnet_tpu", "src", "tools", "bench.py", "benchmark",
-                    "watchdog_util.py", "__graft_entry__.py",
+                    "chip_smoke.py", "__graft_entry__.py",
                     "experiments", "tests", "tests_tpu", "example")
 _ENV_DOC = os.path.join("docs", "env_var.md")
 
@@ -786,7 +786,7 @@ class MetricsHygieneChecker:
             # run-journal / goodput-ledger names (ISSUE 16): every
             # distinct journal.emit event name is a grep key operators
             # and the offline reporter enumerate, and every
-            # goodput.attribute reason is a row in the badput taxonomy
+            # goodput.attribute reason is a row in the badput class list
             # + a mxnet_badput_seconds_total label — the same
             # unbounded-cardinality class as phase names.  `emit` and
             # `attribute` are too generic for any-receiver matching,

@@ -77,15 +77,11 @@ def reset_cache() -> None:
 
 
 def decisions_dir() -> Optional[str]:
-    """Where decisions persist: ``MXNET_AUTOTUNE_DIR``, else an
-    ``autotune-decisions/`` directory next to the persistent compile
-    cache (``MXNET_COMPILE_CACHE_DIR``).  None disables persistence —
-    the tuner still runs, its answer just dies with the process."""
-    d = os.environ.get("MXNET_AUTOTUNE_DIR")
-    if d:
-        return d
-    c = os.environ.get("MXNET_COMPILE_CACHE_DIR")
-    return os.path.join(c, "autotune-decisions") if c else None
+    """Where decisions persist: ``MXNET_AUTOTUNE_DIR``.  Unset,
+    persistence is off — the tuner still runs, its answer just dies
+    with the process.  (Never derived from the compile cache, which
+    entry points always have: a stale decision must not arm itself.)"""
+    return os.environ.get("MXNET_AUTOTUNE_DIR") or None
 
 
 def _platform() -> str:

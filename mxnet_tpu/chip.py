@@ -1,80 +1,62 @@
-"""Chip identification and MFU accounting (VERDICT r4 #1).
+"""Chip identification and MFU accounting.
 
-The north-star target (BASELINE.md / SURVEY.md §6) is expressed as MFU
-— model FLOPs utilisation — not img/s, so the product bench must report
-it first-class.  This module is the one place that knows (a) how to map
-a PJRT `device_kind` string to the chip's dense-bf16 peak FLOPs and
-(b) the model-FLOPs cost of the flagship workload.
-
-Peak numbers are the published per-chip dense bf16 matmul peaks
-(TFLOP/s).  `device_kind` strings vary across PJRT versions ("TPU v4",
-"TPU v5 lite", "TPU v5e", "TPU v5p", "TPU v6 lite", ...), so matching
-is fuzzy on the version token.  Unknown chips return None rather than a
-guess — an MFU computed against the wrong peak is worse than no MFU —
-but the bench then reports MFU against the two plausible classes so the
-artifact is still interpretable (the r4 judge had to do exactly this
-arithmetic by hand: "~20% v5e-class, ~8.5% v5p-class").
+The one peaks table of the repo, keyed by the exact PJRT `device_kind`
+string (`jax.devices()[0].device_kind`), each row with its source.  A
+device that is not in the table is an error, not a default: an MFU
+computed against a guessed peak is worse than no MFU.  Add a row (with
+its source) when the system is brought up on another chip.
+`observability/introspect.peak_flops` reads this table too.
 """
 from __future__ import annotations
 
-# dense bf16 peak, TFLOP/s per chip (all cores)
-_PEAK_TFLOPS = [
-    # (match tokens, peak) — first match wins; order newest-first so
-    # "v5p" matches before the bare "v5" fallback
-    (("v6e", "v6 lite", "trillium"), 918.0),
-    (("v6",), 918.0),
-    (("v5p",), 459.0),
-    (("v5e", "v5 lite", "v5litepod"), 197.0),
-    (("v5",), 459.0),
-    (("v4",), 275.0),
-    (("v3",), 123.0),
-    (("v2",), 46.0),
-]
+from typing import NamedTuple, Optional
+
+from .base import MXNetError
+
+
+class Peaks(NamedTuple):
+    """Published per-chip peaks."""
+    bf16_flops: float        # dense bf16 matmul, FLOP/s
+    hbm_bytes_per_s: float   # HBM bandwidth, bytes/s
+    hbm_bytes: float         # HBM capacity, bytes
+    source: str
+
+
+PEAKS = {
+    "TPU v5 lite": Peaks(197e12, 819e9, 16e9,
+                         'Google Cloud documentation, "TPU v5e"'),
+}
 
 # Model FLOPs per trained image, ResNet-50 v1 @ 224^2: 4.1 GMAC forward
 # = 8.2 GFLOP; backward ~= 2x forward; 24.6 GFLOP/img for fwd+bwd.
-# (Same constant the layout probe used, experiments/layout_probe.py:168.)
 RESNET50_TRAIN_FLOPS_PER_IMG = 24.6e9
 RESNET50_INFER_FLOPS_PER_IMG = 8.2e9
 
 
 def device_kind() -> str:
-    """The PJRT device-kind string of device 0 ('' if no backend)."""
+    """`device_kind` of device 0, as JAX reports it."""
+    import jax
+    return jax.devices()[0].device_kind
+
+
+def peaks(kind: Optional[str] = None) -> Peaks:
+    """The table row for `kind` (default: device 0); raises MXNetError
+    for a device that is not in the table."""
+    k = device_kind() if kind is None else kind
     try:
-        import jax
-        d = jax.devices()[0]
-        return str(getattr(d, "device_kind", "") or d.platform)
-    except Exception:  # noqa: BLE001 — probing must never raise
-        return ""
-
-
-def peak_bf16_tflops(kind: str | None = None) -> float | None:
-    """Dense bf16 peak TFLOP/s for a device-kind string, or None."""
-    k = (kind if kind is not None else device_kind()).lower()
-    if not k:
-        return None
-    for tokens, peak in _PEAK_TFLOPS:
-        if any(t in k for t in tokens):
-            return peak
-    return None
+        return PEAKS[k]
+    except KeyError:
+        raise MXNetError(
+            f"no published peaks for device_kind {k!r} in "
+            f"mxnet_tpu/chip.py (known: {sorted(PEAKS)}); add a row "
+            "with its source rather than guessing") from None
 
 
 def mfu(img_per_s: float, flops_per_img: float = RESNET50_TRAIN_FLOPS_PER_IMG,
-        kind: str | None = None) -> dict:
-    """MFU report for a measured throughput.
-
-    Returns {"chip": kind, "peak_bf16_tflops": P|None, "mfu": frac|None}
-    plus, when the chip is unrecognised, "mfu_if_v5e"/"mfu_if_v5p" so a
-    window artifact is interpretable either way.
-    """
-    k = kind if kind is not None else device_kind()
-    peak = peak_bf16_tflops(k)
-    used = img_per_s * flops_per_img
-    out: dict = {"chip": k, "peak_bf16_tflops": peak}
-    if peak:
-        out["mfu"] = round(used / (peak * 1e12), 4)
-    else:
-        out["mfu"] = None
-        out["mfu_if_v5e"] = round(used / (197.0 * 1e12), 4)
-        out["mfu_if_v5p"] = round(used / (459.0 * 1e12), 4)
-    return out
+        kind: Optional[str] = None) -> dict:
+    """{"chip", "peak_bf16_tflops", "mfu"} for a measured throughput on
+    a chip of `kind` (default: device 0).  Raises on an unknown kind."""
+    k = device_kind() if kind is None else kind
+    peak = peaks(k).bf16_flops
+    return {"chip": k, "peak_bf16_tflops": peak / 1e12,
+            "mfu": round(img_per_s * flops_per_img / peak, 4)}

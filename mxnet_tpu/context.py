@@ -41,7 +41,8 @@ class Context:
         """Resolve to a concrete jax.Device.
 
         'tpu'/'gpu' both mean "accelerator i" — on a TPU host, mx.gpu(0) from
-        a reference script lands on TPU chip 0 (no GPU in the loop).
+        a reference script lands on TPU chip 0 (no GPU in the loop);
+        without an accelerator they raise rather than run on the host.
         'cpu'/'cpu_pinned' resolve to host CPU devices.
         """
         if self.device_type in ("cpu", "cpu_pinned"):
@@ -49,9 +50,10 @@ class Context:
             return devs[min(self.device_id, len(devs) - 1)]
         accels = _accelerators()
         if not accels:
-            # graceful CPU fallback, mirroring mxnet's CPU-only builds
-            devs = _local(None)
-            return devs[min(self.device_id, len(devs) - 1)]
+            raise MXNetError(
+                f"{self}: no accelerator visible to JAX (devices: "
+                f"{[d.platform for d in _local(None)]}); use mx.cpu() "
+                "to run on the host")
         if self.device_id >= len(accels):
             raise MXNetError(
                 f"{self} out of range: {len(accels)} accelerator(s) visible")
@@ -117,13 +119,20 @@ def cpu_pinned(device_id: int = 0) -> Context:
     return Context("cpu_pinned", device_id)
 
 
+def _accelerator(device_type: str, device_id: int) -> Context:
+    ctx = Context(device_type, device_id)
+    ctx.jax_device()  # no such device: raise here, not at the first array
+    return ctx
+
+
 def gpu(device_id: int = 0) -> Context:
-    return Context("gpu", device_id)
+    return _accelerator("gpu", device_id)
 
 
 def tpu(device_id: int = 0) -> Context:
-    """First-class TPU context (north star: BASELINE.json)."""
-    return Context("tpu", device_id)
+    """First-class TPU context (north star: BASELINE.json).  Raises
+    MXNetError when JAX sees no accelerator: there is no CPU fallback."""
+    return _accelerator("tpu", device_id)
 
 
 def num_gpus() -> int:

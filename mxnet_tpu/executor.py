@@ -25,7 +25,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
-from .base import MXNetError, getenv, maybe_enable_compile_cache
+from .base import MXNetError, enable_compile_cache, getenv
 from .context import Context
 from .faultinject import fire as _fi_fire
 from .ndarray import NDArray
@@ -52,10 +52,10 @@ class Executor:
                  aux_states: Dict[str, NDArray], group2ctx=None,
                  shared_exec: Optional["Executor"] = None,
                  mesh=None, data_shard_args=()):
-        # persistent XLA compile cache (MXNET_COMPILE_CACHE_DIR): wired
-        # at bind time so training executors share the on-disk cache the
-        # serving path uses — a restart skips recompiles in both worlds
-        maybe_enable_compile_cache()
+        # persistent XLA compile cache (JAX_COMPILATION_CACHE_DIR): the
+        # persist-everything thresholds go on at bind time so training
+        # executors share the on-disk cache the serving path uses
+        enable_compile_cache()
         self._symbol = symbol
         self._ctx = ctx if isinstance(ctx, Context) else Context(ctx)
         self.arg_dict = dict(args)

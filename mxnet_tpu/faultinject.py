@@ -90,8 +90,8 @@ docs/serving_resilience.md):
                           (``WholeStepCompiler._dispatch``, the fused
                           update) — a ``raise`` rule defaults to the
                           typed ``DeviceUnavailableError`` (classified
-                          transient), modeling a dropped TPU tunnel with
-                          no real device loss
+                          transient), modeling a lost device with no
+                          real device loss
   ==================================================================
 
 Configuration is API- or env-driven::
@@ -155,7 +155,7 @@ _EXC_TYPES: Dict[str, type] = {
     "IOError": IOError,
     "RuntimeError": RuntimeError,
     "TimeoutError": TimeoutError,
-    # the training-resilience taxonomy (mxnet_tpu.resilience): a
+    # the training-resilience classes (mxnet_tpu.resilience): a
     # transient device loss and a corrupt input record, so a chaos plan
     # can drive the supervisor retry and the data skip budget by name
     "DeviceUnavailableError": DeviceUnavailableError,

@@ -138,7 +138,7 @@ class Parameter:
             # ``_memory_tag`` (default "param") lets a subsystem claim
             # its own ledger row: ShardedEmbedding stamps "embed_shards"
             # so ensure_headroom / the registry cost model see table
-            # bytes as their own class (docs/memory.md taxonomy)
+            # bytes as their own class (docs/memory.md tag classes)
             with _memory_scope(getattr(self, "_memory_tag", "param")):
                 data = nd.zeros(self.shape, dtype=self.dtype, ctx=ctx[0])
                 initializer.create(default_init)(

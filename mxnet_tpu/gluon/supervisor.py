@@ -12,7 +12,7 @@ TF paper treats checkpoint-mediated recovery from worker failure as a
 first-class requirement, arxiv 1605.08695 §4.4):
 
   * **typed fault classification** — every step failure routes through
-    ``resilience.classify``: *transient* (UNAVAILABLE tunnel, RPC
+    ``resilience.classify``: *transient* (UNAVAILABLE device, RPC
     deadline, injected chaos) retries; *oom*
     (``DeviceMemoryError``, already post-mortemed by the PR 9 ledger)
     and *permanent* (trace/user errors) propagate immediately.

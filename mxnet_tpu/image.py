@@ -607,7 +607,7 @@ class ImageIter(_io.DataIter):
         (resize/crop); returns contiguous uint8 HWC.  The float work
         (mirror select, cast, mean/std, HWC->CHW) runs as ONE fused XLA
         program per batch (`_dev_aug_fn`), so the host pays JPEG decode
-        only and the device upload is uint8 — 4x less PCIe/tunnel bytes
+        only and the device upload is uint8 — 4x fewer host->device bytes
         than the float32 host path."""
         data = imdecode_np(s)
         for aug in self.auglist:

@@ -1,11 +1,11 @@
 """Goodput ledger: classify every second of a run into a fixed badput
-taxonomy (ISSUE 16).
+class list (ISSUE 16).
 
 PRs 1/8/9/13 can see inside one step — phases, HBM, flops, MFU — but
 none of them answers the operator's fleet question: *what fraction of
 this run's wall-clock was useful training*, and where did the rest go?
 This module keeps that account.  Every second of a training or serving
-run is attributed to exactly one class of a small, fixed taxonomy:
+run is attributed to exactly one of a small, fixed list of classes:
 
   ==================  =====================================================
   ``compute``         useful work — flight's ``trainer_step`` /
@@ -67,7 +67,7 @@ __all__ = ["ENABLED", "CLASSES", "observe_span", "attribute",
 #: kill-switch (docs/env_var.md); parsed once — the gate contract
 ENABLED: bool = bool(getenv("MXNET_GOODPUT", True))
 
-#: the complete, closed taxonomy — ``attribute`` folds anything else
+#: the complete, closed class list — ``attribute`` folds anything else
 #: into ``unattributed`` (warn-once) instead of growing the ledger
 CLASSES = ("compute", "data_wait", "checkpoint_block", "retry_replay",
            "rewind", "recompile", "eviction_churn", "stall", "shed",
@@ -77,7 +77,7 @@ CLASSES = ("compute", "data_wait", "checkpoint_block", "retry_replay",
 #: (compute is goodput; unattributed is derived, not accumulated)
 _BADPUT_CLASSES = frozenset(CLASSES) - {"compute", "unattributed"}
 
-#: flight span name -> taxonomy class.  Only TOP-LEVEL unit-of-work
+#: flight span name -> badput class.  Only TOP-LEVEL unit-of-work
 #: spans appear here — nested phases (h2d/allreduce/fused_update inside
 #: trainer_step) must NOT, or their seconds would double-count.
 _SPAN_CLASS: Dict[str, str] = {
@@ -171,10 +171,10 @@ def observe_span(name: str, dur_s: float) -> None:
 
 
 def attribute(reason: str, seconds: float) -> None:
-    """Book ``seconds`` of wall-clock against taxonomy class ``reason``
+    """Book ``seconds`` of wall-clock against badput class ``reason``
     (discrete badput events: stall timeouts, shed requests, measured
     compile time).  Unknown reasons fold into ``unattributed`` with a
-    one-shot warning — the taxonomy is closed by design, and the
+    one-shot warning — the class list is closed by design, and the
     graft-lint metrics-hygiene rule flags dynamically built reason
     strings at the call site."""
     if not ENABLED:
@@ -183,7 +183,7 @@ def attribute(reason: str, seconds: float) -> None:
         if reason not in _warned_unknown:
             _warned_unknown.add(reason)
             log.warning("goodput.attribute: unknown class %r folded "
-                        "into 'unattributed' (taxonomy: %s)",
+                        "into 'unattributed' (classes: %s)",
                         reason, ", ".join(CLASSES))
         reason = "unattributed"
     if seconds < 0.0:
@@ -206,7 +206,7 @@ def attribute(reason: str, seconds: float) -> None:
 
 
 def note_event(reason: str) -> None:
-    """Count a taxonomy event whose duration is unknown (training
+    """Count a badput-class event whose duration is unknown (training
     ``note_program`` recompiles: the compile happened inside jax, we
     only see the notification).  Shows up in ``report()['events']``
     without inventing seconds."""

@@ -33,14 +33,14 @@ TF's per-op attribution + utilization telemetry, arxiv 1605.08695):
     ``mxnet_step_flops_per_s``, ``mxnet_step_bytes_per_s``, and
     ``mxnet_step_arithmetic_intensity`` gauges (computed at export
     only), plus an ``mxnet_flops_per_s`` counter track in the Perfetto
-    export.  Peak flops come from a per-platform table;
-    ``MXNET_PEAK_FLOPS`` overrides (set it for meaningful MFU — the
-    CPU default is a nominal placeholder).
+    export.  Peak flops come from the one peaks table in
+    ``mxnet_tpu/chip.py`` (keyed by ``device_kind``; an unknown TPU
+    raises); ``MXNET_PEAK_FLOPS`` overrides.  On a CPU there is no
+    peak, so flops/s and bytes/s are reported and MFU is not.
   * **perf-regression sentinel** — per (model signature, platform)
     baselines of {step-time p50, dispatches/step, flops, HBM peak}
-    persist under ``MXNET_PERF_BASELINE_DIR`` (default: a
-    ``perf-baselines/`` sibling inside ``MXNET_COMPILE_CACHE_DIR``,
-    like the compile cache itself).  At runtime the warmed EWMA is
+    persist under ``MXNET_PERF_BASELINE_DIR`` (unset: the sentinel is
+    disarmed).  At runtime the warmed EWMA is
     compared against the stored p50; drift past ``REGRESSION_FACTOR``
     fires ONE loud warning + ``mxnet_perf_regressions_total``
     increment (rate-limited) and flips the ``perf_regression``
@@ -216,7 +216,7 @@ def _hlo_of(compiled, lowered) -> Tuple[Optional[str], bool]:
     """Optimized HLO text, size-capped.  Lazy by flag: nothing is ever
     rendered unless MXNET_INTROSPECT_HLO=1 — and only then does a
     jit-called program pay the extra lowered.compile() (which the
-    persistent compile cache absorbs when MXNET_COMPILE_CACHE_DIR is
+    persistent compile cache absorbs when JAX_COMPILATION_CACHE_DIR is
     set)."""
     if not HLO:
         return None, False
@@ -534,37 +534,19 @@ def attributed_pct(program: str = "whole_step") -> float:
 
 
 # -- MFU / roofline ----------------------------------------------------------
-# Nominal dense peak flops by device kind (f32/bf16 MXU peaks for TPU
-# generations; the CPU entry is a PLACEHOLDER so the math runs — set
-# MXNET_PEAK_FLOPS for a meaningful MFU on your part)
-_PEAK_TABLE = (
-    ("v5p", 459e12),
-    ("v5e", 197e12),
-    ("v5", 197e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
-_CPU_NOMINAL_PEAK = 1e11
-
-
-def peak_flops() -> Tuple[float, str]:
-    """(peak flops/s, source): MXNET_PEAK_FLOPS override > device-kind
-    table > nominal CPU placeholder."""
+def peak_flops() -> Tuple[Optional[float], str]:
+    """(peak flops/s, source): the MXNET_PEAK_FLOPS override, else the
+    chip.py peaks table for this TPU's ``device_kind`` (an unknown kind
+    raises), else ``(None, "none:<platform>")`` — a host CPU has no
+    published peak and MFU against a made-up one would mean nothing."""
     override = float(getenv("MXNET_PEAK_FLOPS", 0.0))
     if override > 0:
         return override, "MXNET_PEAK_FLOPS"
-    try:
-        dev = jax.local_devices()[0]
-        kind = (getattr(dev, "device_kind", "") or "").lower()
-        if dev.platform == "tpu":
-            for tag, peak in _PEAK_TABLE:
-                if tag in kind:
-                    return peak, f"table:{tag}"
-            return 123e12, "table:tpu-default"
-    except Exception:  # noqa: BLE001
-        pass
-    return _CPU_NOMINAL_PEAK, "nominal-cpu"
+    dev = jax.local_devices()[0]
+    if dev.platform != "tpu":
+        return None, f"none:{dev.platform}"
+    from .. import chip as _chip
+    return _chip.peaks(dev.device_kind).bf16_flops, f"chip:{dev.device_kind}"
 
 
 def step_flops() -> Tuple[Optional[float], Optional[float], Optional[str]]:
@@ -591,7 +573,9 @@ def mfu(step_time_s: Optional[float] = None, flops: Optional[float] = None,
     time ÷ platform peak.  Every input is overridable (the bench rider
     passes its own measured step time); defaults come from the noted
     programs + the flight recorder's warmed EWMA.  Returns ``{}`` when
-    either the flops or the step time is not yet measurable."""
+    either the flops or the step time is not yet measurable; where the
+    platform has no peak (CPU) the ``mfu``/``peak_flops`` keys are
+    absent and the achieved rates remain."""
     phase = None
     if flops is None:
         flops, b, phase = step_flops()
@@ -610,11 +594,12 @@ def mfu(step_time_s: Optional[float] = None, flops: Optional[float] = None,
         "flops_per_step": flops,
         "step_time_ms": round(step_time_s * 1e3, 4),
         "flops_per_s": fps,
-        "peak_flops": pk,
         "peak_source": src,
-        "mfu": round(fps / pk, 6),
-        "mfu_pct": round(100.0 * fps / pk, 4),
     }
+    if pk:
+        out["peak_flops"] = pk
+        out["mfu"] = round(fps / pk, 6)
+        out["mfu_pct"] = round(100.0 * fps / pk, 4)
     if bytes_per_step:
         out["bytes_per_step"] = bytes_per_step
         out["bytes_per_s"] = bytes_per_step / step_time_s
@@ -655,14 +640,10 @@ _sentinel: Dict[str, dict] = {}
 
 
 def baseline_dir() -> Optional[str]:
-    """Where baselines persist: ``MXNET_PERF_BASELINE_DIR``, else a
-    ``perf-baselines/`` directory next to the persistent compile cache
-    (``MXNET_COMPILE_CACHE_DIR``).  None disarms the sentinel."""
-    d = os.environ.get("MXNET_PERF_BASELINE_DIR")
-    if d:
-        return d
-    c = os.environ.get("MXNET_COMPILE_CACHE_DIR")
-    return os.path.join(c, "perf-baselines") if c else None
+    """Where baselines persist: ``MXNET_PERF_BASELINE_DIR``.  Unset,
+    the sentinel is disarmed — it never arms itself off the compile
+    cache, which entry points always have."""
+    return os.environ.get("MXNET_PERF_BASELINE_DIR") or None
 
 
 def _platform() -> str:

@@ -530,7 +530,7 @@ SERVE_READMITS = Counter(
     "Readmissions of evicted serving state, by kind (model = weights "
     "re-uploaded from the host payload, bucket = an evicted bucket's "
     "executable rebuilt — a persistent-compile-cache hit when "
-    "MXNET_COMPILE_CACHE_DIR is wired, so it never counts against the "
+    "JAX_COMPILATION_CACHE_DIR is set, so it never counts against the "
     "stay-flat SERVE_COMPILES contract).  readmissions/evictions is "
     "the churn ratio: high means the budget is too tight for the "
     "working set")
@@ -748,7 +748,7 @@ BADPUT_SECONDS = Counter(
     "mxnet_badput_seconds_total",
     "Wall-clock seconds lost to each badput class, by reason "
     "(data_wait / checkpoint_block / retry_replay / rewind / recompile "
-    "/ eviction_churn / stall / shed — the closed goodput taxonomy; "
+    "/ eviction_churn / stall / shed — the closed goodput class list; "
     "docs/goodput.md)")
 SLO_BURN = Counter(
     "mxnet_slo_burn_total",
@@ -790,8 +790,8 @@ MFU = Gauge(
     "Model flops utilization of the training step, 0..1: analytical "
     "flops/step of the captured step program(s) / the flight "
     "recorder's warmed step-time EWMA / platform peak flops "
-    "(MXNET_PEAK_FLOPS override; the CPU default peak is a nominal "
-    "placeholder).  On a GSPMD mesh a {mesh=<axis=size,...>} child "
+    "(MXNET_PEAK_FLOPS override, else the chip.py table for this "
+    "TPU's device_kind; 0 on a CPU, which has no peak).  On a GSPMD mesh a {mesh=<axis=size,...>} child "
     "carries the same value keyed by mesh shape so dashboards can "
     "group sharded vs replicated runs.  Computed at export only",
     fn=lambda: _introspect_mfu("mfu"))

@@ -92,7 +92,7 @@ def summarize_run(run_dir: str) -> dict:
     for e in entries:
         counts[e["event"]] = counts.get(e["event"], 0) + 1
     # downtime between incarnations: last entry of one process to the
-    # process_start of the next — reported beside the taxonomy (the
+    # process_start of the next — reported beside the class list (the
     # dead process could not meter its own absence)
     downtime = 0.0
     for s in starts[1:]:

@@ -39,7 +39,7 @@ def _phase_flops() -> Dict[str, float]:
 
 
 def _badput_map() -> Dict[str, str]:
-    """{span name: badput class} from the goodput ledger's taxonomy —
+    """{span name: badput class} from the goodput ledger's class list —
     the feed for the ``mxnet_badput_seconds`` counter track.
     Lazy/guarded: the exporter must never fail because of it."""
     try:

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from .compat import shard_map
+from jax import shard_map
 
 
 def _resolve(mesh, who: str) -> Mesh:

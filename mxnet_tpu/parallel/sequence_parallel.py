@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from .compat import shard_map
+from jax import shard_map
 
 from ..base import MXNetError
 

@@ -8,7 +8,7 @@ docs/serving_resilience.md are the guides):
   - `BucketedPredictor` — AOT-compiled executables per bucket
     (`jax.jit(...).lower(...).compile()`), `warmup()` for zero
     hot-path compiles, donated input buffers, persistent compile cache
-    via `MXNET_COMPILE_CACHE_DIR`;
+    via `JAX_COMPILATION_CACHE_DIR`;
   - `MicroBatcher` — dynamic micro-batching: concurrent requests
     coalesce into one covering-bucket dispatch
     (`MXNET_SERVE_MAX_WAIT_MS` / `MXNET_SERVE_MAX_BATCH`);

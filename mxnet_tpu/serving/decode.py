@@ -496,7 +496,7 @@ class DecodeEngine:
                                    time.perf_counter() - t0)
             from .. import base as _base
             readmission = (key in self._ever_compiled
-                           and _base._COMPILE_CACHE_WIRED)
+                           and _base.compile_cache_active())
             if _metrics.ENABLED:
                 if readmission:
                     _metrics.SERVE_READMITS.inc(kind="bucket")

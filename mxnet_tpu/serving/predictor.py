@@ -12,7 +12,7 @@ ahead-of-time compiled deployment modules (arxiv 1802.04799):
     pow2-derived, `MXNET_SERVE_BUCKETS` override);
   - each bucket AOT-compiled ONCE via `jax.jit(...).lower(...).compile()`
     — `warmup()` moves every compile off the request path;
-  - JAX's persistent compilation cache (`MXNET_COMPILE_CACHE_DIR`) so a
+  - JAX's persistent compilation cache (`JAX_COMPILATION_CACHE_DIR`) so a
     process restart re-loads executables from disk instead of
     recompiling;
   - requests pad on host into the bucket shape (one device transfer,
@@ -36,7 +36,7 @@ import jax
 
 from ..analysis import hot_path
 from ..analysis import sanitizer as _sanitizer
-from ..base import MXNetError, maybe_enable_compile_cache, np_dtype
+from ..base import MXNetError, enable_compile_cache, np_dtype
 from ..context import cpu
 from ..faultinject import fire as _fi_fire
 from ..ndarray import NDArray
@@ -91,7 +91,7 @@ class BucketedPredictor:
                  output_names: Optional[Sequence[str]] = None,
                  donate: bool = True, resident: bool = True):
         from ..predictor import load_param_payload, split_arg_aux
-        maybe_enable_compile_cache()
+        enable_compile_cache()
         if isinstance(symbol, Symbol):
             sym = symbol
         else:
@@ -188,7 +188,7 @@ class BucketedPredictor:
         # LRU clock per bucket (stamped at precompile and every
         # dispatch) + the set of keys EVER compiled in this process:
         # a rebuild of an evicted bucket is a readmission (a
-        # persistent-cache hit when MXNET_COMPILE_CACHE_DIR is wired),
+        # persistent-cache hit when JAX_COMPILATION_CACHE_DIR is set),
         # not an escape from the bucket set, so it must not count
         # against the stay-flat SERVE_COMPILES contract
         self._bucket_used: Dict[tuple, float] = {}
@@ -247,7 +247,7 @@ class BucketedPredictor:
     def precompile(self, key: tuple):
         """AOT-compile one bucket (idempotent).  The compile happens via
         lower().compile() so it also lands in the persistent compilation
-        cache when MXNET_COMPILE_CACHE_DIR is set."""
+        cache when JAX_COMPILATION_CACHE_DIR is set."""
         if key in self._compiled:
             return self._compiled[key]
         with self._compile_lock:
@@ -304,7 +304,7 @@ class BucketedPredictor:
                                    time.perf_counter() - _t0_compile)
             from .. import base as _base
             readmission = (key in self._ever_compiled
-                           and _base._COMPILE_CACHE_WIRED)
+                           and _base.compile_cache_active())
             if _metrics.ENABLED:
                 if readmission:
                     # rebuilding an evicted bucket with the persistent
@@ -675,7 +675,7 @@ class BucketedPredictor:
         """Re-upload the host param payload to the device and mark the
         model servable again.  Bucket executables rebuild lazily at the
         next dispatch per key — a persistent-compile-cache hit when
-        ``MXNET_COMPILE_CACHE_DIR`` is wired (counted as
+        ``JAX_COMPILATION_CACHE_DIR`` is wired (counted as
         ``mxnet_serve_readmissions_total{kind="bucket"}``, never as a
         ``SERVE_COMPILES`` escape).  Idempotent."""
         with self._compile_lock:

@@ -779,8 +779,8 @@ class ResilientServer:
         # 2. persistent compile cache: configured implies wired
         from .. import base as _base
         checks["compile_cache"] = (
-            not os.environ.get("MXNET_COMPILE_CACHE_DIR")
-            or _base._COMPILE_CACHE_WIRED)
+            not os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or _base.compile_cache_active())
         # 2b. HBM: the compiled per-bucket cost table (always detail)
         # plus the soft-budget check when MXNET_HBM_BUDGET_MB is set —
         # a replica whose tracked device bytes blew the budget must

@@ -28,7 +28,7 @@ from .symbol import Symbol, _Node, _truthy
 
 # -- whole-graph channels-last propagation (VERDICT r4 #1b) -----------------
 # Per-op boundary transposes (layout.py to_cl/from_cl inside each spatial
-# op) measured SLOWER than NCHW on-chip (LAYOUT_r04: framework NHWC 1540
+# op) measured SLOWER than NCHW on-chip (58f48c3:LAYOUT_r04: framework NHWC 1540
 # vs NCHW 1577) even though raw-JAX NHWC wins (1929 vs 1860): XLA does
 # not reliably cancel the transpose pairs across conv→BN→relu→conv
 # chains once bf16 converts/broadcasts sit between them.  This pass

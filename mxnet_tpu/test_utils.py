@@ -465,7 +465,7 @@ def get_mnist(num_train=600, num_test=100):
 def golden_model_cases():
     """name -> zero-arg builder returning (net, input NDArray).  Shared by
     tools/make_golden.py (writer), tests/test_golden_forward.py (CPU
-    gate) and tools/run_tpu_consistency.py (on-chip check)."""
+    gate) and tests_tpu/test_consistency.py (on-chip check)."""
     from . import nd as _nd
     from . import random as _random
     from . import initializer as _init

@@ -1057,7 +1057,7 @@ def test_supervisor_retry_revives_poisoned_buffers(tmp_path, monkeypatch,
         def flaky(*a, _fn=fn, **k):
             if fired["n"] == 0:
                 fired["n"] += 1
-                raise DeviceUnavailableError("injected tunnel loss")
+                raise DeviceUnavailableError("injected device loss")
             return _fn(*a, **k)
         upd._fn_cache[key] = flaky
     loss = sup.step(x, y)   # retried through snapshot restore + replay

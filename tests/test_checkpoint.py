@@ -488,8 +488,6 @@ _CHILD = """
 import os, sys, time
 sys.path.insert(0, {repo!r})
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-from __graft_entry__ import _cpu_only_guard
-_cpu_only_guard()
 import numpy as np
 import mxnet_tpu as mx
 from mxnet_tpu import checkpoint as ck

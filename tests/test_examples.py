@@ -121,118 +121,38 @@ def test_parse_log(tmp_path):
 @pytest.mark.parametrize("layout", ["NCHW", "NHWC"])
 def test_bench_product_path_smoke(layout):
     """bench.py drives Module.fit + tpu_sync kvstore + fused updates; the
-    CPU smoke config checks the whole path wires up (both internal
-    layouts — chip_window runs the TPU bench under the A/B winner) and
-    the loss-sanity assert passes."""
+    explicit CPU rehearsal checks the whole path wires up (both internal
+    layouts) and the loss-sanity assert passes.  Every record of a
+    rehearsal says so and names the device it ran on."""
     import json
     env = {**ENV, "MXT_BENCH_BATCH": "8", "MXT_BENCH_IMG": "64",
            "MXT_BENCH_BATCHES": "2", "MXT_BENCH_LR": "0.01",
            "MXNET_TPU_CONV_LAYOUT": layout}
-    proc = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
+    proc = subprocess.run([sys.executable, os.path.join(REPO, "bench.py"),
+                           "--rehearsal"],
                           env=env, capture_output=True, text=True,
                           timeout=560)
     assert proc.returncode == 0, proc.stdout + proc.stderr
-    line = [l for l in proc.stdout.splitlines() if l.startswith("{")][-1]
-    rec = json.loads(line)
+    rec = json.loads(proc.stdout.splitlines()[-1])
     assert rec["metric"] == "resnet50_train_throughput"
     assert rec["value"] > 0
-    # a clean run must not be flagged partial (watchdog/outage path)
-    assert "partial" not in rec and "error" not in rec, rec
-    # the advisory bench lock must not leak past exit (os._exit paths
-    # drop it explicitly)
-    assert not os.path.exists(os.path.join(REPO, ".bench_lock"))
+    assert rec["rehearsal"] is True and rec["platform"] == "cpu"
+    assert rec["device_kind"] and rec["device_count"] >= 1
+    assert "chip_mfu" not in rec  # a CPU has no peak to divide by
+    assert "failed" not in rec and "error" not in rec, rec
 
 
-def test_chip_window_defers_to_bench_lock(tmp_path, monkeypatch):
-    """The poller must never share the chip with the driver's official
-    bench: chip_window._run waits while .bench_lock exists, and when
-    the lock appears MID-step it kills the child and reruns the step
-    after release (the official artifact outranks diagnostics)."""
-    import importlib
-    import threading
-    import time as _t
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    cw = importlib.import_module("chip_window")
-    real_sleep = _t.sleep
-    monkeypatch.setattr(cw.time, "sleep",
-                        lambda s: real_sleep(min(s, 0.2)))
-    # isolate from the real repo-root lock (a genuine driver bench or
-    # the sibling bench smoke test must not race this test's lock)
-    lock = str(tmp_path / "bench_lock")
-    monkeypatch.setattr(cw, "BENCH_LOCK", lock)
-
-    # stale locks are ignored; fresh locks block
-    with open(lock, "w") as f:
-        f.write("1 0")
-    os.utime(lock, (_t.time() - 3000, _t.time() - 3000))
-    assert not cw._bench_lock_active()
-    os.utime(lock)
-    assert cw._bench_lock_active()
-    os.unlink(lock)
-
-    marker = tmp_path / "ran.txt"
-    summary = str(tmp_path / "S.json")
-    cw.SUMMARY["started_unix"] = _t.time()
-
-    def lock_cycle():
-        # deterministic ordering: take the lock only once attempt 1 has
-        # provably started (marker written), hold it briefly, release
-        while not marker.exists():
-            real_sleep(0.1)
-        with open(lock, "w") as f:
-            f.write("test")
-        # hold LONGER than _run's 2 s lock-check cadence (the loop now
-        # blocks in child.wait(timeout=2) between checks, which the
-        # patched time.sleep does not shorten)
-        real_sleep(3.5)
-        os.unlink(lock)
-
-    th = threading.Thread(target=lock_cycle)
-    th.start()
-    # attempt 1 sleeps forever (only preemption can end it); attempt 2
-    # sees the marker from attempt 1 and exits immediately
-    rec = cw._run(
-        "locktest",
-        [sys.executable, "-c",
-         "import os, time; p = %r; prev = os.path.exists(p); "
-         "open(p, 'a').write('x'); time.sleep(0 if prev else 3600)"
-         % str(marker)],
-        45, summary)
-    th.join()
-    assert rec["rc"] == 0, rec
-    # first attempt started, was preempted by the lock, and the step
-    # reran to completion after release
-    assert marker.read_text() == "xx", marker.read_text()
-
-
-def test_consistency_runner_artifact(tmp_path):
-    """The durable on-chip consistency runner: selftest mode over a case
-    subset must write a valid artifact with per-case status + max_err,
-    and survive a watchdog trip with the artifact intact."""
+def test_bench_refuses_cpu_without_rehearsal():
+    """bench.py cannot run on the CPU by accident: with no TPU it prints
+    its JSON line (device stamped, error named) and exits non-zero."""
     import json
-    out = tmp_path / "CONSISTENCY.json"
-    env = {**ENV, "MXT_CONSISTENCY_SELFTEST": "1"}
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools/run_tpu_consistency.py"),
-         "--out", str(out), "--only", "unary_relu,softmax,dot"],
-        env=env, capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    doc = json.loads(out.read_text())
-    assert doc["completed"] and doc["mode"] == "selftest"
-    assert doc["summary"] == {"pass": len(doc["cases"])}
-    # symbol cases carry max_err; function cases (\*_consistency, pulled
-    # in here by the "dot" substring match) are pass/fail only
-    assert all("max_err" in c for c in doc["cases"]
-               if not c["case"].endswith("_consistency"))
-    # watchdog trip: impossible budget -> hang record, artifact valid, rc 0
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools/run_tpu_consistency.py"),
-         "--out", str(out), "--only", "unary_relu", "--case-budget", "0.0"],
-        env=env, capture_output=True, text=True, timeout=120)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    doc = json.loads(out.read_text())
-    assert not doc["completed"], doc
-    assert doc["cases"][-1]["status"] == "hang", doc
+    proc = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
+                          env=ENV, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode == 1, proc.stdout + proc.stderr
+    rec = json.loads(proc.stdout.splitlines()[-1])
+    assert rec["platform"] == "cpu" and rec["value"] == 0.0
+    assert "--rehearsal" in rec["error"] and rec["phase"] == "device"
 
 
 def test_bench_io_harness():
@@ -945,8 +865,7 @@ def test_rec2idx_tool(tmp_path):
 
 
 def test_diagnose_tool():
-    out = run_example("tools/diagnose.py", "--device-timeout", "3",
-                      timeout=180)
+    out = run_example("tools/diagnose.py", timeout=180)
     for section in ("Platform Info", "Dependency Versions",
                     "MXNet-TPU Info", "Device Info"):
         assert section in out, out
@@ -957,8 +876,7 @@ def test_diagnose_tool():
     # find the package relative to itself, like the reference's)
     env = {k: v for k, v in ENV.items() if k != "PYTHONPATH"}
     proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "diagnose.py"),
-         "--device-timeout", "3"],
+        [sys.executable, os.path.join(REPO, "tools", "diagnose.py")],
         env=env, cwd="/tmp", capture_output=True, text=True, timeout=180)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "IMPORT FAILED" not in proc.stdout, proc.stdout
@@ -1158,122 +1076,6 @@ def test_decode_probe_smoke():
     assert metrics["decode_static_throughput"]["value"] > 0
     assert metrics["decode_kv_cache_throughput"]["value"] > 0
     assert metrics["decode_paths_agree"]["value"] is True
-
-
-def test_bench_fused_step_and_fallback():
-    """bench.py's fused step is off by default (slower on-chip,
-    BENCH_WINDOW_r05.json); forced on via MXT_BENCH_FUSED it must
-    complete, and an injected fused failure must fall back to the
-    standard step and still emit a clean full-run JSON (the driver's
-    one bench run can never lose its number to the fused path)."""
-    import json
-    env = {**ENV, "MXT_BENCH_BATCH": "8", "MXT_BENCH_IMG": "64",
-           "MXT_BENCH_BATCHES": "2", "MXT_BENCH_LR": "0.01",
-           "MXT_BENCH_FUSED": "1"}
-    proc = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                          env=env, capture_output=True, text=True,
-                          timeout=560)
-    rec = json.loads([l for l in proc.stdout.splitlines()
-                      if l.startswith("{")][-1])
-    assert rec["fused_step"] is True and rec["value"] > 0
-    assert "partial" not in rec, rec
-
-    # bench-level fused choice: a failure falls back to the standard step
-    proc = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                          env={**env, "MXT_BENCH_FAIL_FUSED_ONCE": "1"},
-                          capture_output=True, text=True, timeout=560)
-    rec = json.loads([l for l in proc.stdout.splitlines()
-                      if l.startswith("{")][-1])
-    assert rec["fused_step"] is False and rec["value"] > 0
-    assert "fell back" in rec.get("error", ""), rec
-    assert "partial" not in rec, rec
-
-    # PINNED path (the chip-window A/B leg): same failure must surface
-    # as a partial/error, never a silently-standard number
-    env_pin = {**env, "MXNET_FUSED_STEP": "1",
-               "MXT_BENCH_FAIL_FUSED_ONCE": "1"}
-    env_pin.pop("MXT_BENCH_FUSED")
-    proc = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                          env=env_pin, capture_output=True, text=True,
-                          timeout=560)
-    rec = json.loads([l for l in proc.stdout.splitlines()
-                      if l.startswith("{")][-1])
-    assert rec.get("partial") and "injected" in rec.get("error", ""), rec
-
-
-def test_chip_window_best_config_composition(tmp_path, monkeypatch):
-    """compose_best_env (the benchbest window step) must compose ONLY
-    measured winners: NHWC when its leg beat the default, the fastest
-    sweep batch, the flag-sweep WINNER's flags above 1% gain — and
-    return no levers when nothing beat the default."""
-    import importlib
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    cw = importlib.import_module("chip_window")
-
-    # nothing measured -> no levers
-    _, levers = cw.compose_best_env({}, {}, "t",
-                                    artifact_dir=str(tmp_path))
-    assert levers == {}
-
-    doc = {"default": {"value": 1800.0},
-           "nhwc_default": {"value": 1900.0},
-           "batch_sweep": {"384": {"value": 1950.0},
-                           "512": {"value": 1700.0}}}
-    (tmp_path / "FLAGSWEEP_t.txt").write_text(
-        "baseline  1800.0 img/s\nlatency-hiding 1890.0 img/s\n"
-        "WINNER: latency-hiding (1890.0 img/s, +5.0% vs baseline)\n")
-    best_env, levers = cw.compose_best_env(
-        {}, doc, "t", artifact_dir=str(tmp_path))
-    assert levers["MXNET_TPU_CONV_LAYOUT"] == "NHWC"
-    assert levers["MXT_BENCH_BATCH"] == "384"
-    assert "latency_hiding" in levers["XLA_FLAGS"]
-    assert best_env["MXNET_FUSED_STEP"] == "0"
-
-    # losing legs compose nothing; sub-1% sweep wins are noise
-    doc2 = {"default": {"value": 1800.0},
-            "nhwc_default": {"value": 1500.0},
-            "batch_sweep": {"512": {"value": 1400.0}}}
-    (tmp_path / "FLAGSWEEP_t.txt").write_text(
-        "WINNER: vmem-64M (1810.0 img/s, +0.5% vs baseline)\n")
-    _, levers2 = cw.compose_best_env(
-        {}, doc2, "t", artifact_dir=str(tmp_path))
-    assert levers2 == {}
-
-    # a caller-forced --conv-layout is NOT a measured winner: it rides
-    # in best_env but must not appear as a lever (no redundant run)
-    benv3, levers3 = cw.compose_best_env(
-        {"MXNET_TPU_CONV_LAYOUT": "NHWC"}, {"default": {"value": 1800.0}},
-        "t2", artifact_dir=str(tmp_path))
-    assert levers3 == {} and benv3["MXNET_TPU_CONV_LAYOUT"] == "NHWC"
-
-    # with NO baseline anywhere, lone batch AND flag legs compose
-    # nothing (a >1% sweep WINNER file for the tag exists, but there
-    # is no bench number to justify burning a benchbest run)
-    (tmp_path / "FLAGSWEEP_t2.txt").write_text(
-        "WINNER: latency-hiding (900.0 img/s, +5.0% vs baseline)\n")
-    _, levers4 = cw.compose_best_env(
-        {}, {"batch_sweep": {"512": {"value": 1400.0}}}, "t2",
-        artifact_dir=str(tmp_path))
-    assert levers4 == {}
-
-
-def test_bench_watchdog_trip_drops_lock():
-    """A phase that outlives its budget trips the watchdog THREAD,
-    which os._exit(0)s after its hook — bypassing main()'s cleanup —
-    so the hook itself must emit the partial JSON and drop the
-    advisory lock, or a dead bench pins chip_window's deference for
-    the whole staleness window."""
-    import json
-    env = {**ENV, "MXT_BENCH_BATCH": "8", "MXT_BENCH_IMG": "64",
-           "MXT_BENCH_BATCHES": "2", "MXT_BENCH_COMPILE_S": "1"}
-    proc = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                          env=env, capture_output=True, text=True,
-                          timeout=560)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    rec = json.loads([l for l in proc.stdout.splitlines()
-                      if l.startswith("{")][-1])
-    assert rec.get("partial") and rec["phase"] == "compile_epoch_0", rec
-    assert not os.path.exists(os.path.join(REPO, ".bench_lock"))
 
 
 def test_benchmark_score_watchdogged(tmp_path):

@@ -5,7 +5,7 @@ Each case rebuilds a model-zoo net from fixed seeds and compares its
 logits against the committed fixture at 1e-4 — ANY numeric drift in
 init, ops, or the gluon stack fails here.  Regenerate intentionally with
 tools/make_golden.py.  The on-chip twin runs in
-tools/run_tpu_consistency.py (looser tol for bf16 MXU matmuls).
+tests_tpu/test_consistency.py (looser tol for bf16 MXU matmuls).
 """
 import numpy as np
 import pytest

@@ -172,8 +172,6 @@ def test_gates_hold_at_import_in_subprocess():
     code = textwrap.dedent(f"""
         import sys
         sys.path.insert(0, {REPO!r})
-        from __graft_entry__ import _cpu_only_guard
-        _cpu_only_guard()
         from mxnet_tpu.observability import goodput, journal
         assert goodput.ENABLED is False
         assert journal.ENABLED is False
@@ -347,8 +345,6 @@ def test_chaos_run_attributes_95_percent(tmp_path):
 _KILL_CHILD = """
 import os, sys, time
 sys.path.insert(0, {repo!r})
-from __graft_entry__ import _cpu_only_guard
-_cpu_only_guard()
 from mxnet_tpu.observability import journal
 journal.emit("checkpoint_save", step=7, durable=True, bytes=123,
              seconds=0.01)
@@ -361,8 +357,6 @@ while True:
 _RESUME_CHILD = """
 import os, sys
 sys.path.insert(0, {repo!r})
-from __graft_entry__ import _cpu_only_guard
-_cpu_only_guard()
 from mxnet_tpu.observability import journal
 journal.emit("run_resumed", step=7, durable=True, source="test")
 print("RID", journal.run_id(), flush=True)

@@ -377,12 +377,40 @@ def test_mfu_empty_until_measurable():
 
 
 def test_peak_flops_override_beats_table(monkeypatch):
-    peak, src = introspect.peak_flops()
-    assert peak > 0 and src in ("nominal-cpu", "MXNET_PEAK_FLOPS") or \
-        src.startswith("table:")
+    monkeypatch.delenv("MXNET_PEAK_FLOPS", raising=False)
+    # a CPU has no published peak: no placeholder to divide by
+    assert introspect.peak_flops() == (None, "none:cpu")
     monkeypatch.setenv("MXNET_PEAK_FLOPS", "123.5e12")
     peak, src = introspect.peak_flops()
     assert peak == 123.5e12 and src == "MXNET_PEAK_FLOPS"
+
+
+def test_peak_flops_reads_the_chip_table(monkeypatch):
+    """On a TPU the peak is the chip.py row for the exact device_kind;
+    a kind that is not in the table raises instead of guessing."""
+    from mxnet_tpu.base import MXNetError
+
+    class _Dev:
+        platform = "tpu"
+
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    monkeypatch.delenv("MXNET_PEAK_FLOPS", raising=False)
+    monkeypatch.setattr(introspect.jax, "local_devices",
+                        lambda: [_Dev("TPU v5 lite")])
+    assert introspect.peak_flops() == (197e12, "chip:TPU v5 lite")
+    monkeypatch.setattr(introspect.jax, "local_devices",
+                        lambda: [_Dev("TPU v5")])
+    with pytest.raises(MXNetError, match="no published peaks"):
+        introspect.peak_flops()
+
+
+def test_mfu_reports_no_mfu_on_cpu(monkeypatch):
+    monkeypatch.delenv("MXNET_PEAK_FLOPS", raising=False)
+    out = introspect.mfu(step_time_s=0.01, flops=1e6)
+    assert out["flops_per_s"] == 1e8 and out["peak_source"] == "none:cpu"
+    assert not {"mfu", "mfu_pct", "peak_flops"} & set(out)
 
 
 def test_flops_counter_track_in_perfetto_dump(tmp_path, monkeypatch):
@@ -585,21 +613,16 @@ def test_sentinel_readyz_flip_and_refresh(tmp_path, monkeypatch):
         assert rz["checks"]["perf_regression"] is True
 
 
-def test_sentinel_disarmed_without_dir(monkeypatch):
+def test_sentinel_disarmed_without_dir(monkeypatch, tmp_path):
+    """Only MXNET_PERF_BASELINE_DIR arms the sentinel: a compile cache
+    (which the entry points always have) must not."""
     monkeypatch.delenv("MXNET_PERF_BASELINE_DIR", raising=False)
-    monkeypatch.delenv("MXNET_COMPILE_CACHE_DIR", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     _warm_ewma("whole_step", 0.01)
     introspect.sentinel_tick("whole_step")
     assert introspect.baseline_dir() is None
     assert not introspect.sentinel_armed()
-
-
-def test_baseline_dir_defaults_next_to_compile_cache(monkeypatch,
-                                                     tmp_path):
-    monkeypatch.delenv("MXNET_PERF_BASELINE_DIR", raising=False)
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path))
-    assert introspect.baseline_dir() == \
-        os.path.join(str(tmp_path), "perf-baselines")
+    assert not os.listdir(tmp_path)
     monkeypatch.setenv("MXNET_PERF_BASELINE_DIR", str(tmp_path / "own"))
     assert introspect.baseline_dir() == str(tmp_path / "own")
 

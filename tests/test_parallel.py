@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from mxnet_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 from mxnet_tpu import parallel
 from mxnet_tpu.parallel import mesh as mesh_mod

@@ -233,16 +233,27 @@ def test_over_budget_registration_admits_weights_evicted():
 
 # -- restart-free readmission -------------------------------------------------
 
-def test_readmit_zero_new_serve_compiles_when_cache_warm(tmp_path,
-                                                         monkeypatch):
-    """With MXNET_COMPILE_CACHE_DIR wired, rebuilding an evicted
+@pytest.fixture
+def compile_cache(tmp_path, monkeypatch):
+    """JAX's persistent cache at a scratch directory for one test; the
+    process-global jax config is put back afterwards."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    from mxnet_tpu import base
+    saved = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    assert base.enable_compile_cache() == str(tmp_path / "cc")
+    assert base.compile_cache_active()
+    yield
+    jax.config.update("jax_compilation_cache_dir", saved)
+    compilation_cache.reset_cache()
+
+
+def test_readmit_zero_new_serve_compiles_when_cache_warm(compile_cache):
+    """With JAX_COMPILATION_CACHE_DIR set, rebuilding an evicted
     model's buckets is a persistent-cache hit: SERVE_COMPILES must not
     move (readmissions are counted separately) — the restart-free
     churn contract."""
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
-    from mxnet_tpu import base
-    base.maybe_enable_compile_cache()
-    assert base._COMPILE_CACHE_WIRED
     with ModelRegistry(budget_mb=0.0) as reg:
         _register(reg, "alpha")
         before = reg.predict(model="alpha", data=_x())

@@ -284,7 +284,7 @@ def test_superstep_k8_dispatch_gate(comp):
 
 
 # ---------------------------------------------------------------------------
-# K resolution + demotion taxonomy
+# K resolution + demotion reasons
 # ---------------------------------------------------------------------------
 def test_k_resolution_env_beats_ctor_beats_default(monkeypatch):
     net, tr, st = _setup()

@@ -19,12 +19,12 @@ from mxnet_tpu import sym
 from mxnet_tpu.test_utils import check_consistency
 
 TOL = 2e-2
+# MXT_CONSISTENCY_SELFTEST=1 validates the harness cpu-vs-cpu in CI
+SELFTEST = bool(os.environ.get("MXT_CONSISTENCY_SELFTEST"))
 
 
 def _accel():
-    # MXT_CONSISTENCY_SELFTEST=1 validates the harness cpu-vs-cpu in CI
-    return mx.cpu() if os.environ.get("MXT_CONSISTENCY_SELFTEST") \
-        else mx.tpu()
+    return mx.cpu() if SELFTEST else mx.tpu()
 
 
 def _ctxs(**shapes):
@@ -165,7 +165,7 @@ CASES += [
          getattr(sym, "arange_like")(v(), axis=1), 0), v()),
      {"data": (3, 7)}),
     # round 4: hinge-output gradients + conv1d/3d (the NHWC lowering's
-    # rank edges; the 2d NHWC sweep runs via run_tpu_consistency --layout)
+    # rank edges; the 2d NHWC sweep: MXNET_TPU_CONV_LAYOUT=NHWC pytest ...)
     ("svm_output_l2", sym.SVMOutput(v(), sym.clip(sym.abs(
         v("svm_label")) * 2, a_min=0, a_max=4)), {"data": (5, 5),
                                                   "svm_label": (5,)}),
@@ -219,7 +219,24 @@ def test_fc_grad_consistency():
 
 def test_resnet50_fwd_bwd_consistency():
     """The flagship: ResNet-50 forward loss and parameter grads on the
-    real chip match the CPU reference within bf16-MXU tolerance."""
+    real chip match the CPU reference — with the chip's matmuls at full
+    f32 precision.  The subject is the lowering (graph, layouts, BN,
+    pooling, residual adds), not the MXU's rounding: a randomly
+    initialised ResNet-50 in train mode amplifies any perturbation
+    through fifty batch-normalised layers, so at the default bf16 MXU
+    precision class probabilities differ from the CPU's by up to 0.146
+    (the largest probability is 0.144) and gradient sums by 20% at
+    batch 4, and still by 0.063 / 19% at batch 32; at "highest" the
+    probabilities agree to four decimals and the gradient sums to 0.4%
+    (chip run, PR 21).  What the default precision does to a forward
+    pass is checked where it is well-conditioned: the golden logits
+    below, and chip_smoke.py's serve phase (ResNet-50 at 224, 3.5e-3)."""
+    import jax
+    with jax.default_matmul_precision("highest"):
+        _resnet50_fwd_bwd()
+
+
+def _resnet50_fwd_bwd():
     from mxnet_tpu.gluon.model_zoo import vision
     net = vision.resnet50_v1(classes=100)
     out = net(sym.Variable("data"))
@@ -241,10 +258,10 @@ def test_resnet50_fwd_bwd_consistency():
                 for k, g in sorted(mod._exec.grad_dict.items())[:10]}
         results.append((probs, gsum))
     (p_a, g_a), (p_b, g_b) = results
-    np.testing.assert_allclose(p_a, p_b, rtol=5e-2, atol=5e-2)
+    np.testing.assert_allclose(p_a, p_b, rtol=TOL, atol=TOL)
     for k in g_a:
-        np.testing.assert_allclose(g_a[k], g_b[k], rtol=1e-1,
-                                   atol=1e-1, err_msg=k)
+        np.testing.assert_allclose(g_a[k], g_b[k], rtol=TOL, atol=TOL,
+                                   err_msg=k)
 
 
 def test_gluon_lstm_consistency():
@@ -433,3 +450,22 @@ def test_csr_dot_consistency():
             _os.environ.pop("MXNET_SPARSE_DOT", None)
         else:
             _os.environ["MXNET_SPARSE_DOT"] = prev
+
+
+from mxnet_tpu.test_utils import (golden_fixture_path, golden_forward,
+                                  golden_model_cases)
+
+
+@pytest.mark.skipif(SELFTEST, reason="the CPU twin is "
+                    "tests/test_golden_forward.py (1e-4)")
+@pytest.mark.parametrize("name", sorted(golden_model_cases()))
+def test_golden_logits_on_accelerator(name):
+    """Golden-logit zoo fixtures (tests/golden/*.npz) with the model
+    built and run on the accelerator; bf16 MXU matmuls get 2e-2 of the
+    logits' scale where the CPU twin asserts 1e-4."""
+    ref = np.load(golden_fixture_path(name))["logits"]
+    with _accel():
+        got = golden_forward(name)
+    err = float(np.max(np.abs(got - ref)))
+    scale = float(np.max(np.abs(ref))) or 1.0
+    assert err <= TOL * scale, f"golden drift {err:.2e} > {TOL}*{scale:.2e}"

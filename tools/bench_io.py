@@ -96,12 +96,10 @@ def main():
                   f"({r / t:.1f} img/s/thread)")
         # the budget the pipeline must clear, derived from the MFU
         # north star (BASELINE.md): img/s = MFU * peak / flops-per-img
-        from mxnet_tpu.chip import (RESNET50_TRAIN_FLOPS_PER_IMG,
-                                    peak_bf16_tflops)
+        from mxnet_tpu.chip import PEAKS, RESNET50_TRAIN_FLOPS_PER_IMG
         per_thread = max(r / t for r, t in zip(rates, counts))
-        for kind in ("TPU v5e", "TPU v5p"):
-            need = 0.6 * peak_bf16_tflops(kind) * 1e12 \
-                / RESNET50_TRAIN_FLOPS_PER_IMG
+        for kind, peak in PEAKS.items():
+            need = 0.6 * peak.bf16_flops / RESNET50_TRAIN_FLOPS_PER_IMG
             print(f"60% MFU on {kind}: need {need:.0f} img/s "
                   f"≈ {need / per_thread:.0f} threads at the best "
                   f"measured per-thread rate")

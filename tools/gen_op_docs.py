@@ -11,13 +11,7 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-if os.environ.get("JAX_PLATFORMS") != "cpu":
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    os.execv(sys.executable, [sys.executable] + sys.argv)
-
-from __graft_entry__ import _cpu_only_guard  # noqa: E402
-
-_cpu_only_guard()
+os.environ["JAX_PLATFORMS"] = "cpu"  # needs no chip: set before jax loads
 
 import mxnet_tpu  # noqa: E402,F401 — populates the registry
 from mxnet_tpu.ops.registry import OP_ALIASES, OP_REGISTRY  # noqa: E402

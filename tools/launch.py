@@ -8,7 +8,11 @@ dmlc_tracker).  On TPU pods there are no servers: every process is an
 SPMD worker that joins a `jax.distributed` cluster (coordinator =
 process 0) and the collectives ride ICI/DCN.  Backends here:
 
-  local  fork N workers on this host (dev mode)
+  local  fork N workers on this host — CPU dev mode only.  Every
+         child would claim every chip of the host, and a chip belongs
+         to one process: one host's chips are driven by ONE process
+         (`context=[mx.tpu(i) for i in range(n)]` / `make_mesh`, as
+         chip_smoke.py's four-chip phase does), never by `-n N` here
   ssh    one worker per host from --hostfile via `ssh host env ... cmd`
          (the reference's ssh tracker role); worker 0's host doubles as
          the coordinator
