@@ -727,13 +727,15 @@ class MetricsHygieneChecker:
             # flight-recorder phase names (ISSUE 8): phase_span("x"),
             # flight.record("x", ...) — every distinct name is an
             # unbounded entry in flight.summary() + a watchdog EWMA
-            # slot.  `phase_span` is distinctive enough to match under
-            # ANY receiver (x.phase_span / profiler.phase_span / bare);
+            # slot.  `phase_span` (and the primitive's other names,
+            # `span` / `trace_span`) match under ANY receiver
+            # (x.phase_span / profiler.phase_span / bare: only a built
+            # string argument is flagged, so re.Match.span() is safe);
             # `record` is too generic, so it stays allowlisted to
             # flight-ish bases (other aliases escape — conservative by
             # design, a miss is recoverable)
             last = cn.split(".")[-1]
-            if (last == "phase_span"
+            if (last in ("phase_span", "trace_span", "span")
                     or (last == "record"
                         and cn.split(".")[0] in ("record", "flight",
                                                  "_flight", "fl"))) and \

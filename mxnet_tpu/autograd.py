@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import MXNetError
+from .observability.tracing import span
 from .ops import registry as _reg
 
 
@@ -172,6 +173,11 @@ def _ones_cot(shape: Tuple[int, ...], dtype):
 def backward(heads, head_grads=None, retain_graph: bool = False,
              train_mode: bool = True) -> None:
     """Reverse walk of the tape from `heads` (parity: Imperative::Backward)."""
+    with span("mx.autograd.backward", cat="autograd"):
+        _backward(heads, head_grads, retain_graph)
+
+
+def _backward(heads, head_grads, retain_graph) -> None:
     from .ndarray.sparse import _RspCot, RowSparseNDArray
     tape = _state.tape
     grad_map: Dict[Tuple[int, int], jax.Array] = {}

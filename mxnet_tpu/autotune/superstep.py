@@ -43,12 +43,11 @@ from ..analysis import hot_path
 from ..analysis import sanitizer as _san
 from ..gluon.wholestep import WholeStepCompiler, _AmpIneligible, \
     _Ineligible, _ShardIneligible, amp_policy
-from ..observability import flight as _flight
 from ..observability import introspect as _introspect
 from ..observability import journal as _journal
 from ..observability import memory as _memory
 from ..observability import metrics as _metrics
-from ..observability.tracing import trace_span
+from ..observability.tracing import span
 from .. import autograd
 from ..gluon.parameter import DeferredInitializationError
 from . import decisions as _decisions
@@ -521,10 +520,8 @@ class SuperStepCompiler(WholeStepCompiler):
             _metrics.XLA_LAUNCHES.inc(kind="superstep")
             _metrics.OPTIMIZER_STEPS.inc(float(k))
         try:
-            with trace_span("superstep", cat="trainer"), \
-                    _flight.phase_span("superstep", cat="step",
-                                       step=tr._step_id, watch=True,
-                                       mem=True, labels={"k": k}), \
+            with span("superstep", cat="trainer", step=tr._step_id,
+                      watch=True, mem=True, labels={"k": k}), \
                     _memory.oom_guard("superstep.step"):
                 losses, new_aux, new_p, new_s, new_res, new_scaler, \
                     nts = fn(gparams, svals, residuals, scaler, aux,

@@ -197,6 +197,18 @@ def atomic_write(fname: str, data) -> None:
     os.replace(tmp, fname)
 
 
+def flight_dir() -> str:
+    """Where the flight recorder's dumps and the post-mortem reports
+    land: ``MXNET_FLIGHT_DIR``, else ``<tempfile.gettempdir()>/mxnet_flight``
+    — never the working directory, which may be a checkout that a run
+    measures.  Created on demand."""
+    import tempfile
+    d = os.environ.get("MXNET_FLIGHT_DIR") or os.path.join(
+        tempfile.gettempdir(), "mxnet_flight")
+    os.makedirs(d, exist_ok=True)
+    return d
+
+
 def unique_path(directory: str, stem: str, ext: str, clock=None) -> str:
     """Collision-free timestamped file path — the ONE filename policy
     every dump writer (``profiler.dump_profile`` autosnapshots,

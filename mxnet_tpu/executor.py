@@ -32,7 +32,7 @@ from .ndarray import NDArray
 from .observability import introspect as _introspect
 from .observability import memory as _memory
 from .observability import metrics as _metrics
-from .observability.tracing import trace_span
+from .observability.tracing import span
 from .symbol.graph import GraphPlan
 from . import random as _random
 
@@ -132,8 +132,12 @@ class Executor:
             if _metrics.ENABLED:
                 _metrics.JIT_CACHE_MISSES.inc()
             plan = self._plan
-            self._jit_cache[key] = jax.jit(
-                lambda a, x, k, t: plan.run(a, x, k, t), static_argnums=(3,))
+
+            def mx_executor_fwd(arg_vals, aux_vals, key_, is_train):
+                return plan.run(arg_vals, aux_vals, key_, is_train)
+
+            self._jit_cache[key] = jax.jit(mx_executor_fwd,
+                                           static_argnums=(3,))
         elif _metrics.ENABLED:
             _metrics.JIT_CACHE_HITS.inc()
         return self._jit_cache[key]
@@ -151,7 +155,7 @@ class Executor:
             remat = self._remat
             segN = self._mirror_segments
 
-            def fb(arg_vals, aux_vals, key_, ograds):
+            def mx_executor_fwd_bwd(arg_vals, aux_vals, key_, ograds):
                 others = {k: v for k, v in arg_vals.items() if k not in grad_names}
                 # one zero dummy per sparse-embedding step, shaped like
                 # the lookup OUTPUT (tokens × dim, not vocab × dim)
@@ -208,13 +212,17 @@ class Executor:
                     rsp_grads[n] = (ids, vals)
                 return outs, new_aux, grads, rsp_grads
 
-            self._jit_cache[key] = jax.jit(fb)
+            self._jit_cache[key] = jax.jit(mx_executor_fwd_bwd)
         elif _metrics.ENABLED:
             _metrics.JIT_CACHE_HITS.inc()
         return self._jit_cache[key]
 
     # -- public API ---------------------------------------------------------
     def _gather(self, kwargs):
+        with span("mx.executor.gather", cat="executor"):
+            return self._gather_impl(kwargs)
+
+    def _gather_impl(self, kwargs):
         dev = None if self._mesh is not None else self._ctx.jax_device()
         for k, v in kwargs.items():
             if k in self.arg_dict:
@@ -271,7 +279,7 @@ class Executor:
             if _metrics.ENABLED:
                 _metrics.XLA_LAUNCHES.inc(kind="fwd_bwd")
             fwd_bwd = self._fwd_bwd
-            with trace_span("forward_backward", cat="executor"), \
+            with span("mx.executor.launch", cat="executor"), \
                     _memory.oom_guard("executor.forward_backward"):
                 outs, new_aux, grads, rsp_grads = fwd_bwd(
                     arg_vals, aux_vals, key, ograds)
@@ -280,13 +288,14 @@ class Executor:
                 self._noted.add(nk)
                 _introspect.note_jit("executor:fwd_bwd", fwd_bwd,
                                      arg_vals, aux_vals, key, ograds)
-            self._set_results(outs, new_aux)
+            with span("mx.executor.deposit", cat="executor"):
+                self._set_results(outs, new_aux)
             self._pending_grads = (grads, rsp_grads)
             return self._outputs_cache
         if _metrics.ENABLED:
             _metrics.XLA_LAUNCHES.inc(kind="fwd")
         fwd = self._fwd
-        with trace_span("forward", cat="executor"), \
+        with span("mx.executor.launch", cat="executor"), \
                 _memory.oom_guard("executor.forward"):
             outs, new_aux = fwd(arg_vals, aux_vals, key, is_train)
         nk = ("fwd", self._plan_key)
@@ -298,7 +307,8 @@ class Executor:
             self._noted.add(nk)
             _introspect.note_jit("executor:fwd", fwd, arg_vals,
                                  aux_vals, key, is_train)
-        self._set_results(outs, new_aux)
+        with span("mx.executor.deposit", cat="executor"):
+            self._set_results(outs, new_aux)
         return self._outputs_cache
 
     def backward(self, out_grads=None, is_train: bool = True) -> None:
@@ -310,7 +320,8 @@ class Executor:
             raise MXNetError("backward called before forward")
         self._seen_backward = True
         if out_grads is None and self._pending_grads is not None:
-            self._deposit_grads(*self._pending_grads)
+            with span("mx.executor.deposit", cat="executor"):
+                self._deposit_grads(*self._pending_grads)
             self._pending_grads = None
             return
         arg_vals, aux_vals, key = self._snapshot
@@ -343,7 +354,7 @@ class Executor:
         # fused training program dumps ledger+ring and re-raises typed;
         # the memory.oom chaos site injects a synthetic one here
         fwd_bwd = self._fwd_bwd
-        with trace_span("forward_backward", cat="executor"), \
+        with span("mx.executor.launch", cat="executor"), \
                 _memory.oom_guard("executor.forward_backward"):
             _fi_fire("memory.oom", at="executor")
             outs, new_aux, grads, rsp_grads = fwd_bwd(
@@ -353,9 +364,10 @@ class Executor:
             self._noted.add(nk)
             _introspect.note_jit("executor:fwd_bwd", fwd_bwd,
                                  arg_vals, aux_vals, key, ograds)
-        if set_results:
-            self._set_results(outs, new_aux)
-        self._deposit_grads(grads, rsp_grads)
+        with span("mx.executor.deposit", cat="executor"):
+            if set_results:
+                self._set_results(outs, new_aux)
+            self._deposit_grads(grads, rsp_grads)
 
     def _deposit_grads(self, grads, rsp_grads=None):
         from .ndarray.sparse import RowSparseNDArray

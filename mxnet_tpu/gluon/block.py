@@ -22,6 +22,7 @@ from ..base import MXNetError, getenv
 from ..context import cpu
 from ..observability import introspect as _introspect
 from ..observability import metrics as _metrics
+from ..observability.tracing import span
 from .. import ndarray as nd
 from ..ndarray import NDArray
 from .. import symbol as sym_mod
@@ -245,10 +246,12 @@ class CachedOp:
 
     def __init__(self, symbol: Symbol):
         self.symbol = symbol
-        self.plan = GraphPlan(symbol)
-        self._fwd = jax.jit(
-            lambda args, aux, key, t: self.plan.run(args, aux, key, t),
-            static_argnums=(3,))
+        self.plan = plan = GraphPlan(symbol)
+
+        def mx_cachedop_fwd(args, aux, key, is_train):
+            return plan.run(args, aux, key, is_train)
+
+        self._fwd = jax.jit(mx_cachedop_fwd, static_argnums=(3,))
         self._bwd_cache = {}
         self._fwd_donated = None  # built on first donated inference call
         self._noted = set()  # introspection captures done (fwd/bwd)
@@ -264,7 +267,8 @@ class CachedOp:
         if self._fwd_donated is None:
             plan = self.plan
 
-            def fwd_d(data_vals, param_vals, aux_vals, key, t):
+            def mx_cachedop_fwd_donated(data_vals, param_vals, aux_vals,
+                                        key, t):
                 merged = dict(param_vals)
                 merged.update(data_vals)
                 return plan.run(merged, aux_vals, key, t)
@@ -279,7 +283,8 @@ class CachedOp:
             _warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable")
             self._fwd_donated = jax.jit(
-                fwd_d, static_argnums=(4,), donate_argnums=(0,))
+                mx_cachedop_fwd_donated, static_argnums=(4,),
+                donate_argnums=(0,))
         return self._fwd_donated
 
     def _run_all(self, names, vals_list, aux_vals, key, is_train):
@@ -292,7 +297,7 @@ class CachedOp:
         if key_ not in self._bwd_cache:
             plan = self.plan
 
-            def bwd(primals, cots, aux_vals, key, is_train):
+            def mx_cachedop_bwd(primals, cots, aux_vals, key, is_train):
                 def run(*vals):
                     d = dict(zip(key_, vals))
                     outs, new_aux = plan.run(d, aux_vals, key, is_train)
@@ -301,11 +306,16 @@ class CachedOp:
                 _, vjp_fn = jax.vjp(run, *primals)
                 return vjp_fn(cots)
 
-            self._bwd_cache[key_] = jax.jit(bwd, static_argnums=(4,))
+            self._bwd_cache[key_] = jax.jit(mx_cachedop_bwd,
+                                            static_argnums=(4,))
         return self._bwd_cache[key_]
 
     def __call__(self, arg_arrays: Dict[str, NDArray],
                  aux_arrays: Dict[str, NDArray], ctx, input_names=None):
+        with span("mx.cachedop.forward", cat="cachedop"):
+            return self._call(arg_arrays, aux_arrays, ctx, input_names)
+
+    def _call(self, arg_arrays, aux_arrays, ctx, input_names):
         from .. import random as _random
         is_train = autograd.is_training()
         arg_vals = {k: v._data for k, v in arg_arrays.items()}
@@ -354,7 +364,9 @@ class CachedOp:
                     _introspect.note_jit("gluon:bwd", bwd_jit, primals,
                                          tuple(cots), aux_snapshot, key,
                                          is_train)
-                return bwd_jit(primals, tuple(cots), aux_snapshot, key, is_train)
+                with span("mx.cachedop.backward", cat="cachedop"):
+                    return bwd_jit(primals, tuple(cots), aux_snapshot, key,
+                                   is_train)
 
             autograd._record(None, [arg_arrays[n] for n in names], out_nds,
                              vjp_fn, raw_outs)

@@ -34,7 +34,7 @@ first-class requirement, arxiv 1605.08695 §4.4):
   * **stall watchdog** — steps execute on a dedicated worker thread
     while the caller waits with a deadline derived from the
     step-duration EWMA (the supervisor's own, seeded/maxed with the
-    flight recorder's ``trainer_step``/``whole_step`` watch EWMAs).  A
+    flight recorder's ``mx.trainer.step``/``whole_step`` watch EWMAs).  A
     step that blows ``MXNET_SUPERVISE_STALL_FACTOR`` × EWMA (floored at
     ``MXNET_SUPERVISE_STALL_MIN_S``) post-mortems and raises a typed
     ``TrainingStalledError`` instead of hanging forever; the supervisor
@@ -94,7 +94,7 @@ _EWMA_WARMUP = 5    # the two EWMAs must agree on what "normal" means
 
 #: flight phases whose warmed EWMA seeds the stall deadline (whichever
 #: step mode ran, its phase is warm)
-_STEP_PHASES = ("trainer_step", "whole_step")
+_STEP_PHASES = ("mx.trainer.step", "whole_step")
 
 
 def enabled() -> bool:
@@ -495,7 +495,7 @@ class TrainingSupervisor:
             _metrics.SUPERVISOR_REWINDS.inc(reason="retry")
         # the restore + window replay is re-done work, not progress:
         # its whole wall-clock books as retry_replay badput, and any
-        # trainer_step spans recorded inside are suppressed so replayed
+        # mx.trainer.step spans recorded inside are suppressed so replayed
         # steps don't double-count as goodput (docs/goodput.md)
         with _goodput.replay_scope("retry_replay"):
             self._restore_snapshot()
@@ -533,7 +533,7 @@ class TrainingSupervisor:
         first steps include compilation, which has no baseline, and a
         long-lived process's flight EWMA (warmed on a DIFFERENT
         trainer's steps) must not arm a deadline against them.  Once
-        armed, the flight recorder's ``trainer_step``/``whole_step``
+        armed, the flight recorder's ``mx.trainer.step``/``whole_step``
         watch EWMAs can only RAISE the deadline (they see the same
         steps plus whatever else shares the phase — the conservative
         direction)."""

@@ -37,7 +37,7 @@ from ..observability import journal as _journal
 from ..observability import introspect as _introspect
 from ..observability import memory as _memory
 from ..observability import metrics as _metrics
-from ..observability.tracing import trace_span
+from ..observability.tracing import set_step, span
 from .. import optimizer as opt
 from ..model import _create_kvstore
 from .parameter import ParameterDict, Parameter
@@ -106,6 +106,7 @@ class Trainer:
         # phase records (joins allreduce/compress/update sub-phases to
         # their step in a timeline dump)
         self._step_id = 0
+        self._counts = None  # metrics.step_counts() at the last step's end
 
     def _init_optimizer(self, optimizer, optimizer_params):
         param_dict = {i: param for i, param in enumerate(self._params)}
@@ -218,21 +219,29 @@ class Trainer:
         The per-step dispatch delta is published as the
         mxnet_trainer_step_dispatches gauge."""
         on = _metrics.ENABLED
-        d0 = _metrics.step_dispatches() if on else 0.0
-        with trace_span("trainer_step", cat="optimizer"), \
-                _flight.phase_span("trainer_step", cat="step",
-                                   step=self._step_id, watch=True,
-                                   mem=True):
-            self._step(batch_size, ignore_stale_grad)
-        self._step_id += 1
+        deltas = None
         if on:
-            _metrics.TRAINER_STEP_DISPATCHES.set(
-                _metrics.step_dispatches() - d0)
+            c0, deltas = _metrics.step_counts(), {}
+        with span("mx.trainer.step", cat="optimizer", step=self._step_id,
+                  labels=deltas, watch=True, mem=True):
+            self._step(batch_size, ignore_stale_grad)
+            if on:
+                # the record carries the whole Gluon step's counts: from
+                # the last Trainer.step's return (forward, backward, the
+                # loop's reads) to this one's
+                now = _metrics.step_counts()
+                deltas.update(_metrics.step_deltas(self._counts or c0, now))
+                self._counts = now
+                _metrics.TRAINER_STEP_DISPATCHES.set(
+                    now[0] + now[1] - c0[0] - c0[1])
+        self._step_id += 1
+        # a Gluon step runs from one Trainer.step return to the next
+        set_step(self._step_id)
         if _introspect.ENABLED:
             # perf-regression sentinel heartbeat for the fused path
             # (the whole-step path ticks its own phase in
             # WholeStepCompiler._dispatch): one counter bump per step
-            _introspect.sentinel_tick("trainer_step")
+            _introspect.sentinel_tick("mx.trainer.step")
         if _journal.ENABLED:
             _journal.maybe_milestone(self._step_id, source="trainer")
 
@@ -355,9 +364,7 @@ class Trainer:
         idx = tuple(i for i, _ in dense)
         bk = self._ensure_bucketer(sig, idx)
         gc = getattr(self._kv, "_gc", None)
-        with trace_span("bucketed_allreduce", cat="kvstore"), \
-                _flight.phase_span("allreduce", cat="kvstore",
-                                   step=self._step_id, mem=True), \
+        with span("mx.trainer.allreduce", cat="kvstore", mem=True), \
                 _memory.memory_scope("grad_bucket"):
             flats = bk.flatten([g.handle for g in grads])
             ctx = grads[0].context
@@ -525,11 +532,8 @@ class Trainer:
                 sgrads = [_as_rsp(p.list_grad()[0])
                           if reduced_rsp is None or i not in reduced_rsp
                           else reduced_rsp[i] for i, p in rsp]
-                with _flight.phase_span("fused_sparse_update",
-                                        cat="optimizer",
-                                        step=self._step_id, mem=True):
-                    upd.update_sparse([i for i, _ in rsp], sgrads,
-                                      [p.list_data()[0] for _, p in rsp])
+                upd.update_sparse([i for i, _ in rsp], sgrads,
+                                  [p.list_data()[0] for _, p in rsp])
             else:
                 for i, param in rsp:
                     for u, arr, grad in zip(self._updaters,
@@ -557,20 +561,14 @@ class Trainer:
                         "allreduce and update steps saw different live "
                         "parameter sets")
                 if live:
-                    with _flight.phase_span("fused_update",
-                                            cat="optimizer",
-                                            step=self._step_id,
-                                            mem=True):
-                        upd.update_all(
-                            [i for i, _ in live], flats,
-                            [p.list_data()[0] for _, p in live],
-                            grad_views=[views[pos[i]] for i, _ in live])
+                    upd.update_all(
+                        [i for i, _ in live], flats,
+                        [p.list_data()[0] for _, p in live],
+                        grad_views=[views[pos[i]] for i, _ in live])
             else:
-                with _flight.phase_span("fused_update", cat="optimizer",
-                                        step=self._step_id, mem=True):
-                    upd.update_all([i for i, _ in live],
-                                   [p.list_grad()[0] for _, p in live],
-                                   [p.list_data()[0] for _, p in live])
+                upd.update_all([i for i, _ in live],
+                               [p.list_grad()[0] for _, p in live],
+                               [p.list_data()[0] for _, p in live])
             self._clear_fresh(done)
             return
         if fused_ok and ncopies > 1 and \

@@ -58,12 +58,11 @@ from ..faultinject import InjectedFault as _InjectedFault
 from ..faultinject import fire as _fi_fire
 from ..ndarray import NDArray
 from ..resilience import DeviceUnavailableError as _DeviceUnavailableError
-from ..observability import flight as _flight
 from ..observability import journal as _journal
 from ..observability import introspect as _introspect
 from ..observability import memory as _memory
 from ..observability import metrics as _metrics
-from ..observability.tracing import trace_span
+from ..observability.tracing import span
 from ..optimizer import HyperDeviceCache as _HyperDeviceCache
 from ..optimizer import cast_like as _cast_like
 from .. import symbol as sym_mod
@@ -1056,10 +1055,8 @@ class WholeStepCompiler:
             _metrics.XLA_LAUNCHES.inc(kind="whole_step")
             _metrics.OPTIMIZER_STEPS.inc()
         try:
-            with trace_span("whole_step", cat="trainer"), \
-                    _flight.phase_span("whole_step", cat="step",
-                                       step=tr._step_id, watch=True,
-                                       mem=True), \
+            with span("whole_step", cat="trainer", step=tr._step_id,
+                      watch=True, mem=True), \
                     _memory.oom_guard("wholestep.step"):
                 loss, new_aux, new_p, new_s, new_res, new_scaler, nts = \
                     fn(gparams, svals, residuals, scaler, aux, consts,

@@ -48,7 +48,7 @@ from . import ndarray as nd
 from .ndarray import NDArray
 from .observability import memory as _memory
 from .observability import metrics as _metrics
-from .observability.tracing import trace_span
+from .observability.tracing import span
 from . import optimizer as opt
 
 
@@ -338,12 +338,12 @@ class GradBucketer:
         self.sizes = tuple(sizes)
         lay, sig_ = self.layout, self.sig
 
-        def _flat(gs):
+        def mx_kv_flatten(gs):
             return [jnp.concatenate([gs[p].reshape(-1) for p in bucket])
                     if len(bucket) > 1 else gs[bucket[0]].reshape(-1)
                     for bucket in lay]
 
-        def _unflat(flats):
+        def mx_kv_unflatten(flats):
             out = [None] * len(sig_)
             for b, bucket in enumerate(lay):
                 off = 0
@@ -357,10 +357,10 @@ class GradBucketer:
         # pure, jit-inlinable forms (no metrics, no dispatch of their
         # own): the whole-step compiler traces these inside its single
         # training-step program instead of issuing the jitted wrappers
-        self.flatten_inline = _flat
-        self.unflatten_inline = _unflat
-        self._flatten = jax.jit(_flat)
-        self._unflatten = jax.jit(_unflat)
+        self.flatten_inline = mx_kv_flatten
+        self.unflatten_inline = mx_kv_unflatten
+        self._flatten = jax.jit(mx_kv_flatten)
+        self._unflatten = jax.jit(mx_kv_unflatten)
 
     @hot_path
     def flatten(self, grads: List) -> List:
@@ -464,17 +464,13 @@ class KVStore:
     def push(self, key, value, priority: int = 0) -> None:
         """Aggregate `value` (list = per-device copies) into the store.
         If an optimizer is set (update_on_kvstore), applies the update."""
+        with span("kvstore_push", cat="kvstore") as sp:
+            self._push_impl(key, value, priority)
         if _metrics.ENABLED:
-            t0 = time.perf_counter()
-            with trace_span("kvstore_push", cat="kvstore"):
-                self._push_impl(key, value, priority)
             # success path only: a failed push must not count as pushed
-            _metrics.KVSTORE_ALLREDUCE_SECONDS.observe(
-                time.perf_counter() - t0)
+            _metrics.KVSTORE_ALLREDUCE_SECONDS.observe(sp.seconds)
             _metrics.KVSTORE_PUSH_BYTES.inc(sum(
                 _nd_bytes(v) for vl in _val_list(value) for v in vl))
-        else:
-            self._push_impl(key, value, priority)
 
     def _push_impl(self, key, value, priority: int = 0) -> None:
         keys, _ = _key_list(key)
@@ -506,20 +502,16 @@ class KVStore:
         and pull is a pointer hand-off.  Semantics are identical to
         push(key, value); pull(key, out) — verified by tests/test_kvstore.py.
         """
+        with span("mx.kvstore.pushpull", cat="kvstore") as sp:
+            self._pushpull_impl(key, value, out, priority)
         if _metrics.ENABLED:
-            t0 = time.perf_counter()
-            with trace_span("kvstore_pushpull", cat="kvstore"):
-                self._pushpull_impl(key, value, out, priority)
             # success path only: a failed pushpull must not count bytes
-            _metrics.KVSTORE_ALLREDUCE_SECONDS.observe(
-                time.perf_counter() - t0)
+            _metrics.KVSTORE_ALLREDUCE_SECONDS.observe(sp.seconds)
             _metrics.KVSTORE_PUSH_BYTES.inc(sum(
                 _nd_bytes(v) for vl in _val_list(value) for v in vl))
             if out is not None:
                 _metrics.KVSTORE_PULL_BYTES.inc(sum(
                     _nd_bytes(o) for ol in _val_list(out) for o in ol))
-        else:
-            self._pushpull_impl(key, value, out, priority)
 
     def _pushpull_impl(self, key, value, out=None, priority: int = 0) -> None:
         keys, _ = _key_list(key)
@@ -671,7 +663,7 @@ class KVStore:
         if self.num_workers <= 1 or self.type == "local":
             return merged
         from .parallel import collectives
-        with trace_span("kvstore_allreduce", cat="kvstore"):
+        with span("kvstore_allreduce", cat="kvstore"):
             return collectives.allreduce_hosts(merged)
 
     @hot_path
@@ -714,21 +706,18 @@ class KVStore:
         if compression is not None and not isinstance(
                 compression, GradientCompression):
             compression = GradientCompression(**compression)
+        # no span of its own: the Trainer's mx.trainer.allreduce is
+        # this interval (plus the flatten that feeds it)
+        t0 = time.perf_counter() if _metrics.ENABLED else 0.0
+        out = self._allreduce_impl(vals) if compression is None \
+            else self._compressed_allreduce_impl(vals, residuals,
+                                                 compression)
         if _metrics.ENABLED:
-            t0 = time.perf_counter()
-            with trace_span("kvstore_allreduce", cat="kvstore"):
-                out = self._allreduce_impl(vals) if compression is None \
-                    else self._compressed_allreduce_impl(
-                        vals, residuals, compression)
             _metrics.KVSTORE_ALLREDUCE_SECONDS.observe(
                 time.perf_counter() - t0)
             _metrics.KVSTORE_PUSH_BYTES.inc(sum(
                 _nd_bytes(v) for vl in vals for v in vl))
-            return out
-        if compression is not None:
-            return self._compressed_allreduce_impl(vals, residuals,
-                                                   compression)
-        return self._allreduce_impl(vals)
+        return out
 
     def _allreduce_impl(self, vals: List[List[NDArray]]) -> List[NDArray]:
         merged = [self._merge_local(vl) for vl in vals]
@@ -781,7 +770,7 @@ class KVStore:
         dedup = bool(getenv("MXNET_EMBED_DEDUP_IDS", True))
         t0 = time.perf_counter() if _metrics.ENABLED else 0.0
         out = []
-        with trace_span("kvstore_sparse_allreduce", cat="kvstore"):
+        with span("kvstore_sparse_allreduce", cat="kvstore"):
             for vl in vals:
                 if len(vl) == 1 and dedup:
                     # construction guarantees sorted-unique ids — the

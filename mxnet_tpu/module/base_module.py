@@ -18,7 +18,7 @@ from ..io import DataDesc
 from ..model import BatchEndParam
 from ..observability import flight as _flight
 from ..observability import metrics as _obs
-from ..observability.tracing import step_span, trace_span
+from ..observability.tracing import span
 
 
 def _check_input_names(symbol, names, typename, throw):
@@ -231,9 +231,7 @@ class BaseModule:
             nbatch = 0
             data_iter = iter(train_data)
             end_of_batch = False
-            with trace_span("data_fetch", cat="io"), \
-                    _flight.phase_span("data_wait", cat="io"):
-                next_data_batch = next(data_iter)
+            next_data_batch = self._fetch(data_iter, global_step)
             while not end_of_batch:
                 data_batch = next_data_batch
                 if monitor is not None:
@@ -245,49 +243,44 @@ class BaseModule:
                 # producer thread issues them DURING the step, which
                 # would make the delta nondeterministic.
                 obs_on = _obs.ENABLED
+                deltas = None
                 if obs_on:
-                    d0 = _obs.step_dispatches()
-                with step_span(global_step):
+                    c0, deltas = _obs.step_counts(), {}
+                with span("mx.step", cat="step", step=global_step,
+                          labels=deltas, watch=True):
                     if _sup is not None:
                         # supervised: fwd/bwd/update run as ONE step_fn
                         # under retry + divergence/stall watchdogs
                         _sup.step(data_batch)
                     else:
                         self.forward_backward(data_batch)
-                        with trace_span("update", cat="optimizer"):
-                            self.update()
-                if obs_on:
-                    _obs.FIT_STEP_DISPATCHES.set(_obs.step_dispatches() - d0)
+                        self.update()
+                    if obs_on:
+                        # the span's deltas ride its ring record
+                        deltas.update(_obs.step_deltas(c0))
+                        _obs.FIT_STEP_DISPATCHES.set(
+                            deltas["launches"] + deltas["device_puts"])
+                step = global_step
                 global_step += 1
                 try:
-                    # iterators that time their own consumer-side stall
-                    # (PrefetchingIter) must not be counted again here
-                    if obs_on and not getattr(
-                            data_iter, "_self_timed_data_wait", False):
-                        t0 = time.perf_counter()
-                        with trace_span("data_fetch", cat="io"), \
-                                _flight.phase_span("data_wait", cat="io",
-                                                   step=global_step):
-                            next_data_batch = next(data_iter)
-                        _obs.DATA_WAIT_SECONDS.observe(
-                            time.perf_counter() - t0)
-                    else:
-                        with trace_span("data_fetch", cat="io"), \
-                                _flight.phase_span("data_wait", cat="io",
-                                                   step=global_step):
-                            next_data_batch = next(data_iter)
-                    self.prepare(next_data_batch)
+                    next_data_batch = self._fetch(data_iter, step)
+                    with span("mx.module.prepare", cat="io", step=step):
+                        self.prepare(next_data_batch)
                 except StopIteration:
                     end_of_batch = True
-                self.update_metric(eval_metric, data_batch.label)
+                with span("mx.module.update_metric", cat="metric",
+                          step=step):
+                    self.update_metric(eval_metric, data_batch.label)
                 if monitor is not None:
                     monitor.toc_print()
                 if batch_end_callback is not None:
-                    batch_end_params = BatchEndParam(epoch=epoch, nbatch=nbatch,
-                                                     eval_metric=eval_metric,
-                                                     locals=locals())
-                    for callback in _as_list(batch_end_callback):
-                        callback(batch_end_params)
+                    with span("mx.fit.callbacks", cat="callback",
+                              step=step):
+                        batch_end_params = BatchEndParam(
+                            epoch=epoch, nbatch=nbatch,
+                            eval_metric=eval_metric, locals=locals())
+                        for callback in _as_list(batch_end_callback):
+                            callback(batch_end_params)
                 nbatch += 1
 
             for name, val in eval_metric.get_name_value():
@@ -295,8 +288,9 @@ class BaseModule:
             toc = time.time()
             self.logger.info("Epoch[%d] Time cost=%.3f", epoch, (toc - tic))
 
-            arg_params_, aux_params_ = self.get_params()
-            self.set_params(arg_params_, aux_params_)
+            with span("mx.fit.epoch_end", cat="train"):
+                arg_params_, aux_params_ = self.get_params()
+                self.set_params(arg_params_, aux_params_)
             if epoch_end_callback is not None:
                 for callback in _as_list(epoch_end_callback):
                     callback(epoch, self.symbol, arg_params_, aux_params_)
@@ -325,8 +319,20 @@ class BaseModule:
             if ep_t0 is not None:
                 # non-lexical span (the epoch body is one loop pass):
                 # recorded via the raw clock + record() pair
-                _flight.record("fit_epoch", "train", ep_t0,
+                _flight.record("mx.fit.epoch", "train", ep_t0,
                                _flight.now_us(), step=epoch)
+
+    @staticmethod
+    def _fetch(data_iter, step):
+        """The next batch under ``mx.fit.data_fetch``; the span's clock
+        feeds the data-wait histogram, unless the iterator times its own
+        consumer-side stall (PrefetchingIter)."""
+        with span("mx.fit.data_fetch", cat="io", step=step) as sp:
+            batch = next(data_iter)
+        if _obs.ENABLED and not getattr(
+                data_iter, "_self_timed_data_wait", False):
+            _obs.DATA_WAIT_SECONDS.observe(sp.seconds)
+        return batch
 
     def _adopt_existing_bind(self, data_shapes, label_shapes, for_training,
                              inputs_need_grad=False, grad_req="write",

@@ -23,7 +23,7 @@ from .. import ndarray as nd
 from ..io import DataDesc
 from ..observability import memory as _memory
 from ..observability import metrics as _metrics
-from ..observability.tracing import trace_span
+from ..observability.tracing import span
 from .. import optimizer as opt
 from ..model import _create_kvstore, load_checkpoint, save_checkpoint
 from .base_module import BaseModule, _check_input_names
@@ -456,10 +456,11 @@ class Module(BaseModule):
         # a stale flag from a fused step whose update() was skipped must
         # not swallow the NEXT standard-path update
         self.__dict__.pop("_fused_stepped", None)
-        if self._maybe_fused_train_step(data_batch):
-            return
-        self._set_batch(data_batch, True)
-        self._exec.forward_backward()
+        with span("mx.module.forward_backward", cat="executor"):
+            if self._maybe_fused_train_step(data_batch):
+                return
+            self._set_batch(data_batch, True)
+            self._exec.forward_backward()
 
     # -- single-program train step (MXNET_FUSED_STEP=1) ---------------------
     def _fused_step_updater(self):
@@ -539,7 +540,8 @@ class Module(BaseModule):
             plan = ex._plan
             gset = list(grad_names)
 
-            def ftrain(params, states, aux, xs, key, lrs, wds, ts):
+            def mx_module_fused_step(params, states, aux, xs, key, lrs,
+                                     wds, ts):
                 merged = dict(params)
                 merged.update(xs)
 
@@ -567,7 +569,8 @@ class Module(BaseModule):
 
             # hold the plan ref: id() keys must not be recycled
             fs = {"key": fkey, "plan": plan,
-                  "fn": jax.jit(ftrain, donate_argnums=(0, 1, 2))}
+                  "fn": jax.jit(mx_module_fused_step,
+                                donate_argnums=(0, 1, 2))}
             self._fstep = fs
 
         snames = sorted(grad_names)
@@ -582,26 +585,27 @@ class Module(BaseModule):
         if _metrics.ENABLED:
             _metrics.XLA_LAUNCHES.inc(kind="fused_step")
             _metrics.OPTIMIZER_STEPS.inc()
-        with trace_span("fused_train_step", cat="executor"):
+        key = _random.next_key()
+        with span("mx.executor.launch", cat="executor"):
             outs, new_aux, new_p, new_s, nts = fs["fn"](
-                params, states, aux_vals, xs, _random.next_key(),
-                lrs, wds, ts)
+                params, states, aux_vals, xs, key, lrs, wds, ts)
         commit_ts(nts)
 
-        kv_store = (self._kvstore._store
-                    if kv_key and hasattr(self._kvstore, "_store")
-                    else None)
-        for n in pnames:
-            ex.arg_dict[n]._set_data(new_p[n])
-            if kv_store is not None and n in kv_store:
-                # keep the kvstore's weight copy current: a later
-                # pushpull/pull (eligibility flips mid-run) must not
-                # revert training to stale buffers
-                kv_store[n]._set_data(new_p[n])
-        for n in snames:
-            upd.states[ukeys[n]] = upd._state_writeback(
-                upd.states[ukeys[n]], new_s[n])
-        ex._set_results(outs, new_aux)
+        with span("mx.executor.deposit", cat="executor"):
+            kv_store = (self._kvstore._store
+                        if kv_key and hasattr(self._kvstore, "_store")
+                        else None)
+            for n in pnames:
+                ex.arg_dict[n]._set_data(new_p[n])
+                if kv_store is not None and n in kv_store:
+                    # keep the kvstore's weight copy current: a later
+                    # pushpull/pull (eligibility flips mid-run) must not
+                    # revert training to stale buffers
+                    kv_store[n]._set_data(new_p[n])
+            for n in snames:
+                upd.states[ukeys[n]] = upd._state_writeback(
+                    upd.states[ukeys[n]], new_s[n])
+            ex._set_results(outs, new_aux)
         ex._snapshot = None
         ex._pending_grads = None
         self._params_dirty = True
@@ -621,22 +625,23 @@ class Module(BaseModule):
         if self.__dict__.pop("_fused_stepped", False):
             return  # the fused train step already applied the update
         self._params_dirty = True
-        live = [(i, n) for i, n in enumerate(self._param_names)
-                if n in self._exec.grad_dict]
-        names = [n for _, n in live]
-        grads = [self._exec.grad_dict[n] for n in names]
-        if self._kvstore is not None:
-            if self._update_on_kvstore:
-                self._kvstore.pushpull(
-                    names, [[g] for g in grads],
-                    out=[[self._exec.arg_dict[n]] for n in names])
+        with span("mx.module.update", cat="optimizer"):
+            live = [(i, n) for i, n in enumerate(self._param_names)
+                    if n in self._exec.grad_dict]
+            names = [n for _, n in live]
+            grads = [self._exec.grad_dict[n] for n in names]
+            if self._kvstore is not None:
+                if self._update_on_kvstore:
+                    self._kvstore.pushpull(
+                        names, [[g] for g in grads],
+                        out=[[self._exec.arg_dict[n]] for n in names])
+                else:
+                    aggs = [nd.zeros(g.shape, dtype=g.dtype) for g in grads]
+                    self._kvstore.pushpull(names, [[g] for g in grads],
+                                           out=[[a] for a in aggs])
+                    self._update_local([i for i, _ in live], aggs, names)
             else:
-                aggs = [nd.zeros(g.shape, dtype=g.dtype) for g in grads]
-                self._kvstore.pushpull(names, [[g] for g in grads],
-                                       out=[[a] for a in aggs])
-                self._update_local([i for i, _ in live], aggs, names)
-        else:
-            self._update_local([i for i, _ in live], grads, names)
+                self._update_local([i for i, _ in live], grads, names)
 
     def _update_local(self, indices, grads, names):
         from ..optimizer import FusedUpdater

@@ -26,6 +26,8 @@ from ..analysis import sanitizer as _sanitizer
 from ..base import MXNetError, np_dtype
 from ..context import Context, current_context
 from ..observability import memory as _memory
+from ..observability import metrics as _metrics
+from ..observability.tracing import span
 from .. import engine as _engine
 
 
@@ -115,7 +117,10 @@ class NDArray:
         """Parity: NDArray::WaitToRead — block until the buffer is
         computed (via the engine, so the stall is metered)."""
         from .. import engine as _engine
-        _engine.wait_for_var(self._data)
+        if _metrics.ENABLED:
+            _metrics.HOST_SYNC_READS.inc()
+        with span("mx.sync.read", cat="sync"):
+            _engine.wait_for_var(self._data)
 
     wait_to_write = wait_to_read
 
@@ -127,7 +132,11 @@ class NDArray:
         # sanitizer chokepoint: inside an analysis.no_sync() region this
         # raises (MXNET_SANITIZE=1); one flag test otherwise
         _sanitizer.check_sync("NDArray.asnumpy")
-        out = _np.asarray(self._data)
+        if _metrics.ENABLED:
+            _metrics.HOST_SYNC_READS.inc()
+        # the host blocked on the device: the copy waits for the buffer
+        with span("mx.sync.read", cat="sync"):
+            out = _np.asarray(self._data)
         if not out.flags.writeable:
             out = out.copy()
         return out

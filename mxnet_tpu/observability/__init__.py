@@ -12,11 +12,12 @@ package makes that visibility a product API:
     device_put count + transfer bytes, jit cache hits/misses, engine
     wait stalls, kvstore push/pull bytes + allreduce latency, dataloader
     batch-wait time, HBM usage) with Prometheus-text and JSON exporters.
-  - `mxnet_tpu.observability.tracing` — `with trace_span("forward"):`
-    spans that land BOTH in the python-side Chrome-trace timeline
-    (`profiler._events`) and in the XLA xplane trace
-    (`jax.profiler.TraceAnnotation`), so host spans line up with device
-    ops in TensorBoard/Perfetto.
+  - `mxnet_tpu.observability.tracing` — `with span("mx.x"):`, the one
+    span primitive (`trace_span` and `flight.phase_span` are its older
+    names): a `jax.profiler.TraceAnnotation` in ANY profiler session
+    (host spans on the same clock as the device's operations), a
+    flight-ring record with parent and step, and the python-side
+    Chrome-trace mirror (`profiler._events`) while `mx.profiler` runs.
   - `dispatch_counts()` — the queryable per-kind XLA-launch/transfer
     tally that `tests/test_dispatch_count.py` pins as an invariant.
   - `mxnet_tpu.observability.flight` — the always-on flight recorder:
@@ -68,7 +69,7 @@ from .metrics import (Counter, Gauge, Histogram, MetricsRegistry, REGISTRY,
                       enabled, enable, disable, dispatch_counts,
                       step_dispatches, snapshot, render_prometheus,
                       render_json, hbm_stats)
-from .tracing import trace_span, step_span, annotate
+from .tracing import span, trace_span, step_span
 from .flight import phase_span, trace_scope, new_trace_id
 from .memory import memory_scope, oom_guard, DeviceMemoryError, HBMBudgetError
 
@@ -79,7 +80,7 @@ __all__ = [
     "Gauge", "Histogram", "MetricsRegistry", "REGISTRY", "enabled",
     "enable", "disable", "dispatch_counts", "step_dispatches", "snapshot",
     "render_prometheus", "render_json", "hbm_stats",
-    "trace_span", "step_span", "annotate",
+    "span", "trace_span", "step_span",
     "phase_span", "trace_scope", "new_trace_id",
     "memory_scope", "oom_guard", "DeviceMemoryError", "HBMBudgetError",
 ]

@@ -10,7 +10,8 @@ TensorFlow's production stall-attribution leans on the same timeline
 shape, arxiv 1605.08695):
 
   * **ring buffers of phase records** — ``phase_span("allreduce", ...)``
-    appends ``(name, cat, t0, t1, step, trace_id, labels)`` to a
+    (``tracing.span`` under its older name) appends
+    ``(name, cat, t0, t1, step, trace_id, labels, parent)`` to a
     fixed-size per-thread ring (``MXNET_FLIGHT_RING`` records/thread).
     Writes are lock-free after the first record on a thread: each
     thread owns its segment, so concurrent producers never contend
@@ -33,10 +34,11 @@ shape, arxiv 1605.08695):
     ``observability.snapshot()["flight"]``).
 
 Overhead contract (the ``MXNET_METRICS_ENABLED`` discipline):
-``MXNET_FLIGHT=0`` reduces every hook to ONE module-global boolean
-test — no timestamps, no tuple, no ring write.  Enabled, a span costs
-two ``perf_counter`` reads and one list-slot store; the bench ``flight``
-rider pins the fused-trainer overhead at ≤2% steps/s.
+``MXNET_FLIGHT=0`` takes the ring out of every hook — no timestamps, no
+tuple, no ring write; what stays is the span's profiler annotation
+(``tracing.span``).  Enabled, a span costs two ``perf_counter`` reads
+and one list-slot store; the bench ``flight`` rider pins the
+fused-trainer overhead at ≤2% steps/s.
 """
 from __future__ import annotations
 
@@ -49,10 +51,11 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
-from ..base import getenv, unique_path, atomic_write
+from ..base import getenv, unique_path, atomic_write, flight_dir
 from ..analysis import sanitizer as _san
 from . import goodput as _goodput
 from . import journal as _journal
+from . import tracing as _tracing
 
 log = logging.getLogger(__name__)
 
@@ -98,7 +101,7 @@ def disable() -> None:
 
 # -- ring storage ------------------------------------------------------------
 # Record tuple layout (indices are load-bearing for timeline.py):
-#   (name, cat, t0_us, t1_us, step, trace_id, labels)
+#   (name, cat, t0_us, t1_us, step, trace_id, labels, parent)
 class _Segment:
     """One thread's ring.  Only its owner thread writes; readers
     (dump/summary) snapshot ``buf``/``n`` without a lock — a slot being
@@ -169,9 +172,8 @@ MAX_DEAD_SEGMENTS = 16
 def _segment() -> _Segment:
     seg = getattr(_tls, "seg", None)
     if seg is None or seg.epoch != _epoch:
-        from .tracing import _tid
         t = threading.current_thread()
-        seg = _Segment(_tid(), t.name, RING, _epoch)
+        seg = _Segment(_tracing._tid(), t.name, RING, _epoch)
         with _seg_lock:
             dead = [s for s in _segments if not s.thread_alive]
             if len(dead) > MAX_DEAD_SEGMENTS:
@@ -228,16 +230,19 @@ def join_ids(ids) -> Optional[str]:
 # -- recording ---------------------------------------------------------------
 def record(name: str, cat: str, t0_us: float, t1_us: float,
            step: Optional[int] = None, trace_id: Optional[str] = None,
-           labels: Optional[dict] = None, watch: bool = False) -> None:
+           labels: Optional[dict] = None, watch: bool = False,
+           parent: Optional[str] = None) -> None:
     """Append one finished phase to this thread's ring.  Timestamps are
     microseconds on the ``time.perf_counter`` clock — the SAME clock
     ``tracing``/``profiler`` events use, so a merged dump orders
-    correctly across all three sources."""
+    correctly across all three sources.  ``parent`` names the span that
+    was open on the thread when this one opened."""
     if not ENABLED:
         return
     if trace_id is None:
         trace_id = getattr(_tls, "trace", None)
-    _segment().add((name, cat, t0_us, t1_us, step, trace_id, labels))
+    _segment().add((name, cat, t0_us, t1_us, step, trace_id, labels,
+                    parent))
     if _goodput.ENABLED:
         # one boolean + one dict lookup: top-level unit-of-work spans
         # feed the run's goodput ledger (docs/goodput.md)
@@ -254,45 +259,9 @@ def _mem_live():
     return _mem.tracked_bytes() if _mem.ENABLED else None
 
 
-@contextlib.contextmanager
-def phase_span(name: str, cat: str = "phase", step: Optional[int] = None,
-               trace_id: Optional[str] = None,
-               labels: Optional[dict] = None, watch: bool = False,
-               mem: bool = False):
-    """The flight-recorder primitive: time the body and ring-record it.
-
-    ``MXNET_FLIGHT=0``: ONE boolean test, nothing else.  ``watch=True``
-    additionally feeds the slow-phase watchdog (k×EWMA anomaly dump).
-    ``mem=True`` samples the HBM ledger's tracked device bytes at entry
-    and exit (two O(1) counter reads; skipped when
-    ``MXNET_MEMORY_LEDGER=0``) and labels the record with
-    ``mem_delta_bytes``/``mem_live_bytes`` — the per-phase memory
-    timeline: ``dump()`` renders these as a Perfetto counter track, so
-    the timeline shows WHICH phase grew HBM.  The sampled counter is
-    PROCESS-global: a concurrent thread allocating inside this span's
-    window (e.g. the prefetcher staging the next batch during a
-    trainer step) lands in this span's delta too — read overlapping
-    spans' deltas together, per-tag truth lives in ``memory.report()``.
-    Phase ``name``s must come from a bounded literal set — the
-    metrics-hygiene graft-lint rule rejects dynamically built names
-    (every distinct name is a forever-entry in ``summary()``).
-    """
-    if not ENABLED:
-        yield
-        return
-    t0 = _now_us()
-    m0 = _mem_live() if mem else None
-    try:
-        yield
-    finally:
-        if m0 is not None:
-            m1 = _mem_live()
-            if m1 is not None:
-                labels = dict(labels) if labels else {}
-                labels["mem_delta_bytes"] = int(m1 - m0)
-                labels["mem_live_bytes"] = int(m1)
-        record(name, cat, t0, _now_us(), step=step, trace_id=trace_id,
-               labels=labels, watch=watch)
+#: the flight-recorder primitive IS the tracing primitive (one function,
+#: two names): ring record while ``ENABLED``, host-plane annotation always
+phase_span = _tracing.span
 
 
 # -- watchdog ----------------------------------------------------------------
@@ -397,7 +366,9 @@ def dump(path: Optional[str] = None, reason: str = "manual",
     """Write the ring (+ the profiler's ``_events``) as Chrome
     trace-event JSON, atomically — open the file in Perfetto / chrome
     about:tracing.  ``path=None`` writes a collision-free timestamped
-    file under ``MXNET_FLIGHT_DIR`` (default ``.``); ``clock`` is the
+    file under ``MXNET_FLIGHT_DIR`` (default
+    ``<tempfile.gettempdir()>/mxnet_flight``: never the working
+    directory, which may be the tree a run measures); ``clock`` is the
     injectable timestamp source for the filename (tests pin it)."""
     global _dump_count, _last_dump_path
     from . import timeline as _timeline
@@ -412,9 +383,7 @@ def dump(path: Optional[str] = None, reason: str = "manual",
     trace = _timeline.build_trace(records(), list(_prof._events),
                                   meta=meta)
     if path is None:
-        d = os.environ.get("MXNET_FLIGHT_DIR", ".") or "."
-        os.makedirs(d, exist_ok=True)
-        path = unique_path(d, "flight", ".json", clock=clock)
+        path = unique_path(flight_dir(), "flight", ".json", clock=clock)
     atomic_write(path, json.dumps(trace))
     _dump_count += 1
     _last_dump_path = path
@@ -446,9 +415,11 @@ def snapshot_summary() -> dict:
 
 # -- lifecycle ---------------------------------------------------------------
 def reset() -> None:
-    """Drop every segment/record and the watchdog state (tests).  Other
-    threads' next record lands in a fresh segment (epoch bump)."""
+    """Drop every segment/record, the watchdog state and the calling
+    thread's current step (tests).  Other threads' next record lands in
+    a fresh segment (epoch bump)."""
     global _epoch, _last_auto_dump
+    _tracing.set_step(None)
     with _seg_lock:
         _epoch += 1
         _segments.clear()

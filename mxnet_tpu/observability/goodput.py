@@ -8,7 +8,7 @@ This module keeps that account.  Every second of a training or serving
 run is attributed to exactly one of a small, fixed list of classes:
 
   ==================  =====================================================
-  ``compute``         useful work — flight's ``trainer_step`` /
+  ``compute``         useful work — flight's ``mx.trainer.step`` /
                       ``whole_step`` / ``serve_dispatch`` spans
   ``data_wait``       input starvation — prefetch/batch-wait spans
   ``checkpoint_block``  synchronous checkpoint save time
@@ -79,14 +79,14 @@ _BADPUT_CLASSES = frozenset(CLASSES) - {"compute", "unattributed"}
 
 #: flight span name -> badput class.  Only TOP-LEVEL unit-of-work
 #: spans appear here — nested phases (h2d/allreduce/fused_update inside
-#: trainer_step) must NOT, or their seconds would double-count.
+#: mx.trainer.step) must NOT, or their seconds would double-count.
 _SPAN_CLASS: Dict[str, str] = {
-    "trainer_step": "compute",
+    "mx.trainer.step": "compute",
     "whole_step": "compute",
     "superstep": "compute",
     "serve_dispatch": "compute",
     "prefetch_wait": "data_wait",
-    "data_wait": "data_wait",
+    "mx.fit.data_fetch": "data_wait",
     "checkpoint_block": "checkpoint_block",
     "serve_evict": "eviction_churn",
     "serve_readmit": "eviction_churn",

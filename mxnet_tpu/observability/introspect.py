@@ -71,7 +71,8 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 
-from ..base import MXNetError, atomic_write, getenv, unique_path
+from ..base import (MXNetError, atomic_write, flight_dir, getenv,
+                    unique_path)
 from ..analysis import sanitizer as _san
 
 log = logging.getLogger(__name__)
@@ -108,14 +109,14 @@ UNATTRIBUTED = "_unattributed"
 
 #: training-step phase -> program name the MFU/sentinel math pairs it
 #: with (the fused path's step splits across three programs)
-PHASE_PROGRAM = {"whole_step": "whole_step", "trainer_step": "fused_update",
+PHASE_PROGRAM = {"whole_step": "whole_step", "mx.trainer.step": "fused_update",
                  "superstep": "superstep"}
 #: programs whose flops sum to one FUSED-path training step (CachedOp
 #: bwd recomputes the forward inside its fused vjp program)
 FUSED_STEP_PROGRAMS = ("gluon:fwd", "gluon:bwd", "fused_update")
 #: phases whose flight span covers the WHOLE training step — only these
 #: may serve as the denominator for step-flops rates.  The fused path's
-#: "trainer_step" span times Trainer.step alone (allreduce+update; the
+#: "mx.trainer.step" span times Trainer.step alone (allreduce+update; the
 #: user's fwd/bwd run outside it), so dividing full-step flops by it
 #: would overstate MFU severalfold — fused-path MFU needs an explicit
 #: step_time_s (the bench mfu rider measures its own).
@@ -330,7 +331,7 @@ def dump_hlo(name: str, directory: Optional[str] = None) -> str:
             f"no HLO captured for program {name!r} — set "
             f"MXNET_INTROSPECT_HLO=1 before the program compiles "
             f"(captured: {sorted(programs())})")
-    d = directory or os.environ.get("MXNET_FLIGHT_DIR", ".") or "."
+    d = directory or flight_dir()
     os.makedirs(d, exist_ok=True)
     safe = re.sub(r"[^\w.-]", "-", name)
     path = unique_path(d, f"hlo-{safe}", ".txt")
@@ -562,7 +563,7 @@ def step_flops() -> Tuple[Optional[float], Optional[float], Optional[str]]:
     if parts and any(p.get("flops") for p in parts):
         return (sum(p.get("flops") or 0.0 for p in parts),
                 sum(p.get("bytes") or 0.0 for p in parts) or None,
-                "trainer_step")
+                "mx.trainer.step")
     return None, None, None
 
 
@@ -625,7 +626,7 @@ def phase_flops_map() -> Dict[str, float]:
     spans cover a whole training step — the feed for the Perfetto
     ``mxnet_flops_per_s`` counter track (timeline.chrome_events).
     Restricted to FULL_STEP_PHASES: emitting the fused path's
-    fwd+bwd+update flops over the "trainer_step" span (which times only
+    fwd+bwd+update flops over the "mx.trainer.step" span (which times only
     allreduce+update) would render impossible flops/s."""
     flops, _b, phase = step_flops()
     return {phase: flops} if phase in FULL_STEP_PHASES and flops else {}
@@ -838,7 +839,7 @@ def _sentinel_check(phase: str) -> None:
     from . import metrics as _metrics
     if _metrics.ENABLED:
         # kind/phase are bounded literal sets (step_time|dispatches x
-        # whole_step|trainer_step)
+        # whole_step|mx.trainer.step)
         _metrics.PERF_REGRESSIONS.inc(kind=kind, phase=phase)
     from . import journal as _journal
     if _journal.ENABLED:

@@ -59,7 +59,8 @@ import time
 import weakref
 from typing import Dict, List, Optional
 
-from ..base import MXNetError, getenv, atomic_write, unique_path
+from ..base import (MXNetError, getenv, atomic_write, flight_dir,
+                    unique_path)
 from ..analysis import sanitizer as _san
 
 log = logging.getLogger(__name__)
@@ -661,7 +662,7 @@ def oom_guard(site: str):
         raise DeviceMemoryError(
             f"device memory exhausted at {site} — post-mortem (ledger "
             f"report + flight ring) dumping to "
-            f"{os.environ.get('MXNET_FLIGHT_DIR', '.') or '.'}; "
+            f"{flight_dir()}; "
             f"original: {type(e).__name__}: {e}") from e
 
 
@@ -706,9 +707,7 @@ def _bg_oom_dump(site: str, rec: dict) -> None:
             # vice versa below) — pivot from badput row to timeline
             rec["run_id"] = _journal.run_id()
             rec["journal_path"] = _journal.path()
-        d = os.environ.get("MXNET_FLIGHT_DIR", ".") or "."
-        os.makedirs(d, exist_ok=True)
-        path = unique_path(d, "oom", ".json")
+        path = unique_path(flight_dir(), "oom", ".json")
         atomic_write(path, json.dumps(
             {"oom": dict(rec), "report": report(top=20)},
             default=str))

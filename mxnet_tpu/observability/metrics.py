@@ -385,6 +385,18 @@ JIT_CACHE_HITS = Counter(
 JIT_CACHE_MISSES = Counter(
     "mxnet_jit_cache_misses_total",
     "Executor compiled-entry-point cache misses (new jit closures)")
+HOST_SYNC_READS = Counter(
+    "mxnet_host_sync_reads_total",
+    "Blocking reads of a device buffer by the host (NDArray.asnumpy / "
+    "asscalar / wait_to_read): counted beside the mx.sync.read span")
+PROGRAM_LOADS = Counter(
+    "mxnet_program_loads_total",
+    "XLA programs made ready to run, by how: compile (the backend "
+    "compiled it) or cache (read from JAX's persistent compilation "
+    "cache); from JAX's own monitoring events")
+PROGRAM_LOAD_SECONDS = Counter(
+    "mxnet_program_load_seconds_total",
+    "Seconds JAX reports for those compiles and cache reads")
 ENGINE_WAITS = Counter(
     "mxnet_engine_wait_total",
     "Engine blocking waits by kind (wait_for_var, wait_for_all)")
@@ -861,6 +873,22 @@ def step_dispatches() -> float:
             + DEVICE_PUTS.value)
 
 
+def step_counts() -> Tuple[float, float, float, float]:
+    """(launches without kind="data", device_puts, host sync reads,
+    program loads) so far: two calls bracket a step."""
+    return (XLA_LAUNCHES.value - XLA_LAUNCHES.get(kind="data"),
+            DEVICE_PUTS.value, HOST_SYNC_READS.value, PROGRAM_LOADS.value)
+
+
+def step_deltas(since, now=None) -> Dict[str, float]:
+    """What a step's ring record carries: the ``step_counts()`` from
+    ``since`` to ``now`` (default: this moment)."""
+    now = now or step_counts()
+    return {"launches": now[0] - since[0], "device_puts": now[1] - since[1],
+            "sync_reads": now[2] - since[2],
+            "program_loads": now[3] - since[3]}
+
+
 def dispatch_counts() -> Dict[str, float]:
     """Per-kind dispatch tally since process start (or the last
     `REGISTRY.reset()`): compiled-program launches keyed `xla:<kind>`
@@ -983,6 +1011,10 @@ def snapshot() -> dict:
         "engine_wait_seconds": ENGINE_WAIT_SECONDS.value,
         "jit_cache": {"hits": JIT_CACHE_HITS.value,
                       "misses": JIT_CACHE_MISSES.value},
+        "host_sync_reads": HOST_SYNC_READS.value,
+        "program_loads": {"compile": PROGRAM_LOADS.get(how="compile"),
+                          "cache": PROGRAM_LOADS.get(how="cache"),
+                          "seconds": PROGRAM_LOAD_SECONDS.value},
         "optimizer_steps": OPTIMIZER_STEPS.value,
         "fused_dtype_recompiles": FUSED_DTYPE_RECOMPILES.value,
         "serving": {
@@ -1080,3 +1112,37 @@ def render_prometheus() -> str:
 def render_json() -> str:
     _refresh_export_gauges()
     return REGISTRY.render_json()
+
+
+# -- program loads: JAX's own monitoring events ------------------------------
+# JAX reports "/jax/core/compile/backend_compile_duration" around every
+# compile-or-read-from-cache of a program (with the program's name), and
+# "/jax/compilation_cache/cache_retrieval_time_sec" inside it when the
+# persistent cache had the program.
+_load_tls = threading.local()
+
+
+def _on_jax_duration(event: str, secs: float, **kw) -> None:
+    if not ENABLED:
+        return
+    if event.endswith("/cache_retrieval_time_sec"):
+        _load_tls.from_cache = True
+        return
+    if not event.endswith("/backend_compile_duration"):
+        return
+    how = "cache" if getattr(_load_tls, "from_cache", False) else "compile"
+    _load_tls.from_cache = False
+    PROGRAM_LOADS.inc(how=how)
+    PROGRAM_LOAD_SECONDS.inc(secs)
+    from . import flight, tracing
+    if flight.ENABLED:
+        t1 = flight.now_us()
+        parent, step = tracing.context()
+        flight.record("mx.program.load", "compile", t1 - secs * 1e6, t1,
+                      step=step, parent=parent,
+                      labels={"how": how, "program": kw.get("fun_name")})
+
+
+import jax.monitoring  # noqa: E402
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)

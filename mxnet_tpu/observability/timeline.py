@@ -64,7 +64,7 @@ def chrome_events(flight_records: List[tuple]) -> List[dict]:
     # the counter walks records in span-end order regardless of which
     # thread segment recorded them
     for _, rec in sorted(flight_records, key=lambda p: p[1][3]):
-        name, _, t0, t1, _, _, _ = rec
+        name, t0, t1 = rec[0], rec[2], rec[3]
         cls = badput_map.get(name)
         if cls is None or t1 <= t0:
             continue
@@ -76,7 +76,7 @@ def chrome_events(flight_records: List[tuple]) -> List[dict]:
                        "ts": t1, "pid": PID,
                        "args": {cls: round(badput_cum[cls], 6)}})
     for seg, rec in flight_records:
-        name, cat, t0, t1, step, trace_id, labels = rec
+        name, cat, t0, t1, step, trace_id, labels, parent = rec
         seen_tids.setdefault(seg.tid, seg.thread_name)
         ev = {"name": name, "cat": cat, "ph": "X", "ts": t0,
               "dur": t1 - t0, "pid": PID, "tid": seg.tid}
@@ -85,6 +85,8 @@ def chrome_events(flight_records: List[tuple]) -> List[dict]:
             args["step"] = step
         if trace_id is not None:
             args["trace_id"] = trace_id
+        if parent is not None:
+            args["parent"] = parent
         if labels:
             args.update(labels)
         if args:

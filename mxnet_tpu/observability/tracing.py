@@ -1,38 +1,72 @@
-"""Structured tracing spans that land in BOTH timelines.
+"""The one span primitive: ``with span("mx.executor.launch", cat=...):``.
 
-`with trace_span("forward"):` emits
-  - a python-side Chrome-trace complete event ("X") into the profiler's
-    `_events` buffer (dumped by `profiler.dump_profile()`), and
-  - a `jax.profiler.TraceAnnotation` scope, so the same span shows up
-    inside the XLA xplane trace next to the device ops it covers
-    (TensorBoard / Perfetto line the two up by wall-clock).
+A span lands in up to three places, each behind the switch that already
+existed for it:
 
-`step_span(step)` additionally uses `jax.profiler.StepTraceAnnotation`,
-which TensorBoard's profile plugin uses for per-step breakdowns — and,
-since ISSUE 8, feeds the always-on flight recorder
-(`observability.flight`) even while the profiler is paused/stopped:
-both timelines stamp the SAME `time.perf_counter()` monotonic clock,
-so flight records and profiler `_events` can never disagree on t0/t1
-ordering.
+  - the **profiler's host plane**, always: a
+    ``jax.profiler.TraceAnnotation(name)``.  With no profiler session it
+    is the same atomic test JAX pays around every jitted call; with one
+    (``mx.profiler.set_state('run')`` *or* a plain
+    ``jax.profiler.start_trace``) the span is written to the same
+    ``*.xplane.pb`` as the device's operations: one file, one clock.
+  - the **flight ring** while ``flight.ENABLED``: one record
+    ``(name, cat, t0, t1, step, trace_id, labels, parent)``.  ``parent``
+    is the name of the span open on this thread when this one opened;
+    ``step`` is inherited from the enclosing span where not given, else
+    from the thread's current step (``set_step``).
+  - the profiler's Chrome ``_events`` (what ``mx.profiler.dump_profile()``
+    writes) while ``mx.profiler.is_recording()``.
 
-Fast path: when the profiler is stopped, a `trace_span` is ONE
-predicate test — no timestamps, no annotation objects, no allocation
-beyond the generator frame.  Nesting is expressed the Chrome-trace way:
-events on the same pid/tid whose [ts, ts+dur] ranges contain each other
-render nested.
+Spans of category ``"step"`` cover a whole step or more (``mx.step``, the
+whole-step programs).  They go to the ring and the Chrome mirror only, not
+to the host plane: a reader of the profiler's trace that labels a device
+idle gap by the host span overlapping it most (chipbench/trace_reduce.py
+``label_gap``) would give every gap to the enclosing step span.
+
+``trace_span`` and ``flight.phase_span`` are names of this one function.
+Span names are literals (the metrics-hygiene lint rejects built names);
+the ``mx.`` names of the two training step paths are listed in
+``SPAN_NAMES``.
 """
 from __future__ import annotations
 
-import contextlib
-import sys
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 from ..analysis.sanitizer import make_lock as _make_lock
+
+#: every ``mx.`` span and ring-record name the program emits
+SPAN_NAMES = (
+    # Module.fit (module/base_module.py, module/module.py, executor.py)
+    "mx.fit.epoch", "mx.step",
+    "mx.module.forward_backward",
+    "mx.executor.gather", "mx.executor.launch", "mx.executor.deposit",
+    "mx.module.update", "mx.kvstore.pushpull", "mx.optimizer.update_all",
+    "mx.fit.data_fetch", "mx.module.prepare", "mx.module.update_metric",
+    "mx.fit.callbacks", "mx.fit.epoch_end",
+    # Gluon (gluon/block.py, autograd.py, gluon/trainer.py)
+    "mx.cachedop.forward", "mx.autograd.backward", "mx.cachedop.backward",
+    "mx.trainer.step", "mx.trainer.allreduce",
+    # both (random.py, ndarray/ndarray.py, the jax.monitoring listener)
+    "mx.rng.next_key", "mx.sync.read", "mx.program.load",
+)
 
 _tls = threading.local()
 _tid_lock = _make_lock("tracing.tid")
 _tid_map: dict = {}
+# bound on the first span: flight imports this module (phase_span is
+# span), and profiler imports the package
+_flight = _profiler = None
+
+
+def _bind():
+    global _flight, _profiler
+    from . import flight
+    from .. import profiler
+    _flight, _profiler = flight, profiler
+    return flight
 
 
 def _tid() -> int:
@@ -47,129 +81,131 @@ def _tid() -> int:
     return t
 
 
+def _stack() -> list:
+    s = getattr(_tls, "stack", None)
+    if s is None:
+        s = _tls.stack = []
+    return s
+
+
 def _depth() -> int:
-    return getattr(_tls, "depth", 0)
+    """Spans open on this thread."""
+    return len(_stack())
 
 
-def _profiler():
-    from .. import profiler
-    return profiler
+def set_step(step) -> None:
+    """This thread's current step: what a span with no ``step`` of its
+    own and no enclosing span records.  ``Trainer.step`` sets it on
+    return, so a Gluon step runs from one return to the next."""
+    _tls.step = step
 
 
-def _flight():
-    from . import flight
-    return flight
+def context():
+    """(name of the innermost span open on this thread, the step a span
+    opened now would record): for ring records written without a span
+    (``flight.record``)."""
+    stack = _stack()
+    if stack:
+        return stack[-1].name, stack[-1].step
+    return None, getattr(_tls, "step", None)
 
 
-def _annotation(name: str):
-    try:
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return None
+class span:
+    """Time the body under ``name`` (see the module docstring).
 
+    ``watch=True`` feeds the flight recorder's slow-phase watchdog;
+    ``mem=True`` samples the HBM ledger at entry and exit and labels the
+    ring record with ``mem_delta_bytes`` / ``mem_live_bytes``;
+    ``trace_id`` / ``labels`` go into the ring record (``trace_id``
+    defaults to the thread's ``flight.trace_scope``; a ``labels`` dict
+    may be filled until the body ends).  After the body, ``seconds`` is
+    what it took: callers that also feed a histogram read this clock
+    pair, not one of their own."""
 
-@contextlib.contextmanager
-def trace_span(name: str, cat: str = "runtime"):
-    """Record `name` as a nested span on both timelines while the
-    profiler runs; a no-op predicate test otherwise.
+    __slots__ = ("name", "cat", "step", "trace_id", "labels", "watch",
+                 "mem", "parent", "seconds", "_ann", "_t0", "_m0",
+                 "_ring", "_prof")
 
-    Exception-safe depth accounting: the increment/decrement pair and
-    the event record sit in `finally` blocks ordered so that a raising
-    body (or a raising annotation `__exit__`) can neither leak a depth
-    level nor lose the event — the profiler `_events` buffer and the
-    flight ring must agree on span nesting after an exception unwinds
-    through a step."""
-    prof = _profiler()
-    if not prof.is_recording():
-        yield
-        return
-    ann = _annotation(name)
-    start = time.perf_counter() * 1e6
-    _tls.depth = _depth() + 1
-    entered = False
-    try:
-        if ann is not None:
-            ann.__enter__()
-            entered = True
+    def __init__(self, name: str, cat: str = "runtime", step=None,
+                 trace_id=None, labels=None, watch: bool = False,
+                 mem: bool = False):
+        self.name = name
+        self.cat = cat
+        self.step = step
+        self.trace_id = trace_id
+        self.labels = labels
+        self.watch = watch
+        self.mem = mem
+
+    def __enter__(self):
+        flight = _flight or _bind()
+        stack = _stack()
+        if stack:
+            up = stack[-1]
+            self.parent = up.name
+            if self.step is None:
+                self.step = up.step
+        else:
+            self.parent = None
+            if self.step is None:
+                self.step = getattr(_tls, "step", None)
+        stack.append(self)
+        self._ring = flight.ENABLED
+        self._prof = _profiler.is_recording()
+        self._m0 = flight._mem_live() if self.mem and self._ring else None
+        self._t0 = time.perf_counter() * 1e6
+        if self.cat != "step":
+            self._ann = TraceAnnotation(self.name)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        return self
+
+    def __exit__(self, etype, exc, tb):
         try:
-            yield
-        except BaseException:
-            # the annotation sees exactly the exception unwinding
-            # through the SPAN BODY — never an unrelated outer
-            # exception sys.exc_info() would report on a normal
-            # completion inside an except handler, and never an
-            # __exit__ on an annotation whose __enter__ raised
-            if entered:
-                entered = False
-                ann.__exit__(*sys.exc_info())
-            raise
-        if entered:
-            entered = False
-            ann.__exit__(None, None, None)
-    finally:
-        end = time.perf_counter() * 1e6
-        _tls.depth = _depth() - 1
-        prof.record_event(name, start, end, cat=cat, tid=_tid(),
-                          args={"depth": _depth()})
+            if self._ann is not None:
+                # exactly the exception unwinding through the span body
+                self._ann.__exit__(etype, exc, tb)
+        finally:
+            t1 = time.perf_counter() * 1e6
+            self.seconds = (t1 - self._t0) / 1e6
+            stack = _stack()
+            if stack and stack[-1] is self:
+                stack.pop()
+            elif self in stack:
+                stack.remove(self)
+            if self._ring or self._prof:
+                self._record(t1, len(stack))
+        return False
+
+    def _record(self, t1, depth):
+        flight, profiler = _flight, _profiler
+        if self._prof:
+            args = {"depth": depth}
+            if self.step is not None:
+                args["step"] = self.step
+            profiler.record_event(self.name, self._t0, t1, cat=self.cat,
+                                  tid=_tid(), args=args)
+        if self._ring:
+            labels = self.labels
+            if self._m0 is not None:
+                m1 = flight._mem_live()
+                if m1 is not None:
+                    labels = dict(labels) if labels else {}
+                    labels["mem_delta_bytes"] = int(m1 - self._m0)
+                    labels["mem_live_bytes"] = int(m1)
+            flight.record(self.name, self.cat, self._t0, t1,
+                          step=self.step, trace_id=self.trace_id,
+                          labels=labels, watch=self.watch,
+                          parent=self.parent)
 
 
-@contextlib.contextmanager
+trace_span = span
+
+
 def step_span(step_num: int, name: str = "train"):
-    """Step-boundary annotation: xplane StepTraceAnnotation (feeds
-    TensorBoard's per-step breakdown) + a Chrome-trace span + an
-    always-on flight-recorder step record.
-
-    The flight record uses the monotonic `perf_counter` clock whether
-    or not the profiler is running — in particular while the profiler
-    is PAUSED (is_running but not recording), the step still lands in
-    the ring with correctly ordered t0/t1, so a later resume cannot
-    interleave out-of-order events between the two timelines.  It also
-    feeds the slow-step watchdog (`flight.note`)."""
-    prof = _profiler()
-    rec = prof.is_recording()
-    fl = _flight()
-    if not rec and not fl.ENABLED:
-        yield
-        return
-    ann = None
-    if rec:
-        try:
-            import jax
-            ann = jax.profiler.StepTraceAnnotation(name, step_num=step_num)
-            ann.__enter__()
-        except Exception:
-            ann = None
-    # bounded by construction: callers pass literal step-stream names
-    # ("train"), so the derived record name is one entry per stream
+    """A step-level span ``<name>_step`` carrying ``step_num``, for loops
+    outside this package (``Module.fit`` opens ``mx.step`` itself)."""
+    # bounded by construction: callers pass literal stream names
     rec_name = name + "_step"
-    start = time.perf_counter() * 1e6
-    try:
-        try:
-            yield
-        except BaseException:
-            # only a body exception reaches the annotation (see
-            # trace_span): normal completion inside an outer except
-            # handler must not report that handler's exception
-            if ann is not None:
-                a, ann = ann, None
-                a.__exit__(*sys.exc_info())
-            raise
-        if ann is not None:
-            a, ann = ann, None
-            a.__exit__(None, None, None)
-    finally:
-        end = time.perf_counter() * 1e6
-        if rec:
-            prof.record_event(rec_name, start, end, cat="step",
-                              tid=_tid(), args={"step": step_num})
-        if fl.ENABLED:
-            fl.record(rec_name, "step", start, end, step=step_num,
-                      watch=True)
-
-
-def annotate(name: str):
-    """Bare xplane annotation (no python-side event) — for spans that
-    only matter relative to device ops."""
-    ann = _annotation(name)
-    return ann if ann is not None else contextlib.nullcontext()
+    return span(rec_name, cat="step", step=step_num, watch=True)

@@ -26,7 +26,7 @@ from .faultinject import fire as _fi_fire
 from .observability import introspect as _introspect
 from .observability import memory as _memory
 from .observability import metrics as _metrics
-from .observability.tracing import trace_span
+from .observability.tracing import span
 
 _REG = Registry("optimizer")
 _logger = logging.getLogger("mxnet_tpu.optimizer")
@@ -1169,7 +1169,7 @@ class FusedUpdater(Updater):
         def _build():
             idx = list(indices)
 
-            def _apply(wv, gv, sv, lrs, wds, ts):
+            def mx_fused_update(wv, gv, sv, lrs, wds, ts):
                 # the fused optimizer math traces under one literal
                 # named scope, so per_layer() attributes its HLO
                 # instructions to "optimizer" (ISSUE 13)
@@ -1195,7 +1195,7 @@ class FusedUpdater(Updater):
             # still alias their buffers in the general case.  Flat grad
             # buckets are NOT donated: no output shares their shape, so
             # donation could never alias and would only warn.
-            return jax.jit(_apply,
+            return jax.jit(mx_fused_update,
                            donate_argnums=(0, 2) if donate_weights else (2,))
 
         fn = self.lookup_program(key, _build)
@@ -1233,7 +1233,7 @@ class FusedUpdater(Updater):
         # OOM post-mortem chokepoint: the fused multi-tensor update is
         # the other program that holds a whole model (+states) live;
         # the memory.oom chaos site injects a synthetic one here
-        with trace_span("optimizer_update_all", cat="optimizer"), \
+        with span("mx.optimizer.update_all", cat="optimizer", mem=True), \
                 _memory.oom_guard("optimizer.update_all"):
             _fi_fire("memory.oom", at="optimizer")
             # transient-device chaos site at the fused-update dispatch
@@ -1255,10 +1255,11 @@ class FusedUpdater(Updater):
                         *[self.states[i] for i in indices],
                         *(list(weights) if donate_weights else []))
                 raise
-        commit_ts(nts)
-        for k, i in enumerate(indices):
-            weights[k]._set_data(nws[k])
-            self.states[i] = self._state_writeback(self.states[i], nss[k])
+            commit_ts(nts)
+            for k, i in enumerate(indices):
+                weights[k]._set_data(nws[k])
+                self.states[i] = self._state_writeback(self.states[i],
+                                                       nss[k])
 
     def _rowable_state(self, state, vocab) -> bool:
         """True when every state leaf is a DENSE per-row slab (leading dim
@@ -1356,7 +1357,7 @@ class FusedUpdater(Updater):
         def _build():
             idx = list(indices)
 
-            def _apply(wv, iv, gv, sv, lrs, wds, ts):
+            def mx_sparse_update(wv, iv, gv, sv, lrs, wds, ts):
                 with _introspect.layer_scope("optimizer"):
                     nws, nss = [], []
                     for k in range(len(wv)):
@@ -1389,7 +1390,7 @@ class FusedUpdater(Updater):
             # aliases; weights join only under donate_weights (same
             # caveat as update_all: user-held views may alias them).
             # The padded id/row slabs are NOT donated (wrong shapes).
-            return jax.jit(_apply,
+            return jax.jit(mx_sparse_update,
                            donate_argnums=(0, 3) if donate_weights else (3,))
 
         fn = self.lookup_program(key, _build)
@@ -1410,7 +1411,7 @@ class FusedUpdater(Updater):
         if _metrics.ENABLED:
             _metrics.XLA_LAUNCHES.inc(kind="optimizer")
             _metrics.OPTIMIZER_STEPS.inc()
-        with trace_span("optimizer_update_sparse", cat="optimizer"), \
+        with span("mx.optimizer.update_all", cat="optimizer", mem=True), \
                 _memory.oom_guard("optimizer.update_sparse"):
             _fi_fire("memory.oom", at="optimizer")
             _fi_fire("device.unavailable", at="optimizer")
@@ -1423,10 +1424,11 @@ class FusedUpdater(Updater):
                         *[self.states[i] for i in indices],
                         *(list(weights) if donate_weights else []))
                 raise
-        commit_ts(nts)
-        for k, i in enumerate(indices):
-            weights[k]._set_data(nws[k])
-            self.states[i] = self._state_writeback(self.states[i], nss[k])
+            commit_ts(nts)
+            for k, i in enumerate(indices):
+                weights[k]._set_data(nws[k])
+                self.states[i] = self._state_writeback(self.states[i],
+                                                       nss[k])
 
 
 def get_updater(optimizer: Optimizer) -> Updater:

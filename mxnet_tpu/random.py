@@ -25,6 +25,9 @@ def _get():
 # draw from the engine RNG that mx.random.seed controls; ours draw host-
 # side, so the framework owns its own stream — never numpy's global one)
 import numpy as _np
+
+from .observability.tracing import span  # noqa: E402
+
 host_rng = _np.random.RandomState(0)
 
 
@@ -36,9 +39,11 @@ def seed(seed_state: int) -> None:
 
 
 def next_key():
-    key = _get()
-    _state.key, sub = jax.random.split(key)
-    return sub
+    # two launches a call (split, unstack): a span of its own
+    with span("mx.rng.next_key", cat="rng"):
+        key = _get()
+        _state.key, sub = jax.random.split(key)
+        return sub
 
 
 # nd-level sampling functions are attached in ndarray.random (autogen);

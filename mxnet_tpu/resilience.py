@@ -38,7 +38,7 @@ import threading
 import time
 from typing import Dict, Optional
 
-from .base import MXNetError, atomic_write, unique_path
+from .base import MXNetError, atomic_write, flight_dir, unique_path
 
 log = logging.getLogger(__name__)
 
@@ -209,9 +209,7 @@ def post_mortem(reason: str, step: Optional[int] = None,
         if _memory.ENABLED:
             payload["memory"] = _memory.report()
         payload["watch"] = _flight.watch_state()
-        d = os.environ.get("MXNET_FLIGHT_DIR", ".") or "."
-        os.makedirs(d, exist_ok=True)
-        path = unique_path(d, f"postmortem-{reason}", ".json")
+        path = unique_path(flight_dir(), f"postmortem-{reason}", ".json")
         atomic_write(path, json.dumps(payload, default=str))
         info["report_path"] = path
     except Exception as e:  # noqa: BLE001 — a failed dump must not mask

@@ -44,7 +44,6 @@ from ..observability import flight as _flight
 from ..observability import introspect as _introspect
 from ..observability import memory as _memory
 from ..observability import metrics as _metrics
-from ..observability.tracing import trace_span
 from .. import symbol as sym_mod
 from ..symbol import Symbol
 from ..symbol.graph import GraphPlan
@@ -486,21 +485,20 @@ class BucketedPredictor:
                 raise ModelEvictedError(
                     "model weights were evicted between precompile and "
                     "dispatch — readmit() and retry")
-            with trace_span("serve_dispatch", cat="serving"):
-                try:
-                    return compiled(padded, extra, params, aux,
-                                    self._rng)
-                except BaseException:
-                    # MXNET_SANITIZE twin (ISSUE 15): with donation on,
-                    # a failed dispatch may have consumed the padded
-                    # input buffers — poison the batch dict in place so
-                    # a retry that erroneously reuses it fails typed
-                    # (DonatedBufferError) instead of serving deleted
-                    # arrays.  One boolean test when off.
-                    if self._donate and _sanitizer.ENABLED:
-                        _sanitizer.poison_mapping("serve_dispatch",
-                                                  padded)
-                    raise
+            try:
+                return compiled(padded, extra, params, aux,
+                                self._rng)
+            except BaseException:
+                # MXNET_SANITIZE twin (ISSUE 15): with donation on,
+                # a failed dispatch may have consumed the padded
+                # input buffers — poison the batch dict in place so
+                # a retry that erroneously reuses it fails typed
+                # (DonatedBufferError) instead of serving deleted
+                # arrays.  One boolean test when off.
+                if self._donate and _sanitizer.ENABLED:
+                    _sanitizer.poison_mapping("serve_dispatch",
+                                              padded)
+                raise
 
     @hot_path
     def _predict_routed(self, inputs: Dict[str, _np.ndarray]) -> list:

@@ -13,9 +13,9 @@ if "host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8"
 
 os.environ["JAX_PLATFORMS"] = "cpu"
-# flight-recorder anomaly auto-dumps default to cwd; in a noisy shared
-# container a slow test step WILL trip the watchdog, so route dumps to
-# scratch (tests that assert on dumps monkeypatch their own dir)
+# in a noisy shared container a slow test step WILL trip the flight
+# recorder's watchdog, so route its auto-dumps to a scratch directory of
+# this run (tests that assert on dumps monkeypatch their own dir)
 if "MXNET_FLIGHT_DIR" not in os.environ:
     import tempfile
     os.environ["MXNET_FLIGHT_DIR"] = tempfile.mkdtemp(
@@ -91,10 +91,9 @@ def pytest_configure(config):
 
 @pytest.fixture(autouse=True)
 def _flight_dir(tmp_path, monkeypatch):
-    """Flight/OOM auto-dumps default to cwd (MXNET_FLIGHT_DIR='.') —
-    a test that trips the slow-phase watchdog or the OOM post-mortem
-    must never litter the repo root with flight-*/oom-*.json.  Tests
-    that care about the dir still monkeypatch their own."""
+    """A test that trips the slow-phase watchdog or the OOM post-mortem
+    writes its flight-*/oom-*.json under its own tmp_path.  Tests that
+    care about the dir still monkeypatch their own."""
     monkeypatch.setenv("MXNET_FLIGHT_DIR", str(tmp_path / "flight-dumps"))
 
 

@@ -97,8 +97,9 @@ def test_phase_span_records_fields():
                            labels={"k": "v"}):
         time.sleep(0.001)
     (rec,) = _spans("unit_phase")
-    name, cat, t0, t1, step, trace_id, labels = rec
+    name, cat, t0, t1, step, trace_id, labels, parent = rec
     assert cat == "testcat" and step == 7 and labels == {"k": "v"}
+    assert parent is None
     assert t1 > t0 and (t1 - t0) >= 1e3  # >= 1ms in microseconds
     assert trace_id is None
 
@@ -376,17 +377,19 @@ def _one_gluon_step(net=None):
 
 def test_trainer_step_phases_recorded():
     _one_gluon_step()
-    steps = _spans("trainer_step")
+    steps = _spans("mx.trainer.step")
     assert len(steps) == 3
     assert [r[4] for r in steps] == [0, 1, 2]      # step ids
-    assert len(_spans("allreduce")) == 3
-    assert len(_spans("fused_update")) == 3
-    # sub-phases nest inside their step's window and share its step id
+    assert len(_spans("mx.trainer.allreduce")) == 3
+    assert len(_spans("mx.optimizer.update_all")) == 3
+    # sub-phases nest inside their step's window, name it as their
+    # parent and share its step id
     s0 = steps[0]
-    ar0 = _spans("allreduce")[0]
+    ar0 = _spans("mx.trainer.allreduce")[0]
     assert s0[2] <= ar0[2] and ar0[3] <= s0[3] and ar0[4] == 0
-    # watched: trainer_step feeds the watchdog EWMA
-    assert flight.watch_state()["trainer_step"]["count"] == 3
+    assert ar0[7] == "mx.trainer.step"
+    # watched: mx.trainer.step feeds the watchdog EWMA
+    assert flight.watch_state()["mx.trainer.step"]["count"] == 3
 
 
 @pytest.mark.perf_smoke

@@ -68,7 +68,7 @@ def _fresh_goodput():
 
 def test_span_classification_and_report():
     goodput.start()
-    goodput.observe_span("trainer_step", 2.0)
+    goodput.observe_span("mx.trainer.step", 2.0)
     goodput.observe_span("prefetch_wait", 0.5)
     goodput.observe_span("checkpoint_block", 0.25)
     goodput.observe_span("not_a_unit_of_work", 9.0)  # ignored
@@ -99,7 +99,7 @@ def test_replay_scope_suppresses_double_counted_compute():
     with goodput.replay_scope("retry_replay"):
         # replayed steps re-run real math; their spans must NOT book
         # as goodput — the scope owns this wall-clock
-        goodput.observe_span("trainer_step", 5.0)
+        goodput.observe_span("mx.trainer.step", 5.0)
         goodput.observe_span("prefetch_wait", 0.125)
         time.sleep(0.01)
     rep = goodput.report()
@@ -107,7 +107,7 @@ def test_replay_scope_suppresses_double_counted_compute():
     assert rep["classes"]["data_wait"]["seconds"] == 0.125  # not compute
     assert rep["classes"]["retry_replay"]["seconds"] >= 0.01
     # scope closed: compute books again
-    goodput.observe_span("trainer_step", 1.0)
+    goodput.observe_span("mx.trainer.step", 1.0)
     assert goodput.report()["classes"]["compute"]["seconds"] == 1.0
 
 
@@ -123,7 +123,7 @@ def test_badput_metrics_exported():
 
 def test_snapshot_goodput_schema():
     goodput.start()
-    goodput.observe_span("trainer_step", 1.0)
+    goodput.observe_span("mx.trainer.step", 1.0)
     g = mx.observability.snapshot()["goodput"]
     assert g["enabled"] is True
     for key in ("classes", "events", "wall_s", "attributed_s",
@@ -139,7 +139,7 @@ def test_snapshot_goodput_schema():
 def test_disabled_ledger_is_inert():
     goodput.disable()
     goodput.start()
-    goodput.observe_span("trainer_step", 1.0)
+    goodput.observe_span("mx.trainer.step", 1.0)
     goodput.attribute("stall", 1.0)
     goodput.note_event("recompile")
     goodput.serve_latency_sample(1e6)
@@ -175,7 +175,7 @@ def test_gates_hold_at_import_in_subprocess():
         from mxnet_tpu.observability import goodput, journal
         assert goodput.ENABLED is False
         assert journal.ENABLED is False
-        goodput.observe_span("trainer_step", 1.0)
+        goodput.observe_span("mx.trainer.step", 1.0)
         assert goodput.report() == {{"enabled": False}}
         assert journal.emit("milestone", step=1) is None
         print("GATES-OK")
@@ -238,7 +238,7 @@ def test_milestones_embed_goodput_and_respect_cadence(tmp_path,
     journal.configure(run_dir=str(tmp_path / "run"))
     monkeypatch.setattr(journal, "MILESTONE_EVERY", 10)
     goodput.start()
-    goodput.observe_span("trainer_step", 2.0)
+    goodput.observe_span("mx.trainer.step", 2.0)
     for step in range(25):
         journal.maybe_milestone(step, source="trainer")
     entries = [e for e in rpt.load_journal(str(tmp_path / "run"))
@@ -252,7 +252,7 @@ def test_flight_dump_cross_references_journal(tmp_path, monkeypatch):
     run_dir = str(tmp_path / "run")
     journal.configure(run_dir=run_dir)
     monkeypatch.setenv("MXNET_FLIGHT_DIR", str(tmp_path / "dumps"))
-    with flight.phase_span("trainer_step", cat="step", step=1):
+    with flight.phase_span("mx.trainer.step", cat="step", step=1):
         time.sleep(0.001)
     dump_path = flight.dump(reason="manual")
     assert dump_path
@@ -285,7 +285,7 @@ def test_chaos_run_attributes_95_percent(tmp_path):
         state["w"] = float(np.asarray(snap["w"]))
 
     def step_fn(v):
-        with flight.phase_span("trainer_step", cat="step"):
+        with flight.phase_span("mx.trainer.step", cat="step"):
             fi.fire("trainer.step")
             time.sleep(0.005)
             state["w"] += v

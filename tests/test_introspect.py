@@ -225,7 +225,7 @@ def test_fused_path_captures_and_step_flops():
     progs = introspect.programs()
     assert {"gluon:fwd", "gluon:bwd", "fused_update"} <= set(progs)
     flops, _bytes, phase = introspect.step_flops()
-    assert phase == "trainer_step"
+    assert phase == "mx.trainer.step"
     assert flops == sum(progs[n]["flops"] for n in
                         ("gluon:fwd", "gluon:bwd", "fused_update"))
 
@@ -351,7 +351,7 @@ def test_fused_mfu_needs_explicit_step_time():
     the fused_update record carries a baseline signature."""
     for n in introspect.FUSED_STEP_PROGRAMS:
         introspect.note_program(n, compiled=_StubCompiled(flops=1e6))
-    _warm_ewma("trainer_step", 0.001)   # warmed, but partial-span
+    _warm_ewma("mx.trainer.step", 0.001)   # warmed, but partial-span
     assert introspect.mfu() == {}
     assert introspect.phase_flops_map() == {}
     out = introspect.mfu(step_time_s=0.01)

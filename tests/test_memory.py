@@ -357,7 +357,7 @@ def test_trainer_phases_carry_mem_deltas_and_counter_track():
     flight.enable()
     flight.reset()
     _train_mlp(steps=2)
-    recs = [r for _, r in flight.records() if r[0] == "trainer_step"]
+    recs = [r for _, r in flight.records() if r[0] == "mx.trainer.step"]
     assert recs, "no trainer_step phases recorded"
     labeled = [r for r in recs if r[6] and "mem_live_bytes" in r[6]]
     assert labeled, "trainer_step records carry no ledger samples"
@@ -373,7 +373,7 @@ def test_phase_mem_sampling_skipped_when_ledger_off():
     flight.enable()
     flight.reset()
     memory.disable()
-    with flight.phase_span("trainer_step", cat="step", mem=True):
+    with flight.phase_span("mx.trainer.step", cat="step", mem=True):
         pass
     (seg, rec), = flight.records()
     assert rec[6] is None  # no labels fabricated when the ledger is off

@@ -244,15 +244,16 @@ def test_fit_trace_contains_nested_training_spans(tmp_path):
     with open(fname) as f:
         trace = json.load(f)
     names = {e["name"] for e in trace["traceEvents"]}
-    for expected in ("train_step", "forward_backward", "update",
-                     "data_fetch", "kvstore_pushpull",
-                     "optimizer_update_all"):
+    for expected in ("mx.step", "mx.module.forward_backward",
+                     "mx.module.update", "mx.fit.data_fetch",
+                     "mx.kvstore.pushpull", "mx.optimizer.update_all"):
         assert expected in names, (expected, sorted(names))
-    # spans nest: fwd_bwd + update inside their train_step
+    # spans nest: fwd_bwd + update inside their mx.step
     steps = sorted((e for e in trace["traceEvents"]
-                    if e["name"] == "train_step"), key=lambda e: e["ts"])
+                    if e["name"] == "mx.step"), key=lambda e: e["ts"])
     fb = sorted((e for e in trace["traceEvents"]
-                 if e["name"] == "forward_backward"), key=lambda e: e["ts"])
+                 if e["name"] == "mx.module.forward_backward"),
+                key=lambda e: e["ts"])
     assert steps and fb
     s0 = steps[0]
     assert s0["ts"] <= fb[0]["ts"]
