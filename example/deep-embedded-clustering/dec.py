@@ -141,7 +141,8 @@ def main():
                 kl = (pt * (mx.nd.log(pt + 1e-9) -
                             mx.nd.log(qb + 1e-9))).sum(axis=1).mean()
             kl.backward()
-            trainer.step(1)
+            # the KL reads z only: the decoder stays as pretrained
+            trainer.step(1, ignore_stale_grad=True)
             opt.update(0, centers, centers.grad, cstate)
             tot += float(kl.asnumpy())
         logging.info("dec[%d] kl=%.5f", epoch, tot / nb)

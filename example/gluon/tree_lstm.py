@@ -128,7 +128,8 @@ def main():
                 logits = net(tree, ctx)
                 loss = sce(logits, y)
             loss.backward()
-            trainer.step(1)
+            # a tree that is a single leaf never calls f_x / f_h
+            trainer.step(1, ignore_stale_grad=True)
             tot += float(loss.asnumpy())
             correct += int(np.argmax(logits.asnumpy()) == label)
         logging.info("Epoch[%d] loss=%.4f acc=%.3f", epoch,

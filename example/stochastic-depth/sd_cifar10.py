@@ -111,7 +111,8 @@ def main():
             with autograd.record():
                 loss = sce(net(x, rs), y)
             loss.backward()
-            trainer.step(args.batch_size)
+            # a dropped block took no part in this batch
+            trainer.step(args.batch_size, ignore_stale_grad=True)
             tot += float(loss.mean().asnumpy())
         logging.info("Epoch[%d] loss=%.4f (%.1fs)", epoch, tot / nb,
                      time.time() - t0)

@@ -1059,11 +1059,14 @@ class RetraceHazardChecker:
     """Compiled-program identity must be stable (the
     FUSED_DTYPE_RECOMPILES class: a silent retrace/fallback re-pays XLA
     compilation on a hot path, or — worse — silently reuses a program
-    traced for different semantics).  Three shapes:
+    traced for different semantics).  Its shapes:
 
       * ``jax.jit(f)(x)`` — jit-then-call in one expression builds a
         fresh program cache per evaluation: every call recompiles;
       * ``jax.jit`` inside a loop body — one program per iteration;
+      * ``shard_map(...)`` that is not the argument of a ``jax.jit`` —
+        bound eagerly it compiles its body's primitives one by one at
+        every call;
       * ``jax.jit`` call sites outside the blessed compile chokepoints
         (``_JIT_CHOKEPOINTS``) — programs built where introspection /
         dispatch-count gates can't see them;
@@ -1141,6 +1144,20 @@ class RetraceHazardChecker:
                             "loop"))
                         break
                     cur = parents.get(cur)
+            elif cn.split(".")[-1] == "shard_map":
+                outer = parents.get(node)
+                if not (isinstance(outer, ast.Call) and node in outer.args
+                        and _call_name(outer.func) in ("jax.jit",
+                                                       "_jax.jit")):
+                    out.append(ctx.finding(
+                        self.name, node,
+                        "bare shard_map(...) — bound on concrete arrays "
+                        "it runs its body primitive by primitive and "
+                        "compiles each one anew at EVERY call (the "
+                        "sequence-parallel KV decode: 2,383 compiles of "
+                        "105 signatures for 11 positions).  Build "
+                        "jax.jit(shard_map(...)) once per (mesh, specs) "
+                        "and reuse it"))
             elif _call_name(node.func).split(".")[-1] == \
                     "lookup_program" and node.args:
                 key = node.args[0]

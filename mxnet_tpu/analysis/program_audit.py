@@ -237,9 +237,9 @@ def audit_program(rec: dict) -> List[dict]:
     """Verify one ``introspect.programs()`` record against its declared
     contracts.  Returns issue dicts ``{program, check, ok, detail}`` —
     one per failed check (empty = clean).  A record without contracts
-    yields nothing; a contract without captured HLO yields one
-    ``hlo-missing`` issue marked ``skipped=True`` so callers can decide
-    strictness."""
+    yields nothing; a contract without captured HLO, or with HLO cut at
+    the size cap, yields one ``hlo-missing`` / ``hlo-truncated`` issue
+    marked ``skipped=True`` so callers can decide strictness."""
     contracts = rec.get("contracts")
     if not contracts:
         return []
@@ -252,6 +252,15 @@ def audit_program(rec: dict) -> List[dict]:
                            "set MXNET_INTROSPECT_HLO=1 (or "
                            "introspect.configure(hlo=True)) before the "
                            "program compiles"}]
+    if rec.get("hlo_truncated"):
+        # half a program proves nothing: its alias table, casts and
+        # collectives may all lie past the cut, and reading the stub
+        # reports donation "degraded to copy" of a program that is fine
+        return [{"program": name, "check": "hlo-truncated", "ok": False,
+                 "skipped": True,
+                 "detail": "captured HLO was cut at the size cap — raise "
+                           "introspect.configure(hlo_cap_bytes=...) "
+                           "before the program compiles"}]
     issues: List[dict] = []
 
     leaves = contracts.get("donated_leaves")

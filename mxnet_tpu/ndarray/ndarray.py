@@ -295,12 +295,30 @@ class NDArray:
                 b, e, _ = key.indices(self.shape[0])
                 return _gen.slice_axis(self, axis=0, begin=b, end=e)
             return None
-        if isinstance(key, tuple) and all(
-                isinstance(k, slice) and k.step in (None, 1) for k in key):
-            idx = [k.indices(d) for k, d in zip(key, self.shape)]
-            begin = tuple(b for b, _, _ in idx)
-            end = tuple(e for _, e, _ in idx)
-            return _gen.slice(self, begin=begin, end=end)
+        if isinstance(key, tuple) and len(key) <= self.ndim and all(
+                isinstance(k, int) or
+                (isinstance(k, slice) and k.step in (None, 1))
+                for k in key):
+            # an int is the slice [k, k+1) with its axis dropped after:
+            # x[0, a] under record must reach the tape like x[0][a] does
+            # (as a raw view its gradient was silently zero and the
+            # parameters behind it looked stale to Trainer.step)
+            begin, end, keep = [], [], []
+            for k, d in zip(key, self.shape):
+                if isinstance(k, int):
+                    if not -d <= k < d:
+                        raise IndexError(f"index {k} is out of bounds for "
+                                         f"axis with size {d}")
+                    b, e = k % d, k % d + 1
+                else:
+                    b, e, _ = k.indices(d)
+                    keep.append(max(e - b, 0))
+                begin.append(b)
+                end.append(e)
+            out = _gen.slice(self, begin=tuple(begin), end=tuple(end))
+            if len(keep) < len(key):
+                out = out.reshape(tuple(keep) + out.shape[len(key):])
+            return out
         return None
 
     def __setitem__(self, key, value):

@@ -27,7 +27,6 @@ def _ctc_loss(p, data, label, data_lengths=None, label_lengths=None):
     """Parity: contrib/ctc_loss.cc.  data: (T, N, C) activations (pre-softmax),
     label: (N, L) padded with 0/-1; optional per-sequence lengths gated by
     use_data_lengths / use_label_lengths (reference inputs 3 and 4)."""
-    import optax
     if (p["use_label_lengths"] and not p["use_data_lengths"]
             and label_lengths is None):
         # positional call with the unused data_lengths slot elided (symbol
@@ -53,8 +52,19 @@ def _ctc_loss(p, data, label, data_lengths=None, label_lengths=None):
         steps = jnp.arange(labels.shape[1])[None, :]
         lab_valid = steps < label_lengths[:, None].astype(jnp.int32)
     lab = jnp.where(lab_valid, labels, 0)
-    return optax.ctc_loss(logits, logit_pad, lab,
-                          (~lab_valid).astype(jnp.float32), blank_id=blank)
+    return _ctc_scan(logits, logit_pad, lab,
+                     (~lab_valid).astype(jnp.float32), blank)
+
+
+@functools.partial(jax.jit, static_argnums=4)
+def _ctc_scan(logits, logit_pad, labels, label_pad, blank):
+    # one jitted callable for the process: optax's forward recursion is a
+    # lax.scan over a fresh closure, and a scan bound eagerly (the
+    # recorded nd path differentiates op.fn with jax.vjp outside any
+    # jit) is compiled anew at every call, forward and transpose
+    import optax
+    return optax.ctc_loss(logits, logit_pad, labels, label_pad,
+                          blank_id=blank)
 
 
 @register("_contrib_fft", input_names=("data",), aliases=("fft",),

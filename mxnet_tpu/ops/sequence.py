@@ -141,8 +141,16 @@ def _cell_step(mode, state_size):
     return step
 
 
+@functools.partial(jax.jit, static_argnames=("mode", "reverse"))
 def _run_layer(x, h0, c0, W, R, bW, bR, mode, reverse):
-    """One direction of one layer. x: (T, B, in). Returns (T,B,H), hT, cT."""
+    """One direction of one layer. x: (T, B, in). Returns (T,B,H), hT, cT.
+
+    Jitted as a module-level callable: the scan body below is a fresh
+    closure at every call, and a scan bound eagerly (the recorded nd
+    path differentiates op.fn with jax.vjp outside any jit) is compiled
+    anew each time, forward and transpose -- a non-hybridized
+    gluon.rnn layer paid two compiles a step.  Under an outer trace
+    this is an inlined call."""
     T, B, _ = x.shape
     H = h0.shape[-1]
     # hoist the input projection out of the scan: one big MXU matmul

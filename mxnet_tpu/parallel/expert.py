@@ -10,6 +10,7 @@ follows the Switch Transformer formulation.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -100,17 +101,26 @@ def switch_moe_sharded(x, gate_w, expert_fn: Callable, stacked_expert_params,
     expert weights stacked on a leading axis of size mesh.shape[axis_name].
     ``mesh=None`` resolves the ambient current_mesh()."""
     mesh = _resolve(mesh, "switch_moe_sharded")
+    return _moe_fn(expert_fn, mesh, axis_name, float(capacity_factor), k,
+                   jax.tree_util.tree_structure(stacked_expert_params))(
+        x, gate_w, stacked_expert_params)
 
+
+@functools.lru_cache(maxsize=32)
+def _moe_fn(expert_fn, mesh, axis_name, capacity_factor, k, treedef):
+    """The jitted shard_map'd layer, built once per (expert_fn, mesh,
+    axis, capacity, k, parameter tree) -- the idiom of
+    sequence_parallel._sharded_fn: a bare shard_map bound on concrete
+    arrays compiles its body primitive by primitive at every call."""
     def per_device(xs, gw, params):
         squeezed = jax.tree_util.tree_map(lambda a: a[0], params)
         y, aux = topk_moe(xs, gw, expert_fn, squeezed, axis_name,
                           capacity_factor, k=k)
         return y, lax.pmean(aux, axis_name)
 
-    fn = shard_map(
+    return jax.jit(shard_map(  # graft-lint: disable=retrace-hazard
         per_device, mesh=mesh,
         in_specs=(P(axis_name), P(),
-                  jax.tree_util.tree_map(lambda _: P(axis_name),
-                                         stacked_expert_params)),
-        out_specs=(P(axis_name), P()), check_vma=False)
-    return fn(x, gate_w, stacked_expert_params)
+                  jax.tree_util.tree_unflatten(
+                      treedef, [P(axis_name)] * treedef.num_leaves)),
+        out_specs=(P(axis_name), P()), check_vma=False))

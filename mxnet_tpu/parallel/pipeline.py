@@ -15,6 +15,7 @@ the ppermute to ICI neighbor exchanges.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -89,17 +90,30 @@ def gpipe_sharded(stage_fn: Callable, stacked_params, x,
     size mesh.shape[axis_name] (one slice per stage); x is replicated.
     ``mesh=None`` resolves the ambient current_mesh()."""
     mesh = _resolve(mesh, "gpipe_sharded")
+    return _gpipe_fn(stage_fn, mesh, n_microbatches, axis_name,
+                     jax.tree_util.tree_structure(stacked_params))(
+        stacked_params, x)
 
+
+def _stage_specs(treedef, axis_name):
+    return jax.tree_util.tree_unflatten(
+        treedef, [P(axis_name)] * treedef.num_leaves)
+
+
+@functools.lru_cache(maxsize=32)
+def _gpipe_fn(stage_fn, mesh, n_microbatches, axis_name, treedef):
+    """The jitted shard_map'd forward, built once per (stage_fn, mesh,
+    M, axis, parameter tree) -- the idiom of
+    sequence_parallel._sharded_fn: a bare shard_map bound on concrete
+    arrays compiles its body primitive by primitive at every call."""
     def per_device(params, xs):
         squeezed = jax.tree_util.tree_map(lambda a: a[0], params)
         return gpipe(stage_fn, squeezed, xs, n_microbatches, axis_name)
 
-    fn = shard_map(
+    return jax.jit(shard_map(  # graft-lint: disable=retrace-hazard
         per_device, mesh=mesh,
-        in_specs=(jax.tree_util.tree_map(lambda _: P(axis_name), stacked_params),
-                  P()),
-        out_specs=P(), check_vma=False)
-    return fn(stacked_params, x)
+        in_specs=(_stage_specs(treedef, axis_name), P()),
+        out_specs=P(), check_vma=False))
 
 
 def pipeline_1f1b(stage_fn: Callable, stage_params, x, y, loss_fn: Callable,
@@ -213,7 +227,6 @@ def pipeline_train_step(stage_fn: Callable, stacked_params, x, y,
     ``mesh=None`` resolves the ambient current_mesh().
     """
     mesh = _resolve(mesh, "pipeline_train_step")
-    S = mesh.shape[axis_name]
     M = n_microbatches
     if schedule == "gpipe":
         def total_loss(params):
@@ -226,6 +239,16 @@ def pipeline_train_step(stage_fn: Callable, stacked_params, x, y,
         return jax.value_and_grad(total_loss)(stacked_params)
     if schedule != "1f1b":
         raise ValueError(f"unknown pipeline schedule '{schedule}'")
+    return _1f1b_fn(stage_fn, loss_fn, mesh, M, axis_name,
+                    jax.tree_util.tree_structure(stacked_params))(
+        stacked_params, x, y)
+
+
+@functools.lru_cache(maxsize=32)
+def _1f1b_fn(stage_fn, loss_fn, mesh, M, axis_name, treedef):
+    """The jitted shard_map'd 1F1B step, built once per key (see
+    _gpipe_fn)."""
+    S = mesh.shape[axis_name]
 
     def per_device(params, xs, ys):
         squeezed = jax.tree_util.tree_map(lambda a: a[0], params)
@@ -233,11 +256,7 @@ def pipeline_train_step(stage_fn: Callable, stacked_params, x, y,
                                     M, S, axis_name)
         return loss, jax.tree_util.tree_map(lambda g: g[None], grads)
 
-    fn = shard_map(
-        per_device, mesh=mesh,
-        in_specs=(jax.tree_util.tree_map(lambda _: P(axis_name),
-                                         stacked_params), P(), P()),
-        out_specs=(P(), jax.tree_util.tree_map(lambda _: P(axis_name),
-                                               stacked_params)),
-        check_vma=False)
-    return fn(stacked_params, x, y)
+    specs = _stage_specs(treedef, axis_name)
+    return jax.jit(shard_map(  # graft-lint: disable=retrace-hazard
+        per_device, mesh=mesh, in_specs=(specs, P(), P()),
+        out_specs=(P(), specs), check_vma=False))

@@ -88,38 +88,36 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = False,
 
 @functools.lru_cache(maxsize=64)
 def _sharded_fn(kind, mesh: Mesh, axis_name: str, causal, scale):
-    """Build (and CACHE) the shard_map'd callable: jax's dispatch cache
-    is keyed on callable identity, so a fresh partial per call would
-    retrace every step of a decode loop."""
+    """Build (and CACHE) the jitted shard_map'd callable, one per
+    (kind, mesh, axis, causal, scale).  Both halves matter: jax's
+    dispatch cache is keyed on callable identity, so a fresh partial
+    per call would retrace every step of a decode loop; and a bare
+    shard_map called on concrete arrays runs its body primitive by
+    primitive, compiling each one anew at EVERY call (a 12-position
+    decode of a 2-layer model compiled 2,383 programs of 105
+    signatures) -- under jax.jit the body is one program per input
+    shape, loaded once."""
     spec = P(None, None, axis_name, None)
-    if kind == "ring":
-        return shard_map(
-            functools.partial(ring_attention, axis_name=axis_name,
-                              causal=causal, scale=scale),
-            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_vma=False)
-    if kind == "ulysses":
-        return shard_map(
-            functools.partial(ulysses_attention, axis_name=axis_name,
-                              causal=causal, scale=scale),
-            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_vma=False)
     rspec = P()
-    if kind == "ulysses_decode":
+    if kind in ("ring", "ulysses"):
+        body = ring_attention if kind == "ring" else ulysses_attention
+        body = functools.partial(body, axis_name=axis_name,
+                                 causal=causal, scale=scale)
+        in_specs, out_specs = (spec, spec, spec), spec
+    elif kind == "ulysses_decode":
         hspec = P(None, axis_name, None, None)    # head-sharded caches
-        return shard_map(
-            functools.partial(ulysses_decode_step, axis_name=axis_name,
-                              scale=scale),
-            mesh=mesh,
-            in_specs=(rspec, rspec, rspec, hspec, hspec, rspec),
-            out_specs=(P(None, axis_name, None), hspec, hspec),
-            check_vma=False)
-    return shard_map(
-        functools.partial(ring_decode_step, axis_name=axis_name,
-                          scale=scale),
-        mesh=mesh,
-        in_specs=(rspec, rspec, rspec, spec, spec, rspec),
-        out_specs=(rspec, spec, spec), check_vma=False)
+        body = functools.partial(ulysses_decode_step,
+                                 axis_name=axis_name, scale=scale)
+        in_specs = (rspec, rspec, rspec, hspec, hspec, rspec)
+        out_specs = (P(None, axis_name, None), hspec, hspec)
+    else:
+        body = functools.partial(ring_decode_step, axis_name=axis_name,
+                                 scale=scale)
+        in_specs = (rspec, rspec, rspec, spec, spec, rspec)
+        out_specs = (rspec, spec, spec)
+    # one jit per lru_cache entry, module-lifetime: built once, reused
+    return jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,  # graft-lint: disable=retrace-hazard
+                             out_specs=out_specs, check_vma=False))
 
 
 def _resolve(mesh, who: str) -> Mesh:

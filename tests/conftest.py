@@ -6,6 +6,8 @@ tests for device-placement logic, tests/python/unittest/test_multi_device_exec.p
 Tests never touch a chip: JAX_PLATFORMS=cpu is forced below, before
 jax is imported."""
 import os
+import signal
+import threading
 
 flags = os.environ.get("XLA_FLAGS", "")
 if "host_platform_device_count" not in flags:
@@ -97,6 +99,37 @@ def _flight_dir(tmp_path, monkeypatch):
     monkeypatch.setenv("MXNET_FLIGHT_DIR", str(tmp_path / "flight-dumps"))
 
 
+#: seconds one test may take, set-up and tear-down included: a fifth of
+#: tier-1's clock.  A child process gets less (example_runner.TIMEOUT).
+TEST_LIMIT_S = 300
+
+
+@pytest.fixture(autouse=True)
+def _time_limit(request):
+    """Fail a test that runs past TEST_LIMIT_S, by name, instead of letting
+    it eat the clock of every test queued behind it on its worker (the
+    suite's own limit then cuts the run, and what the clock did not reach
+    guards nothing).  An interval timer on the main thread, where pytest
+    and xdist's workers run tests; the handler runs between bytecodes, so
+    a wait on a child, a lock or a socket is interrupted, a compile inside
+    XLA only when it returns."""
+    if threading.current_thread() is not threading.main_thread():
+        yield
+        return
+
+    def expired(signum, frame):
+        pytest.fail(f"{request.node.nodeid} ran past {TEST_LIMIT_S} s",
+                    pytrace=True)
+
+    prev = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, prev)
+
+
 @pytest.fixture(autouse=True)
 def _seed():
     np.random.seed(0)
@@ -118,8 +151,13 @@ def program_audit():
             aliased = program_audit("whole_step")
     """
     from mxnet_tpu.observability import introspect
-    prev_hlo = introspect.HLO
-    introspect.configure(hlo=True)
+    # every knob the audit reads is set here and put back after, so the
+    # result does not depend on what an earlier test of this process left
+    # behind (a leaked 123-byte cap cut the captured program and the audit
+    # read "donation degraded to copy" from the stub)
+    prev = (introspect.ENABLED, introspect.HLO, introspect.HLO_CAP_BYTES)
+    introspect.enable()
+    introspect.configure(hlo=True, hlo_cap_bytes=8 << 20)
 
     def check(program="whole_step", min_aliased=1):
         from mxnet_tpu.analysis import program_audit as pa
@@ -130,6 +168,8 @@ def program_audit():
         assert rec.get("hlo"), \
             f"no HLO captured for {program!r} — the program compiled " \
             f"before this fixture armed capture"
+        assert not rec.get("hlo_truncated"), \
+            f"HLO of {program!r} was cut at {introspect.HLO_CAP_BYTES} bytes"
         issues = pa.audit_program(rec)
         assert issues == [], issues
         aliased = pa.parse_alias_table(rec["hlo"])
@@ -139,4 +179,5 @@ def program_audit():
         return aliased
 
     yield check
-    introspect.configure(hlo=prev_hlo)
+    (introspect.enable if prev[0] else introspect.disable)()
+    introspect.configure(hlo=prev[1], hlo_cap_bytes=prev[2])

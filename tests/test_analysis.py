@@ -214,6 +214,25 @@ BAD_RETRACE = """
         return jax.jit(lambda v: v + 1)(x)
 """
 
+BAD_RETRACE_SHARD_MAP = """
+    from jax import shard_map
+
+    def sharded(mesh, spec, x):
+        fn = shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec)
+        return fn(x)
+"""
+
+GOOD_RETRACE_SHARD_MAP = """
+    import functools
+    import jax
+    from jax import shard_map
+
+    @functools.lru_cache(maxsize=8)
+    def sharded_fn(mesh, spec):
+        return jax.jit(shard_map(body, mesh=mesh, in_specs=spec,  # graft-lint: disable=retrace-hazard
+                                 out_specs=spec))
+"""
+
 BAD_RETRACE_LOOP = """
     import jax
 
@@ -482,6 +501,16 @@ def test_retrace_hazard_jit_then_call(tmp_path):
 def test_retrace_hazard_jit_in_loop(tmp_path):
     got = _lint(tmp_path, BAD_RETRACE_LOOP, ["retrace-hazard"])
     assert any("inside a loop" in f.message for f in got), got
+
+
+def test_retrace_hazard_bare_shard_map(tmp_path):
+    """A shard_map that no jax.jit wraps is the shape the sequence-parallel
+    decode had; jitted and cached it is clean (the jit itself, outside the
+    chokepoints, carries its suppression)."""
+    got = _lint(tmp_path, BAD_RETRACE_SHARD_MAP, ["retrace-hazard"])
+    assert len(got) == 1 and "bare shard_map" in got[0].message, got
+    assert _lint(tmp_path, GOOD_RETRACE_SHARD_MAP,
+                 ["retrace-hazard"]) == []
 
 
 def test_retrace_hazard_unstable_cache_key(tmp_path):

@@ -182,3 +182,31 @@ def test_view_ops_recorded():
 
     g_ref = np.asarray(jax.grad(f)(jnp.asarray(x.asnumpy())))
     assert_almost_equal(x.grad.asnumpy(), g_ref, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("key", [
+    (0, 1), (1, slice(0, 2)), (slice(None), 2), (-1, -1, -1), (0,),
+    (1, slice(None), 3), (slice(0, 1), 1, slice(1, 3))],
+    ids=lambda k: "-".join(str(i) if isinstance(i, int)
+                           else f"{i.start}:{i.stop}" for i in k))
+def test_int_and_slice_tuple_index_is_recorded(key):
+    """x[i, j] and its mixes with unit-step slices reach the tape like
+    x[i][j] does: numpy's values and shape, and the gradient of what was
+    read (as a raw view it read right and its gradient was silently 0)."""
+    x = nd.array(np.arange(24, dtype="f").reshape(2, 3, 4))
+    x.attach_grad()
+    with autograd.record():
+        y = x[key]
+        loss = (y * 2).sum()
+    loss.backward()
+    want = np.zeros((2, 3, 4), "f")
+    want[key] = 2
+    assert y.shape == x.asnumpy()[key].shape
+    assert np.array_equal(y.asnumpy(), x.asnumpy()[key])
+    assert np.array_equal(x.grad.asnumpy(), want)
+
+
+def test_int_tuple_index_out_of_range_raises_under_record():
+    x = nd.array(np.zeros((2, 3), "f"))
+    with autograd.record(), pytest.raises(IndexError):
+        x[0, 3]

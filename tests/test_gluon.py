@@ -482,3 +482,75 @@ def test_bidirectional_valid_length():
     o = out.asnumpy()
     assert o.shape == (2, 5, 8)
     assert (o[0, 3:] == 0).all()
+
+
+# ------------------------------------------------- stale gradients, eager
+
+class _TwoHeads(gluon.Block):
+    """An eager net in the shape of example/gluon/actor_critic.py: one
+    trunk, two heads, called once per time step inside one record."""
+
+    def __init__(self, **kw):
+        super().__init__(**kw)
+        with self.name_scope():
+            self.trunk = nn.Dense(8, activation="relu")
+            self.policy = nn.Dense(2)
+            self.value = nn.Dense(1)
+
+    def forward(self, x):
+        h = self.trunk(x)
+        return nd.softmax(self.policy(h)), self.value(h)
+
+
+def test_eager_block_called_twice_in_one_record_steps():
+    """A non-hybridized block called several times in one record, its
+    outputs picked apart with x[i, j], trains: every parameter took part,
+    so Trainer.step finds no stale gradient and moves them all.  (x[i, j]
+    was a raw view off the tape: backward reached no parameter and the
+    step raised the stale-gradient UserWarning for the first of them.)"""
+    net = _TwoHeads()
+    net.initialize(mx.init.Xavier())
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1})
+    xs = [nd.array(np.random.RandomState(i).rand(1, 4).astype("f"))
+          for i in range(2)]
+    with autograd.record():
+        loss = 0.0
+        for t, x in enumerate(xs):
+            probs, value = net(x)
+            loss = loss - nd.log(probs[0, t]) + (value[0, 0] - 1.0) ** 2
+    before = {k: p.data().asnumpy().copy()
+              for k, p in net.collect_params().items()}
+    loss.backward()
+    trainer.step(1)
+    for k, p in net.collect_params().items():
+        assert p.fresh_grad is False, k          # step cleared the mark
+        assert np.abs(p.data().asnumpy() - before[k]).max() > 0, k
+
+
+def test_eager_block_left_out_of_the_record_still_raises():
+    """The twin: a head that took no part in the recorded step is named by
+    the stale-gradient guard, and ignore_stale_grad=True skips exactly it."""
+    net = _TwoHeads()
+    net.initialize(mx.init.Xavier())
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1})
+    x = nd.array(np.random.RandomState(0).rand(1, 4).astype("f"))
+    net(x)                                      # deferred shapes
+
+    def backward_through_policy_only():
+        with autograd.record():
+            h = net.trunk(x)
+            loss = -nd.log(nd.softmax(net.policy(h))[0, 1])
+        loss.backward()
+
+    backward_through_policy_only()
+    with pytest.raises(UserWarning, match=net.value.weight.name):
+        trainer.step(1)
+    before = {k: p.data().asnumpy().copy()
+              for k, p in net.collect_params().items()}
+    backward_through_policy_only()
+    trainer.step(1, ignore_stale_grad=True)
+    for k, p in net.collect_params().items():
+        moved = np.abs(p.data().asnumpy() - before[k]).max() > 0
+        assert moved == (not k.startswith(net.value.prefix)), k

@@ -46,7 +46,7 @@ pytestmark = pytest.mark.introspect
 def _clean():
     """Per-test isolation: fresh program registry / sentinel state /
     flight EWMAs; knobs restored both sides."""
-    was_on = introspect.ENABLED
+    was_on, cap = introspect.ENABLED, introspect.HLO_CAP_BYTES
     introspect.enable()
     introspect.reset()
     introspect.configure(hlo=False, sentinel_every=1,
@@ -54,7 +54,7 @@ def _clean():
     flight.reset()
     yield
     introspect.reset()
-    introspect.configure(hlo=False, sentinel_every=25,
+    introspect.configure(hlo=False, hlo_cap_bytes=cap, sentinel_every=25,
                          regression_factor=1.5, regression_min_s=300.0)
     (introspect.enable if was_on else introspect.disable)()
     flight.reset()

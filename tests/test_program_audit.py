@@ -237,6 +237,41 @@ def test_whole_step_amp_bf16_cast_coverage_real(monkeypatch,
     assert cov["f32"] == 0 and cov["lp"] >= 2, cov
 
 
+@pytest.mark.program_audit
+@pytest.mark.introspect
+def test_program_audit_fixture_does_not_depend_on_earlier_tests(
+        monkeypatch, request):
+    """Whatever an earlier test of the process left in introspect's knobs
+    (here: capture off, a 123-byte cap, as tests/test_introspect.py used
+    to leave), the fixture audits the whole program of the test that
+    asked for it, and puts the knobs back as it found them."""
+    monkeypatch.setattr(introspect, "HLO", False)
+    monkeypatch.setattr(introspect, "HLO_CAP_BYTES", 123)
+    check = request.getfixturevalue("program_audit")
+    introspect.reset()
+    st = _tiny_wholestep(monkeypatch)
+    assert st.active, st.fallback_reason
+    rec = introspect.programs()["whole_step"]
+    assert len(rec["hlo"]) > 123 and not rec["hlo_truncated"]
+    assert len(check("whole_step")) >= rec["contracts"]["donated_leaves"]
+
+
+@pytest.mark.program_audit
+def test_truncated_hlo_is_skipped_not_misread():
+    """A program cut at the size cap cannot be audited: one skipped
+    ``hlo-truncated`` issue (a failure under strict), never a verdict
+    read from the stub."""
+    rec = {"name": "cut", "hlo": _HEADER_NO_ALIAS[:40],
+           "hlo_truncated": True,
+           "contracts": {"donated_leaves": 4, "host_callbacks": 0}}
+    (issue,) = pa.audit_program(rec)
+    assert issue["check"] == "hlo-truncated" and issue["skipped"]
+    lax_ = pa.audit_programs({"cut": rec})
+    assert lax_["ok"] and lax_["skipped"] == ["cut"]
+    strict = pa.audit_programs({"cut": rec}, strict=True)
+    assert not strict["ok"] and strict["issues"] == [issue]
+
+
 # -- CLI self-audit -----------------------------------------------------------
 
 @pytest.mark.program_audit

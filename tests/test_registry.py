@@ -359,6 +359,9 @@ def test_chaos_four_models_budget_for_two_mixed_tenant_flood():
     sequences count in the same goodput, and every generative failure
     mode is typed too (`SequenceEvicted` rides `Overloaded`)."""
     from mxnet_tpu.serving import DecodeEngine, ToyLM
+    # what an earlier test of the process left for the collector is not
+    # this drill's baseline (a whole run read 8 bytes here and 0 after)
+    _collect()
     dev0 = memory.live_by_tag().get("serve_weights", 0)
     host0 = memory.live_by_tag("host").get("serve_host_params", 0)
     kv0 = memory.live_by_tag().get("serve_kv_pages", 0)
@@ -373,29 +376,9 @@ def test_chaos_four_models_budget_for_two_mixed_tenant_flood():
         eng = DecodeEngine(ToyLM(vocab=16, dim=8, window=4), slots=4,
                            page_tokens=4, max_pages=4, warmup=False,
                            name="gen")
-        # uncontended baseline p99 (budget off, everything resident)
-        lats = []
-        for i in range(20):
-            t0 = time.perf_counter()
-            reg.predict(model=names[i % 4], data=_x())
-            lats.append(time.perf_counter() - t0)
-        p99_base = float(np.percentile(lats, 99))
-        wb = _weights_bytes(reg, "m0")
-        # budget: everything currently resident + ~0.6 models of slack
-        # -> keeping all four resident is impossible, ~2 fit as the
-        # flood shifts traffic between pairs
-        for n in names[2:]:
-            reg._entry(n).predictor.evict()
-        _collect()
-        reg.budget_bytes = memory.tracked_bytes() + 0.6 * wb
-
-        plan = (fi.FaultPlan()
-                .add("serving.evict", "delay", delay_s=0.002)
-                .add("memory.oom", "raise", times=1, after=5))
-        results = {"lat": [], "errors": [], "served": 0, "admitted": 0}
         lock = threading.Lock()
 
-        def tenant_load(tenant, model, rounds):
+        def tenant_load(results, tenant, model, rounds):
             for i in range(rounds):
                 t0 = time.perf_counter()
                 try:
@@ -414,7 +397,7 @@ def test_chaos_four_models_budget_for_two_mixed_tenant_flood():
                     with lock:
                         results["errors"].append(e)
 
-        def gen_load(tenant, rounds):
+        def gen_load(results, tenant, rounds):
             """The generative tenant: sequences through the decode
             engine, same goodput ledger, same typed-or-bust rule."""
             for i in range(rounds):
@@ -439,25 +422,53 @@ def test_chaos_four_models_budget_for_two_mixed_tenant_flood():
                     with lock:
                         results["errors"].append(e)
 
-        with fi.active(plan):
+        def flood():
+            """Mixed tenants, traffic shifting across all 4 models, and
+            the two generative tenants: eight client threads."""
+            results = {"lat": [], "errors": [], "served": 0, "admitted": 0}
             threads = []
-            # mixed tenants, traffic shifting across all 4 models —
-            # the k=2 budget forces continuous evict/readmit churn
-            for r, (tenant, model) in enumerate(
-                    [("acme", "m0"), ("acme", "m2"), ("beta", "m1"),
-                     ("beta", "m3"), ("gamma", "m2"), ("gamma", "m0")]):
-                t = threading.Thread(target=tenant_load,
-                                     args=(tenant, model, 10))
-                threads.append(t)
-                t.start()
+            for tenant, model in [("acme", "m0"), ("acme", "m2"),
+                                  ("beta", "m1"), ("beta", "m3"),
+                                  ("gamma", "m2"), ("gamma", "m0")]:
+                threads.append(threading.Thread(
+                    target=tenant_load, args=(results, tenant, model, 10)))
             for tenant in ("gen-a", "gen-b"):
-                t = threading.Thread(target=gen_load,
-                                     args=(tenant, 6))
-                threads.append(t)
+                threads.append(threading.Thread(
+                    target=gen_load, args=(results, tenant, 6)))
+            for t in threads:
                 t.start()
             for t in threads:
                 t.join(timeout=120)
                 assert not t.is_alive(), "flood worker hung"
+            return results
+
+        # the base is the SAME flood with the budget off and everything
+        # resident: the same eight threads on the same machine under the
+        # same load, so the bound below compares what the budget costs and
+        # not how busy the container is (a serial base read 0.007 s; the
+        # flood beside six busy test workers then read 3.9 s against the
+        # old floor of 2 s)
+        flood()                # first use compiles: not a latency
+        base = flood()
+        assert base["errors"] == [] and base["served"] == base["admitted"]
+        p99_base = float(np.percentile(base["lat"], 99))
+        wb = _weights_bytes(reg, "m0")
+        # budget: everything currently resident + ~0.6 models of slack
+        # -> keeping all four resident is impossible, ~2 fit as the
+        # flood shifts traffic between pairs
+        for n in names[2:]:
+            reg._entry(n).predictor.evict()
+        _collect()
+        reg.budget_bytes = memory.tracked_bytes() + 0.6 * wb
+
+        plan = (fi.FaultPlan()
+                .add("serving.evict", "delay", delay_s=0.002)
+                # early: beside a busy machine most of the flood is shed
+                # at submit and as few as three batches are dispatched
+                .add("memory.oom", "raise", times=1, after=1))
+        with fi.active(plan):
+            # the k=2 budget forces continuous evict/readmit churn
+            results = flood()
         assert plan.stats().get("memory.oom", 0) == 1
 
         # 1. zero unhandled OOM/RESOURCE_EXHAUSTED/untyped escapes
@@ -466,7 +477,7 @@ def test_chaos_four_models_budget_for_two_mixed_tenant_flood():
         assert results["admitted"] > 0
         goodput = results["served"] / results["admitted"]
         assert goodput >= 0.9, (goodput, results)
-        # 3. bounded p99 (generous floor: shared CI container)
+        # 3. bounded p99, against the same flood without the budget
         p99 = float(np.percentile(results["lat"], 99))
         assert p99 <= max(10 * p99_base, 2.0), (p99, p99_base)
         # 4. eviction churn happened and is visible

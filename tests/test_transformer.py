@@ -3,6 +3,7 @@ flash-vs-dense attention parity, hybridized CachedOp equivalence, and a
 training step.  (Beyond-reference capability — the long-context flagship;
 the sharded legs live in tests/test_parallel.py ring/ulysses.)"""
 import numpy as np
+import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import autograd, gluon
@@ -182,7 +183,6 @@ def test_generate_kv_cache_matches_eager():
                            rng=np.random.RandomState(2)).asnumpy()
     assert (s_kv == s_eager).all(), (s_kv, s_eager)
     # conflicting strategy flags are an error, not a silent choice
-    import pytest
     with pytest.raises(ValueError):
         net.generate(prefix, 2, kv_cache=True, static_shapes=False)
     # sp attention types decode over SHARDED caches and need an active
@@ -234,7 +234,6 @@ def test_beam_search_width1_is_greedy_and_scores_are_exact():
         for b in range(seq.shape[0])])
     assert np.allclose(s3.asnumpy(), resc, atol=1e-3), (s3.asnumpy(),
                                                         resc)
-    import pytest
     with pytest.raises(ValueError):
         net.beam_search(prompt, new, beam=0)
 
@@ -264,7 +263,6 @@ def test_sequence_parallel_attn_types():
     sharded kernels and matches the dense variant; without the scope it
     raises the documented error."""
     import jax
-    import pytest
     from jax.sharding import Mesh
     from mxnet_tpu import nd, parallel
     from mxnet_tpu.base import MXNetError
@@ -425,7 +423,6 @@ def test_ring_kv_decode_generate():
     same greedy tokens as an identically-initialized dense model's KV
     decode (max_len divisible by the mesh axis)."""
     import jax
-    import pytest
     from jax.sharding import Mesh
     from mxnet_tpu import parallel
 
@@ -454,10 +451,48 @@ def test_ring_kv_decode_generate():
         bad.generate(prompt, 2, kv_cache=True)
 
 
+@pytest.mark.parametrize("attn_type", ["ring", "ulysses"])
+def test_sp_kv_decode_loads_each_program_once(attn_type, caplog):
+    """The sequence-parallel KV decode builds its programs once per
+    shape: the first generate() under an sp_scope compiles no signature
+    twice, and a second one of the same shapes loads no program at all
+    (a bare shard_map bound eagerly compiled its body primitive by
+    primitive at every call and every position: 2,383 compiles of 105
+    signatures for 11 decode steps)."""
+    import logging
+    import jax
+    from jax.sharding import Mesh
+    from mxnet_tpu import parallel
+    from mxnet_tpu.observability import metrics
+
+    mesh = Mesh(np.array(jax.devices("cpu")[:4]), ("sp",))
+    # shapes no other test of the process uses: a program another test
+    # already compiled would not be compiled, or seen, here
+    net = TransformerLM(vocab=V + 2, dim=16, num_layers=2, num_heads=4,
+                        max_len=24, attn_type=attn_type)
+    net.initialize(mx.init.Xavier(), ctx=mx.cpu())
+    prompt = mx.nd.array(
+        np.random.RandomState(5).randint(0, V, (3, 4)).astype("f"))
+    with parallel.sp_scope(mesh):
+        net(mx.nd.zeros((1, 4)))       # deferred shapes, off the count
+        with caplog.at_level(logging.DEBUG,
+                             logger="jax._src.interpreters.pxla"):
+            first = net.generate(prompt, 6, kv_cache=True).asnumpy()
+        compiled = [r.getMessage().split(". Argument mapping")[0]
+                    for r in caplog.records
+                    if r.getMessage().startswith("Compiling ")]
+        assert compiled, "no compile was logged: the probe is blind"
+        twice = sorted({c for c in compiled if compiled.count(c) > 1})
+        assert not twice, twice
+        loads = metrics.PROGRAM_LOADS.value
+        again = net.generate(prompt, 6, kv_cache=True).asnumpy()
+    assert metrics.PROGRAM_LOADS.value == loads
+    assert (again == first).all()
+
+
 def test_sample_top_k_ties_and_validation():
     """top_k keeps exactly k survivors under ties (top_k=1 == argmax
     even with duplicated maxima); invalid top_k/top_p raise."""
-    import pytest
     tied = mx.nd.array(np.array([[3.0, 3.0, 1.0, 0.0]], "f"))
     for _ in range(5):
         nxt = TransformerLM._sample(tied, 1.0, np.random.RandomState(0),
@@ -476,7 +511,6 @@ def test_ulysses_kv_decode_matches_dense():
     kv_cache=True under an sp_scope with the same greedy tokens as an
     identically-initialized dense model."""
     import jax
-    import pytest
     from jax.sharding import Mesh
     from mxnet_tpu import nd, parallel
 
@@ -556,7 +590,6 @@ def test_beam_and_export_refuse_sp_models():
     on sp-attention models they refuse loudly (allow_sp=False) even
     under an active scope."""
     import jax
-    import pytest
     from jax.sharding import Mesh
     from mxnet_tpu import parallel
 
