@@ -1,0 +1,201 @@
+"""A current decoder language model (gluon): RMS norm, rotary positions,
+latent attention, gated feed-forwards, and sparse experts of which this
+device holds a share.
+
+    x + attn(n1(x));  x + ffn(n2(x))
+
+The first `first_k_dense` layers have a dense gated feed-forward, the rest
+an expert layer: a router over ALL `num_experts` (sigmoid scores plus a
+selection bias, `top_k` a token), the stacked matrices of the
+`held_experts` experts from `first_expert` on, and shared experts that
+every token passes.  With `held_experts < num_experts` the block computes
+this device's part of an expert-parallel layer without its exchange: a
+chosen expert that is absent adds nothing (ops/decoder.py).
+
+The whole stack is one HybridBlock, so a step is one CachedOp forward and
+one backward, as `TransformerLM`'s is.  There is no decode path yet
+(ROADMAP R1 / R6: a latent leaf in the decode cache).
+"""
+from __future__ import annotations
+
+from ...observability import metrics as _metrics
+from .. import nn
+from ..block import HybridBlock
+
+
+def _dense(units, in_units, prefix):
+    return nn.Dense(units, use_bias=False, flatten=False, in_units=in_units,
+                    prefix=prefix)
+
+
+class RMSNorm(HybridBlock):
+    """x / sqrt(mean(x^2) + eps) * gamma over the last axis."""
+
+    def __init__(self, in_channels, epsilon=1e-5, **kw):
+        super().__init__(**kw)
+        self._eps = epsilon
+        self.gamma = self.params.get("gamma", shape=(in_channels,),
+                                     init="ones")
+
+    def hybrid_forward(self, F, x, gamma):
+        return F.rms_norm(x, gamma, eps=self._eps)
+
+
+class LatentAttention(HybridBlock):
+    """Causal multi-head latent attention over (B, T, D): queries through
+    a normalised low-rank latent; keys and values up-projected from ONE
+    normalised latent a token, plus one rotary key shared by all heads.
+    attn_type: 'dense' | 'flash' (the Pallas kernel; nope + rope == v)."""
+
+    def __init__(self, dim, num_heads, q_rank, kv_rank, nope_dim, rope_dim,
+                 v_dim, attn_type="dense", epsilon=1e-5, rope_base=10000.0,
+                 **kw):
+        super().__init__(**kw)
+        self._kv_rank, self._rope_dim = kv_rank, rope_dim
+        self._attn = dict(num_heads=num_heads, nope_dim=nope_dim,
+                          rope_dim=rope_dim, v_dim=v_dim,
+                          rope_base=rope_base, impl=attn_type)
+        with self.name_scope():
+            self.q_a = _dense(q_rank, dim, "qa_")
+            self.q_norm = RMSNorm(q_rank, epsilon, prefix="qnorm_")
+            self.q_b = _dense(num_heads * (nope_dim + rope_dim), q_rank,
+                              "qb_")
+            self.kv_a = _dense(kv_rank + rope_dim, dim, "kva_")
+            self.kv_norm = RMSNorm(kv_rank, epsilon, prefix="kvnorm_")
+            self.kv_b = _dense(num_heads * (nope_dim + v_dim), kv_rank,
+                               "kvb_")
+            self.proj = _dense(dim, num_heads * v_dim, "proj_")
+
+    def hybrid_forward(self, F, x):
+        q = self.q_b(self.q_norm(self.q_a(x)))
+        kva = self.kv_a(x)
+        latent = F.slice_axis(kva, axis=2, begin=0, end=self._kv_rank)
+        k_rope = F.slice_axis(kva, axis=2, begin=self._kv_rank,
+                              end=self._kv_rank + self._rope_dim)
+        kv = self.kv_b(self.kv_norm(latent))
+        return self.proj(F.latent_attention(q, kv, k_rope, **self._attn))
+
+
+class GatedFeedForward(HybridBlock):
+    """down(silu(gate(x)) * up(x)), no biases."""
+
+    def __init__(self, dim, ffn_dim, **kw):
+        super().__init__(**kw)
+        with self.name_scope():
+            self.gate = _dense(ffn_dim, dim, "gate_")
+            self.up = _dense(ffn_dim, dim, "up_")
+            self.down = _dense(dim, ffn_dim, "down_")
+
+    def hybrid_forward(self, F, x):
+        return self.down(F.Activation(self.gate(x), act_type="silu")
+                         * self.up(x))
+
+
+class MoEFeedForward(HybridBlock):
+    """Sparse experts, this device's share, plus the shared experts.
+
+    Trainable: the router's weight (num_experts, D) and the stacked gate,
+    up (held, D, F) and down (held, F, D) matrices of the held experts.
+    Auxiliary (`grad_req="null"`, float32 whatever the net is cast to, as a
+    bfloat16 counter stops counting at 256): `select_bias` (num_experts,),
+    added to the scores for the selection only, and `load` (held + 1,), to
+    which the forward pass adds the assignments of each held expert and,
+    last, of absent ones (read by `observability.metrics.refresh_moe`)."""
+
+    def __init__(self, dim, ffn_dim, num_experts, top_k, held_experts=None,
+                 first_expert=0, shared_experts=1, routed_scale=1.0,
+                 norm_topk=True, **kw):
+        super().__init__(**kw)
+        held = num_experts if held_experts is None else held_experts
+        self._moe = dict(num_experts=num_experts, top_k=top_k,
+                         first=first_expert, held=held, scale=routed_scale,
+                         norm_topk=norm_topk)
+        with self.name_scope():
+            self.router_weight = self.params.get(
+                "router_weight", shape=(num_experts, dim))
+            self.select_bias = self.params.get(
+                "select_bias", shape=(num_experts,), init="zeros",
+                grad_req="null", differentiable=False)
+            self.gate_weight = self.params.get(
+                "gate_weight", shape=(held, dim, ffn_dim))
+            self.up_weight = self.params.get(
+                "up_weight", shape=(held, dim, ffn_dim))
+            self.down_weight = self.params.get(
+                "down_weight", shape=(held, ffn_dim, dim))
+            self.load = self.params.get(
+                "load", shape=(held + 1,), init="zeros", grad_req="null",
+                differentiable=False)
+            self.shared = GatedFeedForward(
+                dim, ffn_dim * shared_experts, prefix="shared_") \
+                if shared_experts else None
+        _metrics.watch_moe_layer(self)
+
+    def cast(self, dtype):
+        super().cast(dtype)
+        self.select_bias.cast("float32")
+        self.load.cast("float32")
+
+    def hybrid_forward(self, F, x, router_weight, select_bias, gate_weight,
+                       up_weight, down_weight, load):
+        y = F.moe_ffn(x, router_weight, select_bias, gate_weight, up_weight,
+                      down_weight, load, **self._moe)
+        return y if self.shared is None else y + self.shared(x)
+
+
+class DecoderBlock(HybridBlock):
+    def __init__(self, attn, ffn, dim, epsilon=1e-5, **kw):
+        super().__init__(**kw)
+        with self.name_scope():
+            self.n1 = RMSNorm(dim, epsilon, prefix="n1_")
+            self.attn = attn(prefix="attn_")
+            self.n2 = RMSNorm(dim, epsilon, prefix="n2_")
+            self.ffn = ffn(prefix="ffn_")
+
+    def hybrid_forward(self, F, x):
+        x = x + self.attn(self.n1(x))
+        return x + self.ffn(self.n2(x))
+
+
+class DecoderLM(HybridBlock):
+    """Token ids (B, T) -> logits (B, T, vocab); untied head, no biases."""
+
+    def __init__(self, vocab, dim, num_layers, num_heads, q_rank, kv_rank,
+                 nope_dim, rope_dim, v_dim, dense_ffn_dim, expert_ffn_dim,
+                 num_experts, top_k, held_experts=None, first_expert=0,
+                 shared_experts=1, first_k_dense=1, routed_scale=1.0,
+                 norm_topk=True, epsilon=1e-5, rope_base=10000.0,
+                 attn_type="dense", **kw):
+        super().__init__(**kw)
+
+        def attn(prefix):
+            return LatentAttention(dim, num_heads, q_rank, kv_rank, nope_dim,
+                                   rope_dim, v_dim, attn_type, epsilon,
+                                   rope_base, prefix=prefix)
+
+        def dense_ffn(prefix):
+            return GatedFeedForward(dim, dense_ffn_dim, prefix=prefix)
+
+        def expert_ffn(prefix):
+            return MoEFeedForward(dim, expert_ffn_dim, num_experts, top_k,
+                                  held_experts, first_expert, shared_experts,
+                                  routed_scale, norm_topk, prefix=prefix)
+
+        with self.name_scope():
+            self.tok = nn.Embedding(vocab, dim, prefix="tok_")
+            self.blocks = nn.HybridSequential(prefix="blocks_")
+            for i in range(num_layers):
+                self.blocks.add(DecoderBlock(
+                    attn, dense_ffn if i < first_k_dense else expert_ffn,
+                    dim, epsilon, prefix=f"l{i}_"))
+            self.norm_f = RMSNorm(dim, epsilon, prefix="normf_")
+            self.head = _dense(vocab, dim, "head_")
+
+    def hybrid_forward(self, F, tokens):
+        return self.head(self.norm_f(self.blocks(self.tok(tokens))))
+
+    def generate(self, *args, **kwargs):
+        raise NotImplementedError(
+            "DecoderLM has no decode path yet: a latent leaf in the decode "
+            "cache is ROADMAP R1 / R6")
+
+    _kv_forward = generate

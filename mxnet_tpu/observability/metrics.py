@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import json as _json
 import threading
+import weakref
 from typing import Dict, List, Optional, Tuple
 
 from ..base import getenv
@@ -671,6 +672,74 @@ SERVE_BUCKET_HBM_BYTES = Gauge(
     "of the AOT executable, set once at precompile; labels are the "
     "bounded bucket-lattice set).  The multi-model HBM budgeter's "
     "per-bucket cost table — what an LRU bucket eviction would free")
+# -- mixture-of-experts layers ------------------------------------------------
+# Every expert block (gluon.model_zoo.transformer.MoEFeedForward) keeps a
+# float32 load counter on its device, which its forward pass adds to through
+# the auxiliary path (as BatchNorm's moving statistics travel).  Nothing is
+# read in the step: `refresh_moe()` stacks the counters of the live blocks
+# and reads them in ONE transfer when the registry is exported or a reader
+# asks.
+_moe_layers = weakref.WeakKeyDictionary()  # block -> its counter as last read
+
+MOE_ASSIGNMENTS = Counter(
+    "mxnet_moe_assignments_total",
+    "(token, choice) pairs the routers of this process's expert layers "
+    "assigned, by where the chosen expert lives (held = one of the experts "
+    "this device holds, absent = an expert of another device, whose part "
+    "of the result is not computed here).  Filled from the layers' "
+    "device-side load counters at export (one device read), never in the "
+    "step")
+MOE_LOAD_MAX_OVER_MEAN = Gauge(
+    "mxnet_moe_expert_load_max_over_mean",
+    "Largest assignment count of any held expert of any layer since the "
+    "layers were built, over the mean of all of them: 1.0 is even routing; "
+    "the busiest expert sets a grouped product's critical path and, across "
+    "devices, the straggler.  Refreshed with "
+    "mxnet_moe_assignments_total")
+MOE_ROWS = Gauge(
+    "mxnet_moe_rows",
+    "Rows of one expert layer's grouped products, set from shapes when the "
+    "op is traced, by kind: required = tokens x top_k x held / num_experts "
+    "(the expectation under even routing), multiplied = the rows the "
+    "products are issued for, padding included (in expectation where the "
+    "product skips the row tiles its group sizes leave empty; tokens x "
+    "top_k, the dropless bound, where it multiplies the whole buffer)")
+
+
+def watch_moe_layer(block) -> None:
+    """Register an expert block whose `load` parameter `refresh_moe` reads."""
+    _moe_layers[block] = None
+
+
+def refresh_moe() -> None:
+    """Pull the load counters of every live expert layer (one stacked
+    device read) into MOE_ASSIGNMENTS and MOE_LOAD_MAX_OVER_MEAN."""
+    import numpy as np
+    blocks, arrays = [], []
+    for block in list(_moe_layers):
+        data = getattr(block.load, "_data", None)
+        if data is not None:  # initialized
+            blocks.append(block)
+            arrays.append(data._data.reshape(-1))
+    if not blocks:
+        return
+    import jax.numpy as jnp
+    flat = np.asarray(jnp.concatenate(arrays), dtype=np.float64)
+    rows = np.split(flat, np.cumsum([a.shape[0] for a in arrays])[:-1])
+    held_all = []
+    for block, row in zip(blocks, rows):
+        last = _moe_layers.get(block)
+        delta = row - last if last is not None and (row >= last).all() \
+            else row
+        _moe_layers[block] = row
+        MOE_ASSIGNMENTS.inc(float(delta[:-1].sum()), where="held")
+        MOE_ASSIGNMENTS.inc(float(delta[-1]), where="absent")
+        held_all.append(row[:-1])
+    held_all = np.concatenate(held_all)
+    if held_all.sum() > 0:
+        MOE_LOAD_MAX_OVER_MEAN.set(float(held_all.max() / held_all.mean()))
+
+
 FUSED_DTYPE_RECOMPILES = Counter(
     "mxnet_fused_dtype_policy_recompiles_total",
     "Compiled-step program recompiles caused by a dtype-policy "
@@ -1100,6 +1169,10 @@ def _refresh_export_gauges() -> None:
     try:
         from . import memory as _mem
         _mem.refresh_gauge()
+    except Exception:  # noqa: BLE001
+        pass
+    try:
+        refresh_moe()
     except Exception:  # noqa: BLE001
         pass
 

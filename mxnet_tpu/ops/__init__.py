@@ -19,5 +19,6 @@ from . import compat
 from . import vision
 from . import contrib
 from . import flash_attention
+from . import decoder
 from . import custom
 from . import sparse_ops

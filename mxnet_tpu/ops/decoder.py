@@ -1,0 +1,227 @@
+"""Operators of a current decoder block: RMS norm, rotary positions, the
+latent-attention assembly, and a mixture-of-experts feed-forward that is
+told which experts it holds.
+
+Registered as ops (not python in the gluon blocks) for the reason
+`_contrib_multihead_attention` is: the head splits, the rotation tables and
+the ragged split of the expert buffer depend on shapes, and an op always
+sees concrete shapes, so the blocks hybridize to one graph.
+
+The expert op is what an expert-parallel step wraps its exchange around:
+it routes over ALL `num_experts`, and computes the part of the result that
+the experts `[first, first + held)` give.  A chosen expert that is absent
+adds nothing.  No capacity, no dropped token: the (token, choice) pairs
+are sorted by expert into a buffer of `T * top_k` rows, the most a
+dropless layer can need, and the three products of the gated feed-forward
+run as grouped products over that ragged split (`lax.ragged_dot`; on the
+TPU XLA lowers it to a Mosaic grouped-matmul call that visits only the row
+tiles its group sizes cover, so the tail of the buffer that no held expert
+owns costs no MXU time).
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..base import Arg
+from ..observability import metrics as _metrics
+from .flash_attention import _dense_reference, _flash_attention
+from .registry import register
+
+# rows of one tile of the TPU's grouped product (the v5e compile of
+# `lax.ragged_dot` carries metadata of m / 512 + groups - 1 tile visits)
+GROUPED_TILE_ROWS = 512
+
+
+@register("_contrib_rms_norm", input_names=("data", "gamma"),
+          aliases=("rms_norm",), args=[Arg("eps", float, 1e-5)])
+def _rms_norm(p, x, gamma):
+    """x / sqrt(mean(x^2) + eps) * gamma over the last axis, the
+    statistics in float32 whatever the activations' dtype."""
+    xf = x.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                    + p["eps"])
+    return (xf * inv * gamma.astype(jnp.float32)).astype(x.dtype)
+
+
+def _rope(x, base, time_axis=1):
+    """Rotary positions over the whole last axis of `x`, positions
+    0..T-1 along `time_axis`.  Pairing: dimension i turns with dimension
+    i + R/2 (the "split halves" form); the interleaved form differs from
+    it by a fixed permutation of the projection's columns."""
+    rot = x.shape[-1]
+    half = rot // 2
+    inv_freq = base ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rot)
+    ang = jnp.arange(x.shape[time_axis], dtype=jnp.float32)[:, None] \
+        * inv_freq[None, :]                                  # (T, R/2)
+    shape = [1] * x.ndim
+    shape[time_axis], shape[-1] = x.shape[time_axis], half
+    cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+@register("_contrib_rotary_embedding", input_names=("data",),
+          aliases=("rotary_embedding",),
+          args=[Arg("base", float, 10000.0), Arg("time_axis", int, 1)])
+def _rotary_embedding(p, x):
+    """Rotary position embedding over the last axis of (B, T, ..., R)."""
+    if x.shape[-1] % 2:
+        raise ValueError(f"rotary_embedding: the last axis ({x.shape[-1]}) "
+                         "must be even")
+    return _rope(x, p["base"], p["time_axis"] % x.ndim)
+
+
+@register("_contrib_latent_attention",
+          input_names=("q", "kv", "k_rope"), aliases=("latent_attention",),
+          args=[Arg("num_heads", int, required=True),
+                Arg("nope_dim", int, required=True),
+                Arg("rope_dim", int, required=True),
+                Arg("v_dim", int, required=True),
+                Arg("rope_base", float, 10000.0),
+                Arg("impl", str, "dense")])
+def _latent_attention(p, q, kv, k_rope):
+    """Causal attention as a latent-attention block assembles it.
+
+    q: (B, T, H * (nope + rope)), the up-projected queries, each head
+    `q_nope | q_rope`; kv: (B, T, H * (nope + v)), the up-projected latent,
+    each head `k_nope | v`; k_rope: (B, T, rope), ONE rotary key shared by
+    all heads.  Rotary positions turn `q_rope` and `k_rope`; a head's key
+    is `k_nope | rope(k_rope)`; scores are scaled by (nope + rope) ** -0.5.
+    Returns (B, T, H * v).  impl='flash' is the Pallas kernel (it takes one
+    head size for q, k and v, so nope + rope must equal v), 'dense'
+    materializes the scores.
+    """
+    B, T, _ = q.shape
+    H, dn, dr, dv = p["num_heads"], p["nope_dim"], p["rope_dim"], p["v_dim"]
+    q = q.reshape(B, T, H, dn + dr)
+    kv = kv.reshape(B, T, H, dn + dv)
+    q = jnp.concatenate([q[..., :dn], _rope(q[..., dn:], p["rope_base"])],
+                        axis=-1)
+    kr = _rope(k_rope, p["rope_base"])[:, :, None, :]
+    k = jnp.concatenate([kv[..., :dn],
+                         jnp.broadcast_to(kr, (B, T, H, dr))], axis=-1)
+    q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, kv[..., dn:]))
+    scale = float(dn + dr) ** -0.5
+    if p["impl"] == "flash":
+        if dn + dr != dv:
+            raise ValueError(
+                f"latent_attention impl='flash': the kernel takes one head "
+                f"size, got {dn}+{dr} for q and k and {dv} for v")
+        out = _flash_attention(q, k, v, scale, True, min(128, T),
+                               min(128, T))
+    elif p["impl"] == "dense":
+        out = _dense_reference(q, k, v, scale, True)
+    else:
+        raise ValueError(f"latent_attention impl={p['impl']!r}: choose "
+                         "'dense' or 'flash'")
+    return out.transpose(0, 2, 1, 3).reshape(B, T, H * dv)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inv):
+    """x[perm] for a permutation: the backward pass is a gather through
+    the inverse, not the scatter-add a plain gather transposes to."""
+    return x.at[perm].get(mode="promise_in_bounds", unique_indices=True)
+
+
+_permute_rows.defvjp(
+    lambda x, perm, inv: (_permute_rows(x, perm, inv), (perm, inv)),
+    lambda res, g: (_permute_rows(g, res[1], res[0]), None, None))
+
+
+def route(h, router_w, bias, top_k, scale, norm_topk):
+    """(chosen experts (T, k) int32, their weights (T, k) float32): sigmoid
+    scores in float32; the `top_k` experts of largest score + bias; weights
+    from the scores WITHOUT the bias, normalised over the chosen, times
+    `scale`."""
+    s = jax.nn.sigmoid(jnp.einsum(
+        "td,ed->te", h.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, idx = lax.top_k(s + lax.stop_gradient(bias.astype(jnp.float32)),
+                       top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * scale
+
+
+def _expert_rows(h, key, w, gate_w, up_w, down_w):
+    """The held experts' part: rows sorted by `key` (held experts 0..held-1,
+    absent ones `held`, which sort last), three grouped products over the
+    ragged split, unsorted and summed over a token's choices."""
+    held = gate_w.shape[0]
+    rows, top_k = key.shape[0], w.shape[1]
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(rows, dtype=jnp.int32), unique_indices=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(held + 1, dtype=jnp.int32),
+                    axis=0, dtype=jnp.int32)
+    groups = sizes[:held]
+    # The tail of the buffer belongs to absent experts.  The TPU's grouped
+    # product leaves rows it does not visit as they were in memory (read on
+    # the chip: values up to 5 in the tail), on the way back too, so the
+    # tail is cut off on both sides of the products: what comes out of them
+    # there is not a result, and what flows back into them is not a gradient.
+    live = (jnp.arange(rows, dtype=jnp.int32) < jnp.sum(groups))[:, None]
+    # every choice's copy of its token, sorted by expert
+    xs = _permute_rows(jnp.repeat(h, top_k, axis=0), order, inv)
+    xs = jnp.where(live, xs, jnp.zeros((), xs.dtype))
+    act = jax.nn.silu(lax.ragged_dot(xs, gate_w, groups)) \
+        * lax.ragged_dot(xs, up_w, groups)
+    ys = lax.ragged_dot(act, down_w, groups)                # (rows, D)
+    ys = jnp.where(live, ys, jnp.zeros((), ys.dtype))
+    y = _permute_rows(ys, inv, order).reshape(-1, top_k, ys.shape[-1])
+    y = jnp.sum(y.astype(jnp.float32) * w[:, :, None], axis=1)
+    return y.astype(h.dtype), sizes
+
+
+@register("_contrib_moe_ffn",
+          input_names=("data", "router_weight", "select_bias",
+                       "gate_weight", "up_weight", "down_weight", "load"),
+          aliases=("moe_ffn",), aux_inputs=[2, 6], f32_inputs=(2, 6),
+          args=[Arg("num_experts", int, required=True),
+                Arg("top_k", int, required=True),
+                Arg("first", int, 0), Arg("held", int, required=True),
+                Arg("scale", float, 1.0), Arg("norm_topk", bool, True)])
+def _moe_ffn(p, x, router_w, bias, gate_w, up_w, down_w, load):
+    """Mixture-of-experts gated feed-forward over the experts held here.
+
+    data (..., D); router_weight (num_experts, D); select_bias
+    (num_experts,), auxiliary, added to the scores for the selection only;
+    gate_weight, up_weight (held, D, F) and down_weight (held, F, D), the
+    stacked matrices of experts `first .. first + held - 1`; load
+    (held + 1,), auxiliary float32: the forward pass adds the assignments
+    each held expert got and, last, those that fell on absent experts.
+
+    Routes every token over all `num_experts` (sigmoid scores, `top_k` a
+    token) and returns sum_i w_i E_i(x) over the chosen experts that are
+    held: a partial result where held < num_experts.  Dropless: the buffer
+    has `T * top_k` rows, so every token sent to one held expert still
+    equals the reference.  The feed-forward's intermediates are recomputed
+    in the backward pass (`jax.checkpoint`), as jobs that fill the chip do.
+    """
+    E, k, first, held = (p["num_experts"], p["top_k"], p["first"],
+                         p["held"])
+    if gate_w.shape[0] != held or not 0 <= first <= E - held:
+        raise ValueError(f"moe_ffn: held={held} first={first} of {E} "
+                         f"experts against {gate_w.shape[0]} stacked")
+    h = x.reshape(-1, x.shape[-1])
+    idx, w = route(h, router_w, bias, k, p["scale"], p["norm_topk"])
+    local = idx - first
+    key = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
+    rows = key.shape[0]
+    required = rows * held / E
+    _metrics.MOE_ROWS.set(required, kind="required")
+    # a product that skips the tiles its group sizes leave empty: the rows
+    # it visits in expectation under even routing, every held expert's
+    # split rounded out to whole tiles, never more than the buffer
+    _metrics.MOE_ROWS.set(
+        min(rows, required + held * GROUPED_TILE_ROWS), kind="multiplied")
+    y, sizes = jax.checkpoint(_expert_rows)(h, key, w, gate_w, up_w, down_w)
+    new_load = load + sizes.astype(load.dtype)
+    return (y.reshape(x.shape), lax.stop_gradient(bias),
+            lax.stop_gradient(new_load))

@@ -9,7 +9,7 @@ k steps — VMEM usage is O(blk·D), independent of sequence length.  Composes
 with `parallel.sequence_parallel.ring_attention`, which rotates K/V shards
 across chips while this kernel handles the on-chip block math.
 
-Backward is a custom VJP that recomputes scores blockwise (lax.map over
+Backward is a custom VJP that recomputes scores blockwise (a loop over
 q-blocks): peak extra memory O(blk_q · Tk) per (batch, head) — linear in
 sequence length, the standard flash recompute trade.
 """
@@ -131,8 +131,18 @@ def _fa_fwd(q, k, v, scale, causal, blk_q, blk_k):
     return o, (q, k, v, o)
 
 
+# The backward pass's per-block key and value gradients, stacked over the
+# query blocks before they are summed, are B*H x (Tq / blk) x Tk x D floats
+# each.  Past this many bytes a stack is not built: the blocks' gradients
+# are summed in the loop's carry instead.  At B*H 64, T 2048, D 64 a stack
+# is 0.5 GiB and that program stays as it was; at B*H 40, D 256 it would be
+# 1.25 GiB twice over, 2.9 GB of a backward program's temporaries (4.62 GB
+# stacked, 1.65 GB carried: v5e compiles, PR 27).
+STACK_BYTES_MAX = 2 ** 30
+
+
 def _fa_bwd(scale, causal, blk_q, blk_k, res, g):
-    """Blockwise recompute backward: lax.map over q blocks keeps peak
+    """Blockwise recompute backward: a loop over q blocks keeps peak
     score memory at O(blk_q · Tk) per (batch, head).
 
     Flash backward identities (FlashAttention paper, §B):
@@ -144,6 +154,7 @@ def _fa_bwd(scale, causal, blk_q, blk_k, res, g):
     Tk = k.shape[2]
     blk = blk_q if Tq % blk_q == 0 else Tq
     nq = Tq // blk
+    carry_kv = B * H * nq * Tk * D * 4 > STACK_BYTES_MAX
 
     qf = q.astype(jnp.float32)
     kf = k.astype(jnp.float32)
@@ -172,6 +183,15 @@ def _fa_bwd(scale, causal, blk_q, blk_k, res, g):
             dv = p.T @ gs                                     # (Tk, D)
             return dq, dk, dv
 
+        if carry_kv:
+            def step(acc, i):
+                dq, dk, dv = q_block(i)
+                return (acc[0] + dk, acc[1] + dv), dq
+
+            (dk, dv), dqs = jax.lax.scan(
+                step, (jnp.zeros_like(k1), jnp.zeros_like(v1)),
+                jnp.arange(nq))
+            return dqs.reshape(Tq, D), dk, dv
         dqs, dks, dvs = jax.lax.map(q_block, jnp.arange(nq))
         return dqs.reshape(Tq, D), dks.sum(0), dvs.sum(0)
 

@@ -412,6 +412,8 @@ def _activation(p, x):
         return jnp.logaddexp(x, 0.0)
     if t == "softsign":
         return x / (1 + jnp.abs(x))
+    if t == "silu":
+        return jax.nn.silu(x)
     raise MXNetError(f"unknown act_type {t}")
 
 

@@ -7,6 +7,17 @@ capacity slots (static shapes — XLA-friendly), exchanged with
 `lax.all_to_all` over ICI, transformed by the local expert, and combined
 back weighted by the gate probability.  The load-balancing auxiliary loss
 follows the Switch Transformer formulation.
+
+How this differs from the expert layer the model zoo trains
+(`ops/decoder.py` `moe_ffn`, `gluon.model_zoo.decoder.MoEFeedForward`):
+here the router is a softmax with top-1/2 choices, tokens go into capacity
+slots and those beyond a slot's capacity are DROPPED, there is one expert
+a device, and the layer is a raw `shard_map` function that neither
+`Trainer` nor `CachedOp` sees.  `moe_ffn` routes over all experts with
+sigmoid scores and a selection bias, holds any number of experts, drops
+nothing (grouped products over a ragged split) and runs inside the normal
+step; it computes one device's share, and is what an expert-parallel step
+wraps its exchange around (ROADMAP R5).
 """
 from __future__ import annotations
 
