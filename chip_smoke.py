@@ -436,8 +436,7 @@ def phase_kernel(s):
                                                dtype=np.float32),
                            ctx=ctx).astype("bfloat16") for _ in range(3))
     lowered = jax.jit(
-        lambda a_, b_, c_: fa._flash_attention(a_, b_, c_, scale, True,
-                                               128, 128)
+        lambda a_, b_, c_: fa._flash_attention(a_, b_, c_, scale, True)
     ).lower(q._data, k._data, v._data).as_text()
     mosaic = "tpu_custom_call" in lowered
     assert mosaic or s.rehearsal, \

@@ -705,6 +705,23 @@ MOE_ROWS = Gauge(
     "product skips the row tiles its group sizes leave empty; tokens x "
     "top_k, the dropless bound, where it multiplies the whole buffer)")
 
+# -- the forward attention kernel ---------------------------------------------
+# Set from shapes when ops/flash_attention.py traces a call, as MOE_ROWS is:
+# nothing runs in the step.  They hold the call traced last.
+FLASH_FWD_BLOCKS = Gauge(
+    "mxnet_flash_fwd_blocks",
+    "(query tile, key tile) pairs of one forward flash-attention call, over "
+    "all its batch entries and heads, by kind: grid = the pairs the kernel's "
+    "grid holds, computed = those it multiplies (under a causal mask the "
+    "pairs wholly above the diagonal are neither fetched nor computed; "
+    "without one the two are equal)")
+FLASH_FWD_TILE = Gauge(
+    "mxnet_flash_fwd_tile",
+    "Rows of the query tile (dim=q) and of the key / value tile (dim=k) "
+    "that the call counted in mxnet_flash_fwd_blocks ran with: chosen from "
+    "the shapes (ops/flash_attention.py _fa_tiles) unless the caller of "
+    "flash_attention gave block_q / block_k")
+
 
 def watch_moe_layer(block) -> None:
     """Register an expert block whose `load` parameter `refresh_moe` reads."""

@@ -92,8 +92,10 @@ def _latent_attention(p, q, kv, k_rope):
     all heads.  Rotary positions turn `q_rope` and `k_rope`; a head's key
     is `k_nope | rope(k_rope)`; scores are scaled by (nope + rope) ** -0.5.
     Returns (B, T, H * v).  impl='flash' is the Pallas kernel (it takes one
-    head size for q, k and v, so nope + rope must equal v), 'dense'
-    materializes the scores.
+    head size for q, k and v, so nope + rope must equal v; it chooses its
+    tiles from T, that head size and the dtype, multiplies q, k and v in
+    the dtype they have with softmax and accumulation in float32, and
+    skips the blocks above the diagonal), 'dense' materializes the scores.
     """
     B, T, _ = q.shape
     H, dn, dr, dv = p["num_heads"], p["nope_dim"], p["rope_dim"], p["v_dim"]
@@ -111,8 +113,7 @@ def _latent_attention(p, q, kv, k_rope):
             raise ValueError(
                 f"latent_attention impl='flash': the kernel takes one head "
                 f"size, got {dn}+{dr} for q and k and {dv} for v")
-        out = _flash_attention(q, k, v, scale, True, min(128, T),
-                               min(128, T))
+        out = _flash_attention(q, k, v, scale, True)
     elif p["impl"] == "dense":
         out = _dense_reference(q, k, v, scale, True)
     else:

@@ -4,18 +4,36 @@ The hot-op showcase for the Pallas path (`/opt/skills/guides/pallas_guide.md`):
 blocked online-softmax attention that never materializes the (T, T) score
 matrix.  The grid is (batch*heads, q_blocks, k_blocks) with the k dimension
 sequential: each program sees one (blk_q, D) query block and one (blk_k, D)
-key/value block in VMEM, carrying running max/sum/accumulator scratch across
-k steps — VMEM usage is O(blk·D), independent of sequence length.  Composes
-with `parallel.sequence_parallel.ring_attention`, which rotates K/V shards
-across chips while this kernel handles the on-chip block math.
+key/value block in VMEM and walks the key block in `sub`-wide sub-tiles,
+carrying running max/sum/accumulator scratch across sub-tiles and k steps.
+
+What the forward kernel does with a call (`_fa_tiles`, `_fa_kernel`):
+
+- The tiles come from the shapes: the largest (blk_q, sub) scores tile and
+  then the largest key/value block that a VMEM budget holds, the whole key
+  length where it fits (k and v are then fetched once a head).  At T 2048
+  that is 512 x 512 under a resident key for head sizes 64 and 256 alike.
+- q, k and v go to the MXU in the dtype they came in (bfloat16 stays
+  bfloat16, float32 stays float32) and the exponentials go to the second
+  product in v's dtype; scores, running max and sum, the exponentials and
+  the accumulator are float32.
+- Under a causal mask the sub-tiles wholly above the diagonal are neither
+  fetched nor computed, and only those the diagonal crosses are masked.
+
+`mxnet_flash_fwd_blocks` / `mxnet_flash_fwd_tile` (observability/metrics.py)
+hold the grid, the computed share and the tiles of the call traced last.
+Composes with `parallel.sequence_parallel.ring_attention`, which rotates
+K/V shards across chips while this kernel handles the on-chip block math.
 
 Backward is a custom VJP that recomputes scores blockwise (a loop over
-q-blocks): peak extra memory O(blk_q · Tk) per (batch, head) — linear in
-sequence length, the standard flash recompute trade.
+q-blocks of its own size, `BWD_BLOCK`): peak extra memory O(blk · Tk) per
+(batch, head) — linear in sequence length, the standard flash recompute
+trade.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -27,15 +45,32 @@ from .registry import register
 NEG_INF = -1e30
 
 
+def _lanes(x, n):
+    """A lane-replicated (rows, 128) value at n lanes."""
+    if n % 128 == 0:
+        return jnp.tile(x, (1, n // 128))
+    if n < 128:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-               scale, causal, blk_q, blk_k):
+               scale, scale_q, causal, blk_q, blk_k, sub):
     """Grid (BH, nq, nk); nk is sequential — scratch carries the online
-    softmax state across k steps.  The running max and sum stay
-    two-dimensional, (blk_q, 1) values broadcast over a 128-lane scratch
-    row: Mosaic has no layout for rank-1 vectors."""
+    softmax state across k steps, and within a step across the `sub`-wide
+    sub-tiles of the (blk_k, D) key / value block.  The running max and
+    sum are (blk_q, 1) values replicated over a 128-lane scratch row:
+    Mosaic has no layout for rank-1 vectors, and whole vregs go in and out
+    of the scratch without a lane broadcast.
+
+    q, k and v reach the MXU in the dtype they came in; scores, softmax
+    state and the accumulator are float32.  `scale_q` says the scale may
+    go on q (the product is exact or q is float32); else it goes on the
+    float32 scores."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
+    n_sub = blk_k // sub
 
     @pl.when(ki == 0)
     def _init():
@@ -43,33 +78,62 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[...].astype(jnp.float32) * scale           # (blk_q, D)
-    k = k_ref[...].astype(jnp.float32)                   # (blk_k, D)
-    v = v_ref[...].astype(jnp.float32)
-    # q @ k^T as a contraction over D of both operands (no transpose)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    if causal:
-        q_pos = qi * blk_q + jax.lax.broadcasted_iota(
-            jnp.int32, (blk_q, blk_k), 0)
-        k_pos = ki * blk_k + jax.lax.broadcasted_iota(
-            jnp.int32, (blk_q, blk_k), 1)
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+    # positions of this step's corner: row first_q of q, column first_k of
+    # k (the tiles are unequal, so everything is compared by position)
+    first_q, first_k = qi * blk_q, ki * blk_k
+    q = q_ref[...]                                           # (blk_q, D)
+    if scale_q:
+        q = q * scale
 
-    m_prev = m_ref[:, :1]                                # (blk_q, 1)
-    l_prev = l_ref[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
-    l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+    def _update(c, masked):
+        """Sub-tile c of the key block: columns first_k + c * sub on."""
+        rows = slice(None) if n_sub == 1 else \
+            pl.ds(pl.multiple_of(c * sub, sub), sub)
+        # q @ k^T as a contraction over D of both operands (no transpose)
+        s = jax.lax.dot_general(q, k_ref[rows, :], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if not scale_q:
+            s = s * scale
+        if masked:
+            row = jax.lax.broadcasted_iota(jnp.int32, (blk_q, sub), 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, (blk_q, sub), 1)
+            s = jnp.where(row - col >= first_k + c * sub - first_q, s,
+                          NEG_INF)
+        m_prev, l_prev = m_ref[...], l_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, sub))
+        corr = jnp.exp(m_prev - m_new)
+        v = v_ref[rows, :]
+        acc_ref[...] = acc_ref[...] * _lanes(corr, acc_ref.shape[1]) \
+            + jnp.dot(p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
+        l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[...] = m_new
+
+    def _over(lo, hi, masked):
+        jax.lax.fori_loop(lo, hi, lambda c, _: _update(c, masked), None)
+
+    if causal:
+        # sub-tiles wholly under the diagonal (last column <= first row)
+        # need no mask; those the diagonal crosses are masked; those
+        # wholly above it (first column > last row) are not visited, and a
+        # key block that holds no other is not fetched (`kv_index_map`).
+        # `lax.div`, not `//`: it rounds toward zero, which below zero
+        # clamps to 0 all the same, and `//` on traced integers costs
+        # every lowering of the kernel a traced helper for each sign
+        def count(n):
+            return jnp.minimum(jnp.maximum(jax.lax.div(n, sub), 0), n_sub)
+        under = count(first_q - first_k + 1)
+        seen = count(first_q + blk_q - 1 - first_k + sub)
+        _over(0, under, False)
+        _over(under, seen, True)
+    else:
+        _over(0, n_sub, False)
 
     @pl.when(ki == nk - 1)
     def _finish():
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
+        o_ref[...] = (acc_ref[...] / _lanes(
+            jnp.maximum(l_ref[...], 1e-30), acc_ref.shape[1])
                       ).astype(o_ref.dtype)
 
 
@@ -85,17 +149,98 @@ def _dense_reference(q, k, v, scale, causal):
                       ).astype(q.dtype)
 
 
+# What one grid step may hold in VMEM by `_fa_vmem_bytes`, under the 16 MiB
+# that Mosaic grants a kernel on v5e without being asked; the scores tile
+# may take a third of it.
+VMEM_BUDGET = 12 * 2 ** 20
+
+
+def _fa_scores_bytes(blk_q, sub, itemsize):
+    """The scores tile and its exponentials in float32, and the
+    exponentials again in v's dtype for the second product."""
+    return blk_q * sub * (4 + 4 + itemsize)
+
+
+def _fa_vmem_bytes(blk_q, blk_k, sub, D, itemsize):
+    """VMEM of one grid step: the q, output, k and v blocks (each twice,
+    the pipeline's two buffers), the float32 scratch, the scores."""
+    blocks = 2 * (2 * blk_q + 2 * blk_k) * D * itemsize
+    scratch = blk_q * (D + 2 * 128) * 4
+    return blocks + scratch + _fa_scores_bytes(blk_q, sub, itemsize)
+
+
+def _fa_tiles(Tq, Tk, D, dtype, budget=VMEM_BUDGET):
+    """(blk_q, blk_k, sub) for a call, or None where nothing fits: the
+    query tile and the key sub-tile that give the largest scores tile
+    within a third of the budget (the squarer on a tie: what a causal mask
+    wastes grows with the longer side), then the largest key / value block
+    of whole sub-tiles that the rest of the budget holds, the whole of Tk
+    where it can: k and v are then fetched once a head and no grid step is
+    spent above the diagonal.  Tiles divide Tq and Tk and are multiples of
+    128, or the whole length."""
+    itemsize = jnp.dtype(dtype).itemsize
+
+    def sizes(T):
+        return [d for d in range(128, T, 128) if T % d == 0] + [T]
+
+    fits = [(bq * bs, -abs(bq - bs), bq, bs)
+            for bq in sizes(Tq) for bs in sizes(Tk)
+            if _fa_scores_bytes(bq, bs, itemsize) <= budget // 3]
+    if not fits:
+        return None
+    _, _, blk_q, sub = max(fits)
+    blk_k = max([d for d in range(sub, Tk + 1, sub) if Tk % d == 0 and
+                 _fa_vmem_bytes(blk_q, d, sub, D, itemsize) <= budget],
+                default=sub)
+    return blk_q, blk_k, sub
+
+
+def _fa_blocks(Tq, Tk, blk_q, sub, causal):
+    """(grid, computed): the (query tile, key sub-tile) pairs of one head
+    and those that a causal mask leaves something of."""
+    nq, nk = Tq // blk_q, Tk // sub
+    if not causal:
+        return nq * nk, nq * nk
+    return nq * nk, sum(min(nk, ((i + 1) * blk_q - 1) // sub + 1)
+                        for i in range(nq))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attention(q, k, v, scale, causal, blk_q, blk_k):
+def _flash_attention(q, k, v, scale, causal, blk_q=None, blk_k=None):
+    """The forward kernel.  blk_q / blk_k None: `_fa_tiles` chooses from
+    the shapes; given, they are the scores tile and the key block both."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
-    if Tq % blk_q or Tk % blk_k:
+    if blk_q is None or blk_k is None:
+        tiles = _fa_tiles(Tq, Tk, D, q.dtype)
+    else:
+        tiles = None if Tq % blk_q or Tk % blk_k else (blk_q, blk_k, blk_k)
+    if tiles is None:
         # shapes the blocking cannot tile (not an escape from compile
         # trouble: on TPU the kernel below compiles or raises)
         return _dense_reference(q, k, v, scale, causal)
+    blk_q, blk_k, sub = tiles
     from jax.experimental.pallas import tpu as pltpu
-    kernel = functools.partial(_fa_kernel, scale=scale, causal=causal,
-                               blk_q=blk_q, blk_k=blk_k)
+    from ..observability import metrics as _metrics
+    for kind, n in zip(("grid", "computed"),
+                       _fa_blocks(Tq, Tk, blk_q, sub, causal)):
+        _metrics.FLASH_FWD_BLOCKS.set(B * H * n, kind=kind)
+    _metrics.FLASH_FWD_TILE.set(blk_q, dim="q")
+    _metrics.FLASH_FWD_TILE.set(sub, dim="k")
+    # the scale goes on q where that is exact (a power of two) or float32
+    # arithmetic already; else on the float32 scores
+    scale_q = q.dtype == jnp.float32 or math.frexp(scale)[0] == 0.5
+    kernel = functools.partial(_fa_kernel, scale=scale, scale_q=scale_q,
+                               causal=causal, blk_q=blk_q, blk_k=blk_k,
+                               sub=sub)
+
+    def kv_index_map(b, i, j):
+        if causal:
+            # past the last key block that holds a position this query
+            # block may see the map stays on that block, and the pipeline
+            # issues no copy for a block it already holds
+            j = jnp.minimum(j, jax.lax.div((i + 1) * blk_q - 1, blk_k))
+        return b, j, 0
     # mxnet_tpu runs with jax_enable_x64 on, under which Python scalars
     # and the index maps' literals trace as f64/i64; Mosaic has neither,
     # so the kernel is traced with 32-bit defaults
@@ -105,8 +250,8 @@ def _flash_attention(q, k, v, scale, causal, blk_q, blk_k):
             grid=(B * H, Tq // blk_q, Tk // blk_k),
             in_specs=[
                 pl.BlockSpec((None, blk_q, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((None, blk_k, D), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((None, blk_k, D), lambda b, i, j: (b, j, 0)),
+                pl.BlockSpec((None, blk_k, D), kv_index_map),
+                pl.BlockSpec((None, blk_k, D), kv_index_map),
             ],
             out_specs=pl.BlockSpec((None, blk_q, D),
                                    lambda b, i, j: (b, i, 0)),
@@ -139,6 +284,9 @@ def _fa_fwd(q, k, v, scale, causal, blk_q, blk_k):
 # 1.25 GiB twice over, 2.9 GB of a backward program's temporaries (4.62 GB
 # stacked, 1.65 GB carried: v5e compiles, PR 27).
 STACK_BYTES_MAX = 2 ** 30
+# The backward pass's query block: its own, whatever tiles the forward
+# kernel chose, so its loops and their score temporaries stay as they were.
+BWD_BLOCK = 128
 
 
 def _fa_bwd(scale, causal, blk_q, blk_k, res, g):
@@ -152,7 +300,7 @@ def _fa_bwd(scale, causal, blk_q, blk_k, res, g):
     q, k, v, o = res
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
-    blk = blk_q if Tq % blk_q == 0 else Tq
+    blk = BWD_BLOCK if Tq % BWD_BLOCK == 0 else Tq
     nq = Tq // blk
     carry_kv = B * H * nq * Tk * D * 4 > STACK_BYTES_MAX
 
@@ -209,14 +357,18 @@ _flash_attention.defvjp(_fa_fwd, _fa_bwd)
 @register("_contrib_flash_attention", input_names=("q", "k", "v"),
           aliases=("flash_attention",),
           args=[Arg("causal", bool, False), Arg("scale", float, -1.0),
-                Arg("block_q", int, 128), Arg("block_k", int, 128)])
+                Arg("block_q", int, -1), Arg("block_k", int, -1)])
 def _flash_attention_op(p, q, k, v):
-    """Memory-efficient attention: q/k/v (B, H, T, D) → (B, H, T, D)."""
+    """Memory-efficient attention: q/k/v (B, H, T, D) → (B, H, T, D).
+
+    block_q / block_k: the scores tile of the forward kernel; left at -1
+    (both or either) the kernel chooses its tiles from the shapes."""
     scale = p["scale"] if p["scale"] > 0 else q.shape[-1] ** -0.5
-    blk_q = min(p["block_q"], q.shape[2])
-    blk_k = min(p["block_k"], k.shape[2])
+    if p["block_q"] <= 0 or p["block_k"] <= 0:
+        return _flash_attention(q, k, v, float(scale), bool(p["causal"]))
     return _flash_attention(q, k, v, float(scale), bool(p["causal"]),
-                            int(blk_q), int(blk_k))
+                            min(p["block_q"], q.shape[2]),
+                            min(p["block_k"], k.shape[2]))
 
 
 @register("_contrib_mha_decode_step",
@@ -309,8 +461,11 @@ def _multihead_attention_op(p, qkv):
     (B, T, D).  Registered as an op (not python in the gluon block) so the
     shape-dependent reshapes/masks live where shapes are always concrete —
     usable from symbol graphs and hybridized blocks.  impl='flash' routes
-    to the Pallas kernel; 'dense' materializes scores (XLA fuses the
-    softmax chain).
+    to the Pallas kernel (tiles chosen from T, the head size and the
+    dtype; q, k and v multiplied in the dtype they have, softmax and
+    accumulation in float32; under `causal` the blocks above the diagonal
+    are skipped); 'dense' materializes scores (XLA fuses the softmax
+    chain).
     """
     B, T, D3 = qkv.shape
     H = p["num_heads"]
@@ -320,8 +475,7 @@ def _multihead_attention_op(p, qkv):
     q, k, v = x[0], x[1], x[2]
     scale = p["scale"] if p["scale"] > 0 else dh ** -0.5
     if p["impl"] == "flash":
-        out = _flash_attention(q, k, v, float(scale), bool(p["causal"]),
-                               min(128, T), min(128, T))
+        out = _flash_attention(q, k, v, float(scale), bool(p["causal"]))
     elif p["impl"] in ("ring", "ulysses"):
         # sequence parallelism as a first-class impl: the mesh comes
         # from the ambient parallel.sp_scope (captured at trace time);
