@@ -101,9 +101,9 @@ chaos-serve:
 
 # graft-lint: the repo-specific static analysis gate (ISSUE 7 + 15,
 # docs/static_analysis.md).  Exit nonzero on any non-baselined finding
-# of the ten rules (thread-safety, host-sync, atomic-write, env-sync,
+# of the nine rules (thread-safety, host-sync, atomic-write, env-sync,
 # metrics-hygiene, memory-hygiene, use-after-donate, retrace-hazard,
-# gate-hygiene, bench-emit) OR any failed compiled-program contract
+# gate-hygiene) OR any failed compiled-program contract
 # (--audit-programs: donation really became input-output aliasing,
 # zero host callbacks, collective count matches the plan);
 # tests/test_analysis.py + tests/test_program_audit.py run the same

@@ -52,8 +52,8 @@ PHASES = ("device", "train_module", "train_gluon", "serve", "kernel",
           "four_chips")
 
 # Full size: the published ResNet-50 v1 configuration the repo leads with.
-# The LM is the width experiments/lm_mfu_probe.py uses (16 heads x 64),
-# depth cut to 2; T = 2048 is where a dense score matrix starts to hurt.
+# The LM is 16 heads x 64 (width 1,024), depth cut to 2; T = 2048 is
+# where a dense score matrix starts to hurt.
 FULL = dict(
     batch=256, img=224, classes=1000, steps=4, lr=0.05,
     serve_buckets=(1, 64), serve_requests=6,
@@ -102,7 +102,7 @@ class Smoke:
 
     def data(self, n):
         """n images and labels from the seed; a class-correlated patch
-        makes the loss learnable, as bench.py's data is."""
+        makes the loss learnable."""
         c = self.cfg
         rng = np.random.default_rng(0)
         labels = rng.integers(0, c["classes"], n).astype(np.float32)
@@ -177,7 +177,8 @@ def phase_device(s):
 
 # -- phase 2 -----------------------------------------------------------------
 def _train_module(s, ctxs):
-    """The bench.py stack as a user writes it; returns the record."""
+    """Module.fit over the tpu_sync kvstore and the fused update, as a
+    user writes it; returns the record."""
     mx, c = s.mx, s.cfg
     from mxnet_tpu.gluon.model_zoo import vision
     from mxnet_tpu.io import DataDesc

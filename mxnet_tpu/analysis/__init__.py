@@ -1,13 +1,13 @@
 """graft-lint: repo-specific static analysis, compiled-program contract
 audit, and runtime sanitizer (ISSUEs 7 + 15; docs/static_analysis.md).
 
-Static side — ``analysis.run(checkers, paths) -> [Finding]`` with ten
+Static side — ``analysis.run(checkers, paths) -> [Finding]`` with nine
 repo-specific rules: the PR 7 set (thread-safety, host-sync,
 atomic-write, env-sync, metrics-hygiene, memory-hygiene) plus the
 jit/program-boundary tier (use-after-donate — a def-use dataflow pass
 over donated call positions, ``analysis/dataflow.py``; retrace-hazard;
-gate-hygiene; bench-emit).  Per-finding ``# graft-lint:
-disable=<rule>`` suppression and a checked-in ``baseline.json`` for
+gate-hygiene).  Per-finding ``# graft-lint: disable=<rule>``
+suppression and a checked-in ``baseline.json`` for
 grandfathered findings.  ``make lint-graft`` / ``python -m
 mxnet_tpu.analysis`` is the CI gate; tests/test_analysis.py pins it in
 tier-1.

@@ -1,9 +1,9 @@
-"""The ten repo-specific graft-lint checkers (ISSUEs 7 + 15).
+"""The nine repo-specific graft-lint checkers (ISSUEs 7 + 15).
 
 Each rule encodes a defect class a human reviewer actually caught —
 the PR 7 set (thread-safety, host-sync, atomic-write, env-sync,
 metrics-hygiene, memory-hygiene) works at the source level; the ISSUE
-15 tier (use-after-donate, retrace-hazard, gate-hygiene, bench-emit)
+15 tier (use-after-donate, retrace-hazard, gate-hygiene)
 guards the jit/program boundary where the bug class moved after PR 10
 made the training step one opaque donated program.  The checker
 docstrings name the incidents.  All checkers are AST-based and
@@ -21,7 +21,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 from .core import FileCtx, Finding, PKG_DIR, REPO_ROOT
 
 _ENV_RE = re.compile(r"^(MXNET_|MXT_)[A-Z0-9_]+$")
-_ENV_DOC_RE = re.compile(r"\b((?:MXNET|MXT)_[A-Z0-9_]*\*?)")
+_ENV_DOC_RE = re.compile(r"\b((?:MXNET|MXT)_[A-Z0-9_]+)")
 
 
 # one dotted-call-name resolver for the whole package: dataflow.py owns
@@ -505,9 +505,8 @@ class AtomicWriteChecker:
 # helpers the AST pass can't follow still count as read.  The package
 # itself is included so a PARTIAL scan (one file) never turns every
 # documented variable into a "stale row".  Paths are repo-relative.
-_ENV_EXTRA_ROOTS = ("mxnet_tpu", "src", "tools", "bench.py", "benchmark",
-                    "chip_smoke.py", "__graft_entry__.py",
-                    "experiments", "tests", "tests_tpu", "example")
+_ENV_EXTRA_ROOTS = ("mxnet_tpu", "src", "tools", "chip_smoke.py",
+                    "__graft_entry__.py", "tests", "tests_tpu", "example")
 _ENV_DOC = os.path.join("docs", "env_var.md")
 
 
@@ -518,8 +517,7 @@ class EnvVarSyncChecker:
     1-6 reviews each found knobs that shipped undocumented.
 
     Reads are detected as ``os.environ.get/[]/setdefault``,
-    ``os.getenv`` and ``base.getenv`` calls with a literal name.  Doc
-    tokens ending in ``*`` are prefix wildcards (``MXT_BENCH_*``).
+    ``os.getenv`` and ``base.getenv`` calls with a literal name.
     """
 
     name = "env-sync"
@@ -565,23 +563,15 @@ class EnvVarSyncChecker:
                 return _const_str(sl)
         return None
 
-    def _doc_tokens(self) -> Tuple[Set[str], List[str]]:
+    def _doc_tokens(self) -> Set[str]:
         try:
             with open(self.doc_path, encoding="utf-8") as f:
-                text = f.read()
+                return set(_ENV_DOC_RE.findall(f.read()))
         except OSError:
-            return set(), []
-        tokens = set(_ENV_DOC_RE.findall(text))
-        exact = {t for t in tokens if not t.endswith("*")}
-        # wildcard rows (`MXT_BENCH_*`) document a family — but a bare
-        # brand prefix (the prose says "the MXNET_* knobs") documents
-        # nothing and must not become a catch-all
-        prefixes = [t[:-1] for t in tokens
-                    if t.endswith("*") and t[:-1] not in ("MXNET_", "MXT_")]
-        return exact, prefixes
+            return set()
 
     def finalize(self) -> List[Finding]:
-        exact, prefixes = self._doc_tokens()
+        documented = self._doc_tokens()
         out: List[Finding] = []
         read_names: Set[str] = set()
         doc_rel = os.path.relpath(self.doc_path, REPO_ROOT) \
@@ -589,9 +579,7 @@ class EnvVarSyncChecker:
         reported: Set[str] = set()
         for name, ctx, node in self._reads:
             read_names.add(name)
-            if name in exact or any(name.startswith(p) for p in prefixes):
-                continue
-            if name in reported:
+            if name in documented or name in reported:
                 continue   # one finding per variable, at its first read
             reported.add(name)
             out.append(ctx.finding(
@@ -599,7 +587,7 @@ class EnvVarSyncChecker:
                 f"env var '{name}' is read here but not documented in "
                 f"{doc_rel} — add a row (name, default, meaning)"))
         # docs -> code: documented vars nobody reads anywhere
-        undocumented_side = exact - read_names - self._indirect
+        undocumented_side = documented - read_names - self._indirect
         if undocumented_side:
             extra_text = self._extra_corpus()
             for name in sorted(undocumented_side):
@@ -1363,105 +1351,6 @@ class GateHygieneChecker:
 
 
 # ---------------------------------------------------------------------------
-# 10. bench-emit (ISSUE 15 satellite)
-# ---------------------------------------------------------------------------
-class BenchEmitChecker:
-    """Every bench.py rider's result dict must be reachable from
-    ``_emit``'s BENCH JSON — the exact omission fixed twice already
-    (PR 12: the wholestep rider ran but never reached the artifact;
-    PR 14: same for the mfu rider).  A rider that runs and reports
-    nothing is worse than one that fails: the scoring artifact silently
-    loses the axis.
-
-    Checks any scanned ``bench*.py``, and — via ``finalize`` — always
-    the repo's own ``bench.py`` even when the sweep paths don't include
-    it: every string key K with a ``_STATE[K] = ...`` assignment must
-    be READ (``_STATE[K]`` / ``_STATE.get(K)``) inside ``_emit``.
-    """
-
-    name = "bench-emit"
-
-    def __init__(self):
-        self._saw_repo_bench = False
-
-    def check_file(self, ctx: FileCtx) -> List[Finding]:
-        base = os.path.basename(ctx.relpath)
-        if not (base.startswith("bench") and base.endswith(".py")):
-            return []
-        if ctx.relpath == "bench.py":
-            self._saw_repo_bench = True
-        return self._check_tree(ctx)
-
-    def _check_tree(self, ctx: FileCtx) -> List[Finding]:
-        emit_fn = None
-        for node in ast.walk(ctx.tree):
-            if isinstance(node, ast.FunctionDef) and node.name == "_emit":
-                emit_fn = node
-                break
-        if emit_fn is None:
-            return []
-
-        def state_key(node) -> Optional[str]:
-            # _STATE["k"] subscript
-            if isinstance(node, ast.Subscript) and \
-                    isinstance(node.value, ast.Name) and \
-                    node.value.id == "_STATE":
-                sl = node.slice
-                if isinstance(sl, ast.Index):  # py<3.9 compat shape
-                    sl = sl.value
-                return _const_str(sl)
-            return None
-
-        emitted: Set[str] = set()
-        for node in ast.walk(emit_fn):
-            k = state_key(node)
-            if k:
-                emitted.add(k)
-            if isinstance(node, ast.Call) and \
-                    _call_name(node.func) == "_STATE.get" and node.args:
-                k = _const_str(node.args[0])
-                if k:
-                    emitted.add(k)
-        out: List[Finding] = []
-        seen: Set[str] = set()
-        for node in ast.walk(ctx.tree):
-            targets = []
-            if isinstance(node, ast.Assign):
-                targets = node.targets
-            elif isinstance(node, ast.AugAssign):
-                targets = [node.target]
-            for t in targets:
-                k = state_key(t)
-                if k and k not in emitted and k not in seen:
-                    seen.add(k)
-                    out.append(ctx.finding(
-                        self.name, t,
-                        f"rider result _STATE[{k!r}] is assigned but "
-                        f"never read inside _emit — it will not reach "
-                        f"the BENCH JSON artifact (the PR 12/PR 14 "
-                        f"omission class).  Add an `out[{k!r}] = "
-                        f"_STATE[{k!r}]` leg to _emit"))
-        return out
-
-    def finalize(self) -> List[Finding]:
-        if self._saw_repo_bench:
-            return []
-        path = os.path.join(REPO_ROOT, "bench.py")
-        try:
-            with open(path, encoding="utf-8") as f:
-                source = f.read()
-            tree = ast.parse(source, filename=path)
-        except (OSError, SyntaxError):
-            return []
-        ctx = FileCtx(path, "bench.py", source, tree)
-        out = []
-        for f in self._check_tree(ctx):
-            if not ctx.suppressed(self.name, f.line):
-                out.append(f)
-        return out
-
-
-# ---------------------------------------------------------------------------
 def registry() -> Dict[str, type]:
     return {
         ThreadSafetyChecker.name: ThreadSafetyChecker,
@@ -1473,7 +1362,6 @@ def registry() -> Dict[str, type]:
         UseAfterDonateChecker.name: UseAfterDonateChecker,
         RetraceHazardChecker.name: RetraceHazardChecker,
         GateHygieneChecker.name: GateHygieneChecker,
-        BenchEmitChecker.name: BenchEmitChecker,
     }
 
 

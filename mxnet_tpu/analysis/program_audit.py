@@ -384,10 +384,9 @@ def audit_programs(programs: Optional[Dict[str, dict]] = None,
 # -- the CLI self-audit workload ----------------------------------------------
 def self_audit(steps: int = 2, amp: Optional[str] = None) -> dict:
     """Build a tiny whole-step training program WITH HLO capture and
-    audit it — the ``--audit-programs`` CLI leg (and the bench lint
-    rider's audit half).  Runs entirely in-process on whatever backend
-    ``jax`` resolves (the Makefile pins cpu); restores every knob it
-    touches.  Returns the ``audit_programs(strict=True)`` report plus
+    audit it — the ``--audit-programs`` CLI leg.  Runs entirely
+    in-process on whatever backend ``jax`` resolves (the Makefile pins
+    cpu); restores every knob it touches.  Returns the ``audit_programs(strict=True)`` report plus
     ``{"programs": [names audited]}``."""
     import os
     import numpy as _np
@@ -405,7 +404,7 @@ def self_audit(steps: int = 2, amp: Optional[str] = None) -> dict:
     enabled_prev = _introspect.ENABLED
     # the probe notes its program under the canonical "whole_step" name
     # — snapshot the registry so a host process's own captured programs
-    # (bench riders, a live trainer) come back untouched
+    # (a live trainer's) come back untouched
     with _introspect._lock:
         saved_programs = {k: dict(v)
                           for k, v in _introspect._programs.items()}

@@ -26,9 +26,8 @@ any device→host synchronization the package performs
 rule, used by the dispatch-count and chaos tests.
 
 Overhead discipline (the repo rule set by the metrics layer): with the
-sanitizer off — the default; ``bench.py`` asserts it — the factories
-return PLAIN ``threading`` primitives, so production hot paths pay
-zero wrapper overhead.  Enable before constructing the objects under
+sanitizer off (the default) the factories return PLAIN ``threading``
+primitives, so production hot paths pay zero wrapper overhead.  Enable before constructing the objects under
 test (``MXNET_SANITIZE=1`` at import covers the whole process).
 
 Results surface through the metrics registry:

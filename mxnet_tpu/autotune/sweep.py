@@ -1,11 +1,8 @@
 """Measured-sweep tuner: short paired-interleave probes pick the knobs.
 
-PR 13's bench methodology — alternate the two legs pair-by-pair,
-median the adjacent-pair deltas, take the best third-sized chunk so a
-noisy-neighbor burst on a shared container cannot fake a regression —
-packaged as a LIBRARY (the bench riders and this tuner share the same
-statistic, so a tuned decision and a bench verdict can never disagree
-on methodology).
+The statistic: alternate the two legs pair-by-pair, median the
+adjacent-pair deltas, take the best third-sized chunk so a
+noisy-neighbor burst on a shared container cannot fake a regression.
 
 ``tune()`` is the entry point: it sweeps superstep K (against the HBM
 ledger's headroom — staging K batches asks ``ensure_headroom`` first),

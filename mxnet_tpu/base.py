@@ -155,8 +155,9 @@ def enable_compile_cache(default_to_checkout: bool = False) -> Optional[str]:
     The library calls this lazily at executor / serving construction and
     acts only when ``JAX_COMPILATION_CACHE_DIR`` is set (jax reads that
     variable itself; this adds the thresholds).  Entry-point scripts
-    (chip_smoke.py, bench.py) pass ``default_to_checkout=True`` so that a
-    second run in one checkout loads executables instead of recompiling.
+    (chip_smoke.py, chipbench/run.py) pass ``default_to_checkout=True`` so
+    that a second run in one checkout loads executables instead of
+    recompiling.
     No other path is ever handed to ``jax_compilation_cache_dir``."""
     if not (default_to_checkout
             or os.environ.get("JAX_COMPILATION_CACHE_DIR")):

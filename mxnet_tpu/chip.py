@@ -1,11 +1,17 @@
-"""Chip identification and MFU accounting.
+"""Chip identification: the package's table of published peaks.
 
-The one peaks table of the repo, keyed by the exact PJRT `device_kind`
-string (`jax.devices()[0].device_kind`), each row with its source.  A
-device that is not in the table is an error, not a default: an MFU
-computed against a guessed peak is worse than no MFU.  Add a row (with
-its source) when the system is brought up on another chip.
-`observability/introspect.peak_flops` reads this table too.
+Keyed by the exact PJRT `device_kind` string
+(`jax.devices()[0].device_kind`), each row with its source.  A device
+that is not in the table is an error, not a default: a utilisation
+computed against a guessed peak is worse than none.  Add a row (with its
+source) when the system is brought up on another chip.  Its one reader is
+`observability/introspect.peak_flops`.
+
+`chipbench/peaks.json` is the benchmark's own copy of the same row: the
+benchmark may not import what it measures, or a change to this file
+would move the yardstick.  The FLOPs a model needs are stated once, in
+its configuration's `chipbench/configs/<name>/flops.py`; nothing here
+counts them.
 """
 from __future__ import annotations
 
@@ -27,11 +33,6 @@ PEAKS = {
                          'Google Cloud documentation, "TPU v5e"'),
 }
 
-# Model FLOPs per trained image, ResNet-50 v1 @ 224^2: 4.1 GMAC forward
-# = 8.2 GFLOP; backward ~= 2x forward; 24.6 GFLOP/img for fwd+bwd.
-RESNET50_TRAIN_FLOPS_PER_IMG = 24.6e9
-RESNET50_INFER_FLOPS_PER_IMG = 8.2e9
-
 
 def device_kind() -> str:
     """`device_kind` of device 0, as JAX reports it."""
@@ -50,13 +51,3 @@ def peaks(kind: Optional[str] = None) -> Peaks:
             f"no published peaks for device_kind {k!r} in "
             f"mxnet_tpu/chip.py (known: {sorted(PEAKS)}); add a row "
             "with its source rather than guessing") from None
-
-
-def mfu(img_per_s: float, flops_per_img: float = RESNET50_TRAIN_FLOPS_PER_IMG,
-        kind: Optional[str] = None) -> dict:
-    """{"chip", "peak_bf16_tflops", "mfu"} for a measured throughput on
-    a chip of `kind` (default: device 0).  Raises on an unknown kind."""
-    k = device_kind() if kind is None else kind
-    peak = peaks(k).bf16_flops
-    return {"chip": k, "peak_bf16_tflops": peak / 1e12,
-            "mfu": round(img_per_s * flops_per_img / peak, 4)}

@@ -69,7 +69,7 @@ class ShardedEmbedding(Embedding):
                 "even after arbitration — shrink the table, raise "
                 "MXNET_HBM_BUDGET_MB, or shard across a larger mesh axis")
 
-    # -- introspection helpers (smoke gate / bench rider) -------------------
+    # -- introspection helpers (smoke gate) ----------------------------------
     def partition_plan(self, mesh=None) -> dict:
         """Static description of the committed layout: shard count, the
         axis, rows per shard, and the wire economics a dense gradient

@@ -1,8 +1,10 @@
 """ResNet v1/v2 (parity: python/mxnet/gluon/model_zoo/vision/resnet.py).
 
 The BASELINE.json north-star model: resnet50_v1 hybridized is the flagship
-training workload (bench.py).  18/34/50/101/152 in both v1 (post-act) and
-v2 (pre-act) variants, matching the reference's layer configs.
+training workload (cell ``resnet50_train_module`` of BENCHMARK.json runs
+it as a symbol through Module.fit).  18/34/50/101/152 in both v1
+(post-act) and v2 (pre-act) variants, matching the reference's layer
+configs.
 """
 from __future__ import annotations
 

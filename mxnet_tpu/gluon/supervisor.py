@@ -48,8 +48,7 @@ Overhead contract (the METRICS_ENABLED discipline):
 ``MXNET_SUPERVISE=0`` reduces ``step()`` to ONE module-global boolean
 test and a direct call.  Enabled, a steady-state step costs one
 worker-thread handoff, one EWMA update, and (every
-``MXNET_SUPERVISE_CHECK_EVERY`` steps) one host read of the loss; the
-bench ``chaos`` rider pins the total at ≤2% steps/s.
+``MXNET_SUPERVISE_CHECK_EVERY`` steps) one host read of the loss.
 
 ::
 

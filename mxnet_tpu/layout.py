@@ -15,8 +15,8 @@ Adjacent boundary transposes cancel in XLA's algebraic simplifier
 ops), so a conv→BN→relu→conv chain stays channels-last end to end; only
 the graph's true entry/exit pay a real data movement.
 
-Default off (NCHW) until an on-chip A/B (experiments/layout_probe.py;
-ROADMAP S2) records a win; select with
+Default off (NCHW): no cell of BENCHMARK.json has measured the NHWC
+pass on the chip yet (ROADMAP.md S2 names it as a lever); select with
 ``mxnet_tpu.layout.set_conv_layout("NHWC")`` or
 ``MXNET_TPU_CONV_LAYOUT=NHWC``.  Flip the flag BEFORE building
 executors/CachedOps — compiled plans trace the flag at build time.

@@ -508,14 +508,13 @@ def _grad_wanted(a):
 
 
 def _dot_use_nnz(nnz, m, k, n, itemsize):
-    """Path choice for csr·dense (measured,
-    benchmark/python/sparse/sparse_bench.py): the nnz path builds an
-    (nnz, N) gather intermediate; the dense path materializes the (M, K)
-    lhs and rides the MXU, which wins by ~100x at 10% density.  Take nnz
-    only when its intermediate is smaller than the dense form
+    """Path choice for csr·dense: the nnz path builds an (nnz, N) gather
+    intermediate; the dense path materializes the (M, K) lhs and rides
+    the MXU (not measured on the chip; no cell has sparse traffic).  Take
+    nnz only when its intermediate is smaller than the dense form
     (true-sparse regime — e.g. libsvm features with N=1..small) or when
     densifying is infeasible at this dtype.  MXNET_SPARSE_DOT=nnz|dense
-    overrides (tests pin storage behavior; the benchmark A/Bs both)."""
+    overrides (tests pin storage behavior)."""
     mode = os.environ.get("MXNET_SPARSE_DOT", "auto")
     if mode in ("nnz", "dense"):
         return mode == "nnz"

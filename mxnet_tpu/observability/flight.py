@@ -37,8 +37,7 @@ Overhead contract (the ``MXNET_METRICS_ENABLED`` discipline):
 ``MXNET_FLIGHT=0`` takes the ring out of every hook — no timestamps, no
 tuple, no ring write; what stays is the span's profiler annotation
 (``tracing.span``).  Enabled, a span costs two ``perf_counter`` reads
-and one list-slot store; the bench ``flight`` rider pins the
-fused-trainer overhead at ≤2% steps/s.
+and one list-slot store.
 """
 from __future__ import annotations
 

@@ -137,8 +137,8 @@ def _touch_clock_locked(now: float) -> None:
 
 def start() -> None:
     """Pin the run-clock origin to *now* (callers that want wall-clock
-    accounting from a known point — the chaos test, bench rider, or a
-    training driver's first step).  Without it the clock starts at the
+    accounting from a known point — the chaos test or a training
+    driver's first step).  Without it the clock starts at the
     first attributed span."""
     if not ENABLED:
         return

@@ -119,7 +119,7 @@ FUSED_STEP_PROGRAMS = ("gluon:fwd", "gluon:bwd", "fused_update")
 #: "mx.trainer.step" span times Trainer.step alone (allreduce+update; the
 #: user's fwd/bwd run outside it), so dividing full-step flops by it
 #: would overstate MFU severalfold — fused-path MFU needs an explicit
-#: step_time_s (the bench mfu rider measures its own).
+#: step_time_s.
 #: "superstep" qualifies too: its span covers K whole steps and its
 #: noted program's cost_analysis flops are K x one step, so the
 #: flops/time quotient stays a true device rate.
@@ -571,9 +571,8 @@ def mfu(step_time_s: Optional[float] = None, flops: Optional[float] = None,
         bytes_per_step: Optional[float] = None,
         peak: Optional[float] = None) -> dict:
     """MFU + roofline telemetry: analytical flops/step ÷ measured step
-    time ÷ platform peak.  Every input is overridable (the bench rider
-    passes its own measured step time); defaults come from the noted
-    programs + the flight recorder's warmed EWMA.  Returns ``{}`` when
+    time ÷ platform peak.  Every input is overridable; defaults come
+    from the noted programs + the flight recorder's warmed EWMA.  Returns ``{}`` when
     either the flops or the step time is not yet measurable; where the
     platform has no peak (CPU) the ``mfu``/``peak_flops`` keys are
     absent and the achieved rates remain."""

@@ -37,8 +37,7 @@ not a debugging afterthought:
 Overhead contract (the ``MXNET_METRICS_ENABLED`` discipline):
 ``MXNET_MEMORY_LEDGER=0`` reduces every hook to ONE module-global
 boolean test — no weakref, no dict write, no tag lookup.  Enabled, a
-registration costs one weakref + one counter update; the bench
-``memory`` rider pins fused-trainer overhead at ≤2% steps/s.
+registration costs one weakref + one counter update.
 
 Accuracy notes: live bytes are computed from shape/dtype metadata
 (never a device sync); wrappers sharing one device buffer (views,

@@ -597,7 +597,7 @@ DECODE_TOKENS_PER_S = Gauge(
     "Instantaneous decode throughput: active sequences advanced by the "
     "most recent step / its wall-clock (continuous batching's win over "
     "request-level coalescing is exactly this gauge under mixed-length "
-    "traffic — the bench.py decode rider pins it)")
+    "traffic)")
 FAULTS_INJECTED = Counter(
     "mxnet_faults_injected_total",
     "Faults fired by the mxnet_tpu.faultinject harness, by site and "

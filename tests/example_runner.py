@@ -44,3 +44,18 @@ def notebook_script(rel, dest):
     dest.write_text("\n\n".join("".join(c["source"]) for c in nb["cells"]
                                 if c["cell_type"] == "code"))
     return str(dest)
+
+
+def pack_rec(path, n, size):
+    """Write ``n`` JPEG records of ``size`` x ``size`` noise, labelled by
+    their index, to the .rec file at ``path``."""
+    import numpy as np
+    from mxnet_tpu import recordio
+    img = (np.random.RandomState(0).rand(size, size, 3) * 255
+           ).astype(np.uint8)
+    w = recordio.MXRecordIO(path, "w")
+    for i in range(n):
+        header = recordio.IRHeader(0, float(i), i, 0)
+        w.write(recordio.pack_img(header, np.roll(img, i, axis=0),
+                                  quality=85, img_fmt=".jpg"))
+    w.close()

@@ -6,8 +6,8 @@ Three layers:
      FIRES, and every suppression form (inline comment, baseline)
      works;
   2. the tier-1 gate — the full mxnet_tpu/ sweep must report ZERO
-     non-baselined findings (the `make lint-graft` twin), inside the
-     30s budget the bench rider also guards;
+     non-baselined findings (the `make lint-graft` twin), inside a
+     30s budget;
   3. the sanitizer — lock-order cycles and non-reentrant re-entry are
      detected typed, no_sync regions raise on device→host syncs, and
      the real PR 5-class hazard (SIGTERM emergency save re-entering
@@ -34,8 +34,8 @@ ALL_RULES = analysis.ALL_RULES
 
 # -- helpers -----------------------------------------------------------------
 
-def _lint(tmp_path, source, rules=None, baseline=None, name="snippet.py"):
-    p = tmp_path / name
+def _lint(tmp_path, source, rules=None, baseline=None):
+    p = tmp_path / "snippet.py"
     p.write_text(textwrap.dedent(source))
     return analysis.run(rules, [str(p)], baseline)
 
@@ -289,23 +289,6 @@ GOOD_GATE = """
             return x
         return compute(x)
 """
-
-# the historical shape both PR 12 (wholestep) and PR 14 (mfu) fixed:
-# the rider ran, stored its result, and _emit never forwarded it
-BAD_BENCH_EMIT = """
-    _STATE = {"phase": "start", "img_s": None}
-
-    def _emit(partial):
-        out = {"value": _STATE["img_s"]}
-        if _STATE.get("lint") is not None:
-            out["lint"] = _STATE["lint"]
-        print(out)
-
-    def _run():
-        _STATE["lint"] = {"ok": True}
-        _STATE["mfu"] = {"mfu_pct": 12.0}   # never emitted
-"""
-
 
 # -- each rule fires on its known-bad fixture --------------------------------
 
@@ -568,37 +551,6 @@ def test_gate_hygiene_module_level_read_is_clean(tmp_path):
     assert _lint(tmp_path, src, ["gate-hygiene"]) == []
 
 
-# -- ISSUE 15: bench-emit -----------------------------------------------------
-
-def test_bench_emit_fires_on_historical_shape(tmp_path):
-    """The exact omission PR 12 (wholestep) and PR 14 (mfu) fixed by
-    hand, reconstructed: the rider stores its result, _emit never
-    forwards it."""
-    got = _lint(tmp_path, BAD_BENCH_EMIT, ["bench-emit"],
-                name="bench_fixture.py")
-    assert len(got) == 1, got
-    assert "'mfu'" in got[0].message and "_emit" in got[0].message
-
-
-def test_bench_emit_clean_when_forwarded(tmp_path):
-    fixed = BAD_BENCH_EMIT.replace(
-        '        if _STATE.get("lint") is not None:',
-        '        if _STATE.get("mfu") is not None:\n'
-        '            out["mfu"] = _STATE["mfu"]\n'
-        '        if _STATE.get("lint") is not None:')
-    assert _lint(tmp_path, fixed, ["bench-emit"],
-                 name="bench_fixture.py") == []
-
-
-def test_bench_emit_covers_repo_bench_py():
-    """The finalize leg audits the REAL bench.py even when the sweep
-    paths don't include it — every _STATE rider key must reach _emit
-    (this is what caught the probe_attempts omission this PR fixed)."""
-    got = analysis.run(["bench-emit"],
-                       [os.path.join(REPO_ROOT, "mxnet_tpu")], None)
-    assert got == [], got
-
-
 def test_new_rule_inline_suppression(tmp_path):
     """Both suppression styles work on the new tier too."""
     src = BAD_USE_AFTER_DONATE.replace(
@@ -679,8 +631,8 @@ def test_checked_in_baseline_policy():
 @pytest.mark.analysis
 def test_full_codebase_sweep_clean_and_fast():
     """`make lint-graft` in-process: zero non-baselined findings over
-    mxnet_tpu/ at HEAD, inside the 30s budget (bench.py re-checks the
-    budget so the gate can't silently outgrow tier-1)."""
+    mxnet_tpu/ at HEAD, inside the 30s budget (so the gate can't
+    silently outgrow tier-1)."""
     t0 = time.perf_counter()
     active, _, _ = run_detailed(None, ["mxnet_tpu"], DEFAULT_BASELINE)
     dt = time.perf_counter() - t0
@@ -702,14 +654,10 @@ def test_cli_exits_nonzero_on_seeded_violations(tmp_path):
              "memory-hygiene": BAD_MEMORY,
              "use-after-donate": BAD_USE_AFTER_DONATE,
              "retrace-hazard": BAD_RETRACE,
-             "gate-hygiene": BAD_GATE,
-             "bench-emit": BAD_BENCH_EMIT}
+             "gate-hygiene": BAD_GATE}
     assert set(seeds) == set(ALL_RULES)
     for i, (rule, src) in enumerate(seeds.items()):
-        # bench-emit only audits bench-named files
-        fname = f"bench_seed_{i}.py" if rule == "bench-emit" \
-            else f"seed_{i}.py"
-        p = tmp_path / fname
+        p = tmp_path / f"seed_{i}.py"
         p.write_text(textwrap.dedent(src))
         rc = main(["--rules", rule, str(p)])
         assert rc == 1, f"rule {rule} did not gate"

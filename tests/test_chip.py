@@ -1,7 +1,5 @@
-"""mxnet_tpu.chip: the one peaks table (keyed by device_kind, with
-source) and MFU accounting.  An unknown device is an error."""
-import math
-
+"""mxnet_tpu.chip: the package's peaks table (keyed by device_kind, with
+source).  An unknown device is an error."""
 import pytest
 
 from mxnet_tpu import chip
@@ -19,19 +17,10 @@ def test_v5e_row():
 def test_unknown_kind_raises(kind):
     with pytest.raises(MXNetError, match="no published peaks"):
         chip.peaks(kind)
-    with pytest.raises(MXNetError, match="no published peaks"):
-        chip.mfu(1577.63, kind=kind)
-
-
-def test_mfu_known_chip():
-    m = chip.mfu(1577.63, kind="TPU v5 lite")
-    assert m["chip"] == "TPU v5 lite" and m["peak_bf16_tflops"] == 197.0
-    assert math.isclose(m["mfu"], 1577.63 * 24.6e9 / 197e12, rel_tol=1e-3)
-    assert set(m) == {"chip", "peak_bf16_tflops", "mfu"}
 
 
 def test_default_kind_is_device_zero():
     # the tier-1 host is a CPU: the default lookup must raise, not guess
     assert chip.device_kind() == "cpu"
-    with pytest.raises(MXNetError):
-        chip.mfu(100.0)
+    with pytest.raises(MXNetError, match="no published peaks"):
+        chip.peaks()

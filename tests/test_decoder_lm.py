@@ -1,10 +1,10 @@
 """The current decoder blocks (ops/decoder.py, gluon/model_zoo/decoder.py)
 against the plain reference of chipbench/configs/glm-4.7-flash, at the
 rehearsal's sizes, float32, seeded: latent attention, the expert op and its
-shares, the load counters, the flash kernel at head size 256, the new
-cell's rehearsal, and the new per-layer readers."""
+shares, the load counters, the flash kernel at head size 256, the cell's
+rehearsal under a planted fault (the sound one is in
+tests/test_benchmark_cells.py), and the cell's per-layer readers."""
 import gc
-import json
 import os
 import weakref
 
@@ -22,6 +22,8 @@ from mxnet_tpu.ops.flash_attention import _dense_reference
 
 from chipbench import cell as cellmod
 from chipbench import run
+
+from test_benchmark_cells import float32_traffic  # noqa: F401  (fixture)
 
 CDIR = os.path.join(cellmod.HERE, "configs", "glm-4.7-flash")
 REF = cellmod.load_module(os.path.join(CDIR, "reference.py"),
@@ -342,31 +344,6 @@ def test_decoder_lm_has_no_decode_path_yet():
 
 
 # -- the cell's rehearsal ----------------------------------------------------
-@pytest.fixture
-def float32_traffic(monkeypatch):
-    """As chipbench/tests/test_cells_cpu.py: at the tiny size the cell's
-    limits, read at full size on the chip, hold for float32 only."""
-    real = cellmod.load_json
-
-    def load(path):
-        out = real(path)
-        if os.path.basename(os.path.dirname(path)) == "traffic":
-            out["dtype"] = "float32"
-        return out
-
-    monkeypatch.setattr(cellmod, "load_json", load)
-
-
-def test_cell_rehearsal_reads_correct(float32_traffic):
-    res = run.run_cell(CELL, 7, 0.5, False, rehearsal=True)
-    json.dumps(res)
-    assert res["attempted"] > 0 and res["failed"] == 0
-    for num, rec in res["compared"].items():
-        tol = 1e-3 if num.startswith("dparam_norm_gap") else 1e-4
-        assert rec["value"] < tol, (num, res["compared"])
-    assert res["correct"] is True, res["compared"]
-
-
 def test_cell_rehearsal_planted_fault_reads_not_correct(float32_traffic,
                                                         monkeypatch):
     """Half of every batch repeats the other half."""

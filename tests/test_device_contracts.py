@@ -50,9 +50,15 @@ def test_accelerator_context_resolves_and_bounds_checks(monkeypatch):
 
 @pytest.fixture
 def cache_config(monkeypatch):
-    """Record every directory handed to jax's cache option, touch none."""
+    """Record every directory handed to jax's cache option, touch none.
+    `enable_compile_cache` hands a directory over only when jax's option
+    differs from it, and whatever ran before on this worker (a cell's
+    rehearsal calls the entry points' form) may have set the option: it is
+    cleared here and put back afterwards."""
     seen = []
     real = jax.config.update
+    before = jax.config.jax_compilation_cache_dir
+    real("jax_compilation_cache_dir", None)
 
     def update(name, value):
         if name == "jax_compilation_cache_dir":
@@ -64,7 +70,8 @@ def cache_config(monkeypatch):
     monkeypatch.setattr(base._jax.config, "update", update)
     from jax.experimental.compilation_cache import compilation_cache
     monkeypatch.setattr(compilation_cache, "reset_cache", lambda: None)
-    return seen
+    yield seen
+    real("jax_compilation_cache_dir", before)
 
 
 def test_cache_dir_is_the_environments_when_set(monkeypatch, cache_config):

@@ -140,8 +140,8 @@ def test_fit_step_dispatch_budget(counters):
 
 
 def test_full_fit_loop_dispatch_budget(counters):
-    """VERDICT r3 #9: pin the FULL fit() loop — metric update + epoch
-    callback included, the exact bench.py pattern — not just
+    """Pin the FULL fit() loop — metric update + epoch callback
+    included, the pattern of chipbench/entries/module_fit.py — not just
     forward_backward+update.  Budget per batch in a steady epoch:
     0 device_puts, and a fixed handful of compiled-program launches
     (fused fwd+bwd, fused update, the metric's one on-device NLL
@@ -170,13 +170,13 @@ def test_full_fit_loop_dispatch_budget(counters):
                                          "momentum": 0.9,
                                          "multi_precision": True})
 
-    # device-resident data; iterator slices on device (bench.py:103-108)
+    # device-resident data; the iterator slices on the device
     x = mx.nd.array(rs.normal(0, 1, (batch * nbatch, 3, 8, 8)).astype("f"))
     y = mx.nd.array(rs.randint(0, 10, batch * nbatch).astype("f"))
     it = NDArrayIter(x, y, batch_size=batch)
 
     class LossMetric(mx.metric.EvalMetric):
-        """bench.py LossMetric: ONE jitted on-device NLL per batch, no
+        """ONE jitted on-device NLL per batch, no
         host fetch inside the timed loop."""
 
         def __init__(self):

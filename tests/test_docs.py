@@ -43,3 +43,61 @@ def test_doc_blocks_execute(path):
             raise AssertionError(
                 f"{os.path.relpath(path, REPO)} block {i} failed: "
                 f"{type(e).__name__}: {e}\n--- block ---\n{src}") from e
+
+
+# -- every file a document names is there ------------------------------------
+PATH_DOCS = sorted(
+    [os.path.join(REPO, "README.md"),
+     os.path.join(REPO, ".claude", "skills", "verify", "SKILL.md")]
+    + glob.glob(os.path.join(REPO, "docs", "**", "*.md"), recursive=True))
+SPAN_RE = re.compile(r"`([^`\n]+)`")
+PATH_RE = re.compile(
+    r"(?<![\w./<>{*:-])((?:[\w.-]+/)*[\w.-]+\.(?:py|md|json))(?![\w/])")
+# a paragraph that opens so cites the reference project's tree, not this one
+PARITY_RE = re.compile(r"^Parity (?:target|role):.*?(?:\n\s*\n|\Z)",
+                       re.S | re.M)
+
+
+@pytest.fixture(scope="module")
+def tree_names():
+    names = set()
+    for _d, dirs, files in os.walk(REPO):
+        # not what git ignores: a parent checkout unpacked under .scratch/
+        # would lend its file names to the tree
+        dirs[:] = [d for d in dirs if not d.startswith(".")
+                   and d not in ("__pycache__", "chiprun_out")]
+        names.update(files)
+    return names
+
+
+def _missing_paths(path, names):
+    """Backticked repo-relative paths to .py / .md / .json files that the
+    document at ``path`` names and the tree does not hold.  A path counts
+    as repo-relative when its first component is at the root of the
+    checkout or of the package (``gluon/trainer.py``), or beside the
+    document; a bare file name has to be some file's name in the tree.
+    Placeholders (``<cell>``, ``*``, ``{a,b}``), absolute paths and
+    ``commit:path`` of a ``git show`` are not paths of this tree."""
+    text = PARITY_RE.sub("", open(path).read())
+    bases = (REPO, os.path.join(REPO, "mxnet_tpu"), os.path.dirname(path))
+    missing = set()
+    for span in SPAN_RE.findall(text):
+        for tok in PATH_RE.findall(span):
+            if "/" not in tok:
+                found = tok in names
+            else:
+                first = tok.split("/", 1)[0]
+                if not any(os.path.exists(os.path.join(b, first))
+                           for b in bases):
+                    continue
+                found = any(os.path.exists(os.path.join(b, tok))
+                            for b in bases)
+            if not found:
+                missing.add(tok)
+    return sorted(missing)
+
+
+@pytest.mark.parametrize(
+    "path", PATH_DOCS, ids=[os.path.relpath(p, REPO) for p in PATH_DOCS])
+def test_doc_names_only_files_that_exist(path, tree_names):
+    assert _missing_paths(path, tree_names) == []

@@ -1,66 +1,11 @@
-"""Smokes of the measuring harnesses on the CPU: bench.py's rehearsal in both
-layouts, tools/bench_io.py, tools/bandwidth, benchmark/ and the
-experiments/ probes.  They prove the harness runs; none is a measurement."""
+"""Smokes of the reference's two benchmark examples on the CPU
+(example/image-classification/benchmark.py and benchmark_score.py).  They
+prove the scripts run; none is a measurement.  The benchmark the repo is
+judged by is BENCHMARK.json + chipbench/, whose cells are rehearsed in
+tests/test_benchmark_cells.py."""
 import json
-import os
-import sys
 
-import numpy as np
-import pytest
-
-from example_runner import REPO, run_example, run_python
-
-
-@pytest.mark.parametrize("layout", ["NCHW", "NHWC"])
-def test_bench_product_path_smoke(layout):
-    """bench.py drives Module.fit + tpu_sync kvstore + fused updates; the
-    explicit CPU rehearsal checks the whole path wires up (both internal
-    layouts) and the loss-sanity assert passes.  Every record of a
-    rehearsal says so and names the device it ran on."""
-    proc = run_python(
-        ["bench.py", "--rehearsal"], cwd=REPO,
-        env={"MXT_BENCH_BATCH": "8", "MXT_BENCH_IMG": "64",
-             "MXT_BENCH_BATCHES": "2", "MXT_BENCH_LR": "0.01",
-             "MXNET_TPU_CONV_LAYOUT": layout})
-    rec = json.loads(proc.stdout.splitlines()[-1])
-    assert rec["metric"] == "resnet50_train_throughput"
-    assert rec["value"] > 0
-    assert rec["rehearsal"] is True and rec["platform"] == "cpu"
-    assert rec["device_kind"] and rec["device_count"] >= 1
-    assert "chip_mfu" not in rec  # a CPU has no peak to divide by
-    assert "failed" not in rec and "error" not in rec, rec
-
-
-def test_bench_refuses_cpu_without_rehearsal():
-    """bench.py cannot run on the CPU by accident: with no TPU it prints
-    its JSON line (device stamped, error named) and exits non-zero."""
-    proc = run_python(["bench.py"], cwd=REPO, rc=1)
-    rec = json.loads(proc.stdout.splitlines()[-1])
-    assert rec["platform"] == "cpu" and rec["value"] == 0.0
-    assert "--rehearsal" in rec["error"] and rec["phase"] == "device"
-
-
-def test_bench_io_harness():
-    """Standalone input-pipeline benchmark (parallel decode pool)."""
-    out = run_example("tools/bench_io.py", "--num-images", "64",
-                      "--batch-size", "16", "--image-size", "64",
-                      "--threads", "4", "--epochs", "1")
-    assert "decode+augment throughput" in out
-
-
-def test_bandwidth_harness():
-    sys.path.insert(0, os.path.join(REPO, "tools", "bandwidth"))
-    import importlib
-    measure = importlib.import_module("measure")
-    gbps = measure.run("local", size_mb=1, num_keys=2, repeats=2)
-    assert gbps > 0
-
-
-def test_sparse_benchmark_harness():
-    out = run_example("benchmark/python/sparse/sparse_bench.py",
-                      "--quick")
-    assert "sparse bench done" in out
-    assert "grad stype=row_sparse" in out  # rows-only path exercised
+from example_runner import REPO, run_example
 
 
 def test_benchmark_sweep_driver(tmp_path):
@@ -81,35 +26,8 @@ def test_benchmark_sweep_driver(tmp_path):
     assert rec["rc"] == 0 and rec["img_s"] > 0, rec
 
 
-def test_lm_mfu_probe_smoke():
-    """experiments/lm_mfu_probe.py (transformer-LM MFU window leg):
-    smoke config must train (finite decreasing-ish loss) and emit one
-    JSON line with the tok/s + FLOPs accounting fields."""
-    proc = run_python(["experiments/lm_mfu_probe.py"], cwd=REPO,
-                      env={"MXT_LM_PROBE_SMOKE": "1"})
-    rec = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rec["metric"] == "transformer_lm_train_throughput"
-    assert rec["value"] > 0 and rec["train_tflops_per_step"] >= 0
-    assert np.isfinite(rec["loss_first"]) and np.isfinite(rec["loss_final"])
-    # 2 smoke steps on random tokens: loss must move and not blow up
-    assert rec["loss_final"] < rec["loss_first"] + 1.0
-
-
-def test_decode_probe_smoke():
-    """experiments/decode_probe.py (decode window leg): both decode
-    strategies must run, agree token-for-token, and emit JSON rows."""
-    proc = run_python(["experiments/decode_probe.py"], cwd=REPO,
-                      env={"MXT_DECODE_PROBE_SMOKE": "1"})
-    rows = [json.loads(ln) for ln in proc.stdout.strip().splitlines()
-            if ln.startswith("{")]
-    metrics = {r["metric"]: r for r in rows}
-    assert metrics["decode_static_throughput"]["value"] > 0
-    assert metrics["decode_kv_cache_throughput"]["value"] > 0
-    assert metrics["decode_paths_agree"]["value"] is True
-
-
 def test_benchmark_score_watchdogged(tmp_path):
-    """benchmark_score.py (VERDICT r4 #6): per-cell subprocess watchdogs
+    """benchmark_score.py: per-cell subprocess watchdogs
     + --out durable partials — a per-cell timeout records an error row
     instead of killing the run, and good cells still land."""
     def score(cell_timeout, out):

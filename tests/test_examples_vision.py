@@ -1,11 +1,8 @@
 """Image pipelines end to end: detection, segmentation, style transfer, the
 Kaggle entries and the .rec ImageNet path, one child process each."""
-import os
-import sys
-
 import numpy as np
 
-from example_runner import REPO, run_example
+from example_runner import pack_rec, run_example
 
 
 def test_ssd_example():
@@ -75,11 +72,8 @@ def test_train_imagenet_rec_device_augment(tmp_path):
     """The north-star rec-file path end to end: pack a tiny JPEG .rec,
     train resnet-8 on it with the device-augment input split (the
     default), bf16 data dtype."""
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    import importlib
-    bench_io = importlib.import_module("bench_io")
     rec = str(tmp_path / "tiny.rec")
-    bench_io.pack(rec, 96, 40)
+    pack_rec(rec, 96, 40)
     out = run_example("example/image-classification/train_imagenet.py",
                       "--data-train", rec, "--network", "resnet",
                       "--num-layers", "8", "--num-classes", "10",

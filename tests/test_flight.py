@@ -540,13 +540,20 @@ def test_slow_request_injection_autoproduces_linked_dump(tmp_path,
     timeline in which that request's queue/pad/dispatch/slice spans
     share one trace_id."""
     monkeypatch.setenv("MXNET_FLIGHT_DIR", str(tmp_path))
-    flight.SLOW_FACTOR = 4.0
+    # beside busy workers one warm-up request of a millisecond now and then
+    # takes four times the others: nothing may dump while the base is taken
+    flight.SLOW_FACTOR = float("inf")
     flight.AUTO_DUMP_MIN_S = 0.0
     pred = _mlp_predictor().warmup()
     with serving.MicroBatcher(pred, max_wait_ms=0) as mb:
         for _ in range(8):   # warm the serve_request EWMA
             mb.submit(data=np.zeros((2, 8), np.float32)).result(timeout=10)
         assert not _dumps(tmp_path)
+        # the threshold lies at half the injected delay, in units of the
+        # base as this load has it (never under the default factor)
+        base = flight.watch_ewma("serve_request")
+        assert base is not None and base < 0.125 / 4.0, base
+        flight.SLOW_FACTOR = 0.125 / base
         with fi.active(fi.FaultPlan().add("serving.dispatch", "delay",
                                           delay_s=0.25)):
             mb.submit(data=np.zeros((2, 8), np.float32)).result(timeout=10)

@@ -3,9 +3,9 @@
 User-facing semantics are NCHW either way — these tests pin that the
 channels-last lowering in ops/nn.py (conv/deconv/pool/BN) is numerically
 identical to the channels-first one, forward AND backward, for every
-configuration the model zoo uses.  The on-chip A/B lives in
-experiments/layout_probe.py; here we
-prove the flag can be flipped without changing results.
+configuration the model zoo uses.  No cell has measured NHWC on the chip
+(ROADMAP.md S2); here we prove the flag can be flipped without changing
+results.
 """
 import numpy as np
 import pytest
