@@ -28,6 +28,7 @@ from ..ndarray import NDArray
 from .. import symbol as sym_mod
 from ..symbol import Symbol
 from ..symbol.graph import GraphPlan, infer_shapes_types
+from ..ops.registry import RESIDUAL_NAME
 from .. import autograd
 from .parameter import Parameter, ParameterDict, DeferredInitializationError
 
@@ -233,26 +234,109 @@ class Block:
         raise NotImplementedError
 
 
+# What a recorded call keeps for its backward program is decided by what
+# made a value, nothing else: the outputs of matrix products, convolutions,
+# grouped products and kernel calls stay on the device between the two
+# programs; everything else (norms, activations, casts, masks, the softmax
+# of a loss) is recomputed from them in the backward program, where it is
+# bound by memory traffic.  jax hands the outermost `jax.checkpoint`'s
+# policy down into an op's own (ops/decoder.py moe_ffn), so the grouped
+# products inside it are kept by this rule too, and its gathers and
+# permutations are not.  A kernel whose call the policy meets only as a
+# `custom_vjp` marks its output itself (`ops/registry.py` RESIDUAL_NAME,
+# as ops/flash_attention.py does).
+_KEPT_PRIMITIVES = frozenset((
+    "dot_general", "conv_general_dilated", "ragged_dot",
+    "ragged_dot_general", "pallas_call"))
+_RESIDUAL_POLICY = jax.checkpoint_policies.save_from_both_policies(
+    lambda prim, *_, **__: prim.name in _KEPT_PRIMITIVES,
+    jax.checkpoint_policies.save_only_these_names(RESIDUAL_NAME))
+
+
+class _InputRef:
+    """In the pullback a recording forward program returns, the place of a
+    residual that is one of the program's own inputs (a parameter, the data,
+    the key): the backward program is handed that input again, so the forward
+    program writes no second copy of the weights.  A pytree node without
+    leaves; `index` counts the leaves of `(args, aux, key)`."""
+    __slots__ = ("index",)
+
+    def __init__(self, index):
+        self.index = index
+
+
+jax.tree_util.register_pytree_node(
+    _InputRef, lambda r: ((), r.index), lambda index, _: _InputRef(index))
+
+
+def _is_input_ref(x):
+    return isinstance(x, _InputRef)
+
+
 class CachedOp:
     """Compiled graph closure (parity: Imperative::CachedOp,
     src/imperative/cached_op.cc).
 
-    Both directions are jitted: forward is one XLA executable; the backward
-    stored on the autograd tape is a second executable computing the vjp
-    (forward recomputed inside the compiled program, fused by XLA) — the
-    TPU analog of CachedOp's cached forward/backward graphs
-    (cached_op.cc:179,227).
+    Outside `autograd.record()` a call is one XLA executable that returns
+    the outputs and the new auxiliary states, nothing else.
+
+    Under `autograd.record()` the forward program (`mx_cachedop_fwd`, the
+    same name) differentiates as it runs: it also returns the pullback of
+    the graph, a pytree whose leaves are the residuals `_RESIDUAL_POLICY`
+    keeps.  The tape entry holds them, and `backward` launches a second
+    executable (`mx_cachedop_bwd`) that applies the pullback to the
+    cotangents: it recomputes the element-wise work from the residuals and
+    runs none of the forward graph's products or kernels again.  Residuals
+    that are the program's own inputs (the parameters above all) are not
+    returned: the tape hands the backward program the same arrays.
+
+    The kept residuals live from the forward launch until the tape is
+    cleared (`backward(retain_graph=False)`, the default) and the backward
+    program has run; with `retain_graph=True` they survive for a second
+    `backward`.  A recorded call that no `backward` follows pins them,
+    like its outputs, until the next `backward` clears the tape
+    (`mxnet_cachedop_residual_bytes` says how many bytes that is).  They
+    are not donated: jax gives a donated argument's memory to an output
+    of the same size only, and no residual has a gradient's size.
     """
 
     def __init__(self, symbol: Symbol):
         self.symbol = symbol
         self.plan = plan = GraphPlan(symbol)
 
-        def mx_cachedop_fwd(args, aux, key, is_train):
-            return plan.run(args, aux, key, is_train)
+        def mx_cachedop_fwd(args, aux, key, is_train, recording):
+            if not recording:
+                return plan.run(args, aux, key, is_train)
 
-        self._fwd = jax.jit(mx_cachedop_fwd, static_argnums=(3,))
-        self._bwd_cache = {}
+            def run(args_):
+                outs, new_aux = plan.run(args_, aux, key, is_train)
+                return tuple(outs), new_aux
+
+            (outs, new_aux), pullback = jax.vjp(
+                jax.checkpoint(run, policy=_RESIDUAL_POLICY), args)
+            inputs = {id(x): i for i, x in enumerate(
+                jax.tree_util.tree_leaves((args, aux, key)))}
+            leaves, tree = jax.tree_util.tree_flatten(pullback)
+            nbytes = {"kept": 0, "primal": 0}
+            for j, leaf in enumerate(leaves):
+                ref = inputs.get(id(leaf))
+                nbytes["kept" if ref is None else "primal"] += \
+                    leaf.size * leaf.dtype.itemsize
+                if ref is not None:
+                    leaves[j] = _InputRef(ref)
+            for kind, n in nbytes.items():
+                _metrics.CACHEDOP_RESIDUAL_BYTES.set(n, kind=kind)
+            return outs, new_aux, jax.tree_util.tree_unflatten(tree, leaves)
+
+        def mx_cachedop_bwd(pullback, inputs, cots):
+            inputs = jax.tree_util.tree_leaves(inputs)
+            pullback = jax.tree_util.tree_map(
+                lambda r: inputs[r.index] if _is_input_ref(r) else r,
+                pullback, is_leaf=_is_input_ref)
+            return pullback(cots)[0]
+
+        self._fwd = jax.jit(mx_cachedop_fwd, static_argnums=(3, 4))
+        self._bwd = jax.jit(mx_cachedop_bwd)
         self._fwd_donated = None  # built on first donated inference call
         self._noted = set()  # introspection captures done (fwd/bwd)
 
@@ -287,29 +371,6 @@ class CachedOp:
                 donate_argnums=(0,))
         return self._fwd_donated
 
-    def _run_all(self, names, vals_list, aux_vals, key, is_train):
-        d = dict(zip(names, vals_list))
-        outs, new_aux = self.plan.run(d, aux_vals, key, is_train)
-        return tuple(outs) + tuple(new_aux[k] for k in sorted(new_aux))
-
-    def _get_bwd(self, names):
-        key_ = tuple(names)
-        if key_ not in self._bwd_cache:
-            plan = self.plan
-
-            def mx_cachedop_bwd(primals, cots, aux_vals, key, is_train):
-                def run(*vals):
-                    d = dict(zip(key_, vals))
-                    outs, new_aux = plan.run(d, aux_vals, key, is_train)
-                    return tuple(outs) + tuple(new_aux[k] for k in sorted(new_aux))
-
-                _, vjp_fn = jax.vjp(run, *primals)
-                return vjp_fn(cots)
-
-            self._bwd_cache[key_] = jax.jit(mx_cachedop_bwd,
-                                            static_argnums=(4,))
-        return self._bwd_cache[key_]
-
     def __call__(self, arg_arrays: Dict[str, NDArray],
                  aux_arrays: Dict[str, NDArray], ctx, input_names=None):
         with span("mx.cachedop.forward", cat="cachedop"):
@@ -341,35 +402,41 @@ class CachedOp:
             for k, v in new_aux.items():
                 aux_arrays[k]._set_data(v)
             return out_nds
-        outs, new_aux = self._fwd(arg_vals, aux_vals, key, is_train)
+        recording = autograd.is_recording()
+        outs, new_aux, *pullback = self._fwd(arg_vals, aux_vals, key,
+                                             is_train, recording)
         if _introspect.ENABLED and "fwd" not in self._noted:
             # once per CachedOp: analytical cost of the compiled fwd —
             # the fused-path MFU numerator (a retrace, no XLA compile)
             self._noted.add("fwd")
             _introspect.note_jit("gluon:fwd", self._fwd, arg_vals,
-                                 aux_vals, key, is_train)
+                                 aux_vals, key, is_train, recording)
         out_nds = [NDArray(o, ctx) for o in outs]
-        if autograd.is_recording():
-            names = list(arg_vals.keys())
-            primals = tuple(arg_vals[n] for n in names)
-            bwd_jit = self._get_bwd(names)
-            aux_snapshot = dict(aux_vals)
-            raw_outs = tuple(outs) + tuple(new_aux[k] for k in sorted(new_aux))
+        if recording:
+            names = list(arg_vals)
+            aux_names = sorted(new_aux)
+            n_out = len(outs)
+            # the backward program's three arguments: the residuals the
+            # forward program kept, the inputs it left where they were
+            # (aux_vals: the arrays this call read, not the new states)
+            held = (pullback[0], (arg_vals, aux_vals, key))
 
             def vjp_fn(cots):
+                cots = (tuple(cots[:n_out]),
+                        dict(zip(aux_names, cots[n_out:])))
                 if _metrics.ENABLED:
                     _metrics.XLA_LAUNCHES.inc(kind="bwd")
+                    _metrics.CACHEDOP_BACKWARDS.inc()
                 if _introspect.ENABLED and "bwd" not in self._noted:
                     self._noted.add("bwd")
-                    _introspect.note_jit("gluon:bwd", bwd_jit, primals,
-                                         tuple(cots), aux_snapshot, key,
-                                         is_train)
+                    _introspect.note_jit("gluon:bwd", self._bwd, *held, cots)
                 with span("mx.cachedop.backward", cat="cachedop"):
-                    return bwd_jit(primals, tuple(cots), aux_snapshot, key,
-                                   is_train)
+                    grads = self._bwd(*held, cots)
+                return tuple(grads[n] for n in names)
 
-            autograd._record(None, [arg_arrays[n] for n in names], out_nds,
-                             vjp_fn, raw_outs)
+            autograd._record(
+                None, [arg_arrays[n] for n in names], out_nds, vjp_fn,
+                tuple(outs) + tuple(new_aux[k] for k in aux_names))
         for k, v in new_aux.items():
             aux_arrays[k]._set_data(v)
         return out_nds

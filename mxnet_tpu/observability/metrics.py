@@ -705,6 +705,23 @@ MOE_ROWS = Gauge(
     "product skips the row tiles its group sizes leave empty; tokens x "
     "top_k, the dropless bound, where it multiplies the whole buffer)")
 
+# -- a recorded CachedOp call -------------------------------------------------
+# Set from shapes when gluon/block.py traces a recording forward program, as
+# MOE_ROWS is: nothing runs in the step.  They hold the program traced last.
+CACHEDOP_RESIDUAL_BYTES = Gauge(
+    "mxnet_cachedop_residual_bytes",
+    "Bytes of the residuals a recorded CachedOp call hands its backward "
+    "program, by kind: kept = what the forward program writes for it and "
+    "the tape holds until backward clears it (outputs of matrix products, "
+    "convolutions, grouped products and kernel calls), primal = the "
+    "forward program's own inputs it reuses (parameters, data, key), "
+    "passed again and never copied")
+CACHEDOP_BACKWARDS = Counter(
+    "mxnet_cachedop_backward_total",
+    "Launches of a CachedOp backward program that ran from the forward "
+    "program's residuals (every recorded CachedOp call that backward "
+    "reaches)")
+
 # -- the forward attention kernel ---------------------------------------------
 # Set from shapes when ops/flash_attention.py traces a call, as MOE_ROWS is:
 # nothing runs in the step.  They hold the call traced last.

@@ -203,7 +203,9 @@ def _moe_ffn(p, x, router_w, bias, gate_w, up_w, down_w, load):
     held: a partial result where held < num_experts.  Dropless: the buffer
     has `T * top_k` rows, so every token sent to one held expert still
     equals the reference.  The feed-forward's intermediates are recomputed
-    in the backward pass (`jax.checkpoint`), as jobs that fill the chip do.
+    in the backward pass (`jax.checkpoint`), as jobs that fill the chip do;
+    under a caller's own `jax.checkpoint` (a recorded CachedOp call) the
+    caller's policy decides instead, and keeps the grouped products.
     """
     E, k, first, held = (p["num_experts"], p["top_k"], p["first"],
                          p["held"])

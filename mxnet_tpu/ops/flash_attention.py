@@ -37,10 +37,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from ..base import Arg
-from .registry import register
+from .registry import RESIDUAL_NAME, register
 
 NEG_INF = -1e30
 
@@ -273,6 +274,9 @@ def _flash_attention(q, k, v, scale, causal, blk_q=None, blk_k=None):
 
 def _fa_fwd(q, k, v, scale, causal, blk_q, blk_k):
     o = _flash_attention(q, k, v, scale, causal, blk_q, blk_k)
+    # without the mark a recorded CachedOp call's backward program would run
+    # the kernel again for `o` (registry.RESIDUAL_NAME)
+    o = checkpoint_name(o, RESIDUAL_NAME)
     return o, (q, k, v, o)
 
 

@@ -22,6 +22,13 @@ import numpy as _np
 
 from ..base import Arg, MXNetError, ParamSchema
 
+# An op whose output is dear to make and made where a caller's
+# `jax.checkpoint` policy cannot see the primitive (a kernel call inside a
+# `custom_vjp`) marks it `jax.ad_checkpoint.checkpoint_name(out,
+# RESIDUAL_NAME)`: a recorded CachedOp call then keeps it for its backward
+# program (gluon/block.py _RESIDUAL_POLICY) and does not run the op again.
+RESIDUAL_NAME = "mx_residual"
+
 # name -> Operator
 OP_REGISTRY: Dict[str, "Operator"] = {}
 # alias -> canonical name
