@@ -1,20 +1,34 @@
-"""A current decoder language model (gluon): RMS norm, rotary positions,
-latent attention, gated feed-forwards, and sparse experts of which this
-device holds a share.
+"""A current decoder language model (gluon): RMS norm, gated feed-forwards,
+sparse experts of which this device holds a share, and an attention block
+chosen layer by layer.
 
     x + attn(n1(x));  x + ffn(n2(x))
 
+Attention blocks: `LatentAttention` (queries through a low-rank latent,
+keys and values from one latent a token, one shared rotary key) and
+`GroupedQueryAttention` (fewer key/value heads than query heads, rotary
+positions or none, a sliding window or none).  `DecoderLM(attention=[...])`
+takes a pattern of block factories and repeats it over the layers, so a
+global position-free layer can stand beside windowed rotary ones; without
+it every layer is a `LatentAttention` built from the rank and head
+arguments.
+
 The first `first_k_dense` layers have a dense gated feed-forward, the rest
-an expert layer: a router over ALL `num_experts` (sigmoid scores plus a
-selection bias, `top_k` a token), the stacked matrices of the
-`held_experts` experts from `first_expert` on, and shared experts that
-every token passes.  With `held_experts < num_experts` the block computes
-this device's part of an expert-parallel layer without its exchange: a
-chosen expert that is absent adds nothing (ops/decoder.py).
+an expert layer: a router over ALL `num_experts` (`router="sigmoid"`:
+sigmoid scores plus a selection bias; `"softmax_topk"`: a softmax over the
+chosen logits; `top_k` a token either way), the stacked matrices of the
+`held_experts` experts from `first_expert` on under a SiLU or a ReLU gate,
+and shared experts that every token passes, or none.  With
+`router_reads="attention_input"` the router scores the layer's normalised
+input, the tensor its attention reads, and not the feed-forward's.  With
+`held_experts < num_experts` the block computes this device's part of an
+expert-parallel layer without its exchange: a chosen expert that is absent
+adds nothing (ops/decoder.py).
 
 The whole stack is one HybridBlock, so a step is one CachedOp forward and
 one backward, as `TransformerLM`'s is.  There is no decode path yet
-(ROADMAP R1 / R6: a latent leaf in the decode cache).
+(ROADMAP R1 / R6: a latent leaf, a window's ring and a global layer's
+table in the decode cache).
 """
 from __future__ import annotations
 
@@ -76,6 +90,31 @@ class LatentAttention(HybridBlock):
         return self.proj(F.latent_attention(q, kv, k_rope, **self._attn))
 
 
+class GroupedQueryAttention(HybridBlock):
+    """Causal attention over (B, T, D) with `num_kv_heads` key/value heads
+    for `num_heads` query heads of `head_dim` (query head j reads key/value
+    head j // (num_heads / num_kv_heads)), no biases.  rope: rotary
+    positions over the whole head, or no positions at all.  window: a
+    query sees its own key and the `window - 1` before it; None, every key
+    up to its own.  attn_type: 'dense' | 'flash' (the Pallas kernel)."""
+
+    def __init__(self, dim, num_heads, num_kv_heads, head_dim, rope=True,
+                 window=None, attn_type="dense", rope_base=10000.0, **kw):
+        super().__init__(**kw)
+        self._attn = dict(num_heads=num_heads, num_kv_heads=num_kv_heads,
+                          rope=rope, rope_base=rope_base,
+                          window=window or -1, impl=attn_type)
+        with self.name_scope():
+            self.q = _dense(num_heads * head_dim, dim, "q_")
+            self.k = _dense(num_kv_heads * head_dim, dim, "k_")
+            self.v = _dense(num_kv_heads * head_dim, dim, "v_")
+            self.proj = _dense(dim, num_heads * head_dim, "proj_")
+
+    def hybrid_forward(self, F, x):
+        return self.proj(F.grouped_query_attention(
+            self.q(x), self.k(x), self.v(x), **self._attn))
+
+
 class GatedFeedForward(HybridBlock):
     """down(silu(gate(x)) * up(x)), no biases."""
 
@@ -100,16 +139,19 @@ class MoEFeedForward(HybridBlock):
     bfloat16 counter stops counting at 256): `select_bias` (num_experts,),
     added to the scores for the selection only, and `load` (held + 1,), to
     which the forward pass adds the assignments of each held expert and,
-    last, of absent ones (read by `observability.metrics.refresh_moe`)."""
+    last, of absent ones (read by `observability.metrics.refresh_moe`).
+    router, activation: as `moe_ffn` has them.  Called with a second
+    input, the router scores that and the experts read the first."""
 
     def __init__(self, dim, ffn_dim, num_experts, top_k, held_experts=None,
                  first_expert=0, shared_experts=1, routed_scale=1.0,
-                 norm_topk=True, **kw):
+                 norm_topk=True, router="sigmoid", activation="silu", **kw):
         super().__init__(**kw)
         held = num_experts if held_experts is None else held_experts
         self._moe = dict(num_experts=num_experts, top_k=top_k,
                          first=first_expert, held=held, scale=routed_scale,
-                         norm_topk=norm_topk)
+                         norm_topk=norm_topk, router=router,
+                         activation=activation)
         with self.name_scope():
             self.router_weight = self.params.get(
                 "router_weight", shape=(num_experts, dim))
@@ -135,16 +177,27 @@ class MoEFeedForward(HybridBlock):
         self.select_bias.cast("float32")
         self.load.cast("float32")
 
-    def hybrid_forward(self, F, x, router_weight, select_bias, gate_weight,
-                       up_weight, down_weight, load):
-        y = F.moe_ffn(x, router_weight, select_bias, gate_weight, up_weight,
-                      down_weight, load, **self._moe)
+    def hybrid_forward(self, F, x, routed_by=None, router_weight=None,
+                       select_bias=None, gate_weight=None, up_weight=None,
+                       down_weight=None, load=None):
+        rest = (router_weight, select_bias, gate_weight, up_weight,
+                down_weight, load)
+        y = F.moe_ffn(x, *rest, **self._moe) if routed_by is None else \
+            F.moe_ffn_routed_by(x, routed_by, *rest, **self._moe)
         return y if self.shared is None else y + self.shared(x)
 
 
 class DecoderBlock(HybridBlock):
-    def __init__(self, attn, ffn, dim, epsilon=1e-5, **kw):
+    """x + attn(n1(x)), then x + ffn(n2(x)).  router_reads: 'ffn_input', or
+    'attention_input' for an expert layer whose router scores n1(x)."""
+
+    def __init__(self, attn, ffn, dim, epsilon=1e-5,
+                 router_reads="ffn_input", **kw):
         super().__init__(**kw)
+        if router_reads not in ("ffn_input", "attention_input"):
+            raise ValueError(f"DecoderBlock router_reads={router_reads!r}: "
+                             "choose 'ffn_input' or 'attention_input'")
+        self._early_router = router_reads == "attention_input"
         with self.name_scope():
             self.n1 = RMSNorm(dim, epsilon, prefix="n1_")
             self.attn = attn(prefix="attn_")
@@ -152,25 +205,37 @@ class DecoderBlock(HybridBlock):
             self.ffn = ffn(prefix="ffn_")
 
     def hybrid_forward(self, F, x):
-        x = x + self.attn(self.n1(x))
+        h = self.n1(x)
+        x = x + self.attn(h)
+        if self._early_router:
+            return x + self.ffn(self.n2(x), h)
         return x + self.ffn(self.n2(x))
 
 
 class DecoderLM(HybridBlock):
-    """Token ids (B, T) -> logits (B, T, vocab); untied head, no biases."""
+    """Token ids (B, T) -> logits (B, T, vocab); untied head, no biases.
 
-    def __init__(self, vocab, dim, num_layers, num_heads, q_rank, kv_rank,
-                 nope_dim, rope_dim, v_dim, dense_ffn_dim, expert_ffn_dim,
-                 num_experts, top_k, held_experts=None, first_expert=0,
+    attention: a pattern of attention-block factories (`prefix=` is the
+    one argument each is called with), repeated over the layers: layer i
+    gets `attention[i % len(attention)]`.  None: every layer a
+    `LatentAttention` from `num_heads`, the ranks and the head sizes."""
+
+    def __init__(self, vocab, dim, num_layers, num_heads=None, q_rank=None,
+                 kv_rank=None, nope_dim=None, rope_dim=None, v_dim=None,
+                 dense_ffn_dim=None, expert_ffn_dim=None, num_experts=None,
+                 top_k=None, held_experts=None, first_expert=0,
                  shared_experts=1, first_k_dense=1, routed_scale=1.0,
                  norm_topk=True, epsilon=1e-5, rope_base=10000.0,
-                 attn_type="dense", **kw):
+                 attn_type="dense", attention=None, router="sigmoid",
+                 activation="silu", router_reads="ffn_input", **kw):
         super().__init__(**kw)
 
-        def attn(prefix):
+        def latent(prefix):
             return LatentAttention(dim, num_heads, q_rank, kv_rank, nope_dim,
                                    rope_dim, v_dim, attn_type, epsilon,
                                    rope_base, prefix=prefix)
+
+        pattern = list(attention) if attention else [latent]
 
         def dense_ffn(prefix):
             return GatedFeedForward(dim, dense_ffn_dim, prefix=prefix)
@@ -178,15 +243,19 @@ class DecoderLM(HybridBlock):
         def expert_ffn(prefix):
             return MoEFeedForward(dim, expert_ffn_dim, num_experts, top_k,
                                   held_experts, first_expert, shared_experts,
-                                  routed_scale, norm_topk, prefix=prefix)
+                                  routed_scale, norm_topk, router, activation,
+                                  prefix=prefix)
 
         with self.name_scope():
             self.tok = nn.Embedding(vocab, dim, prefix="tok_")
             self.blocks = nn.HybridSequential(prefix="blocks_")
             for i in range(num_layers):
+                dense = i < first_k_dense
                 self.blocks.add(DecoderBlock(
-                    attn, dense_ffn if i < first_k_dense else expert_ffn,
-                    dim, epsilon, prefix=f"l{i}_"))
+                    pattern[i % len(pattern)],
+                    dense_ffn if dense else expert_ffn, dim, epsilon,
+                    "ffn_input" if dense else router_reads,
+                    prefix=f"l{i}_"))
             self.norm_f = RMSNorm(dim, epsilon, prefix="normf_")
             self.head = _dense(vocab, dim, "head_")
 
@@ -195,7 +264,8 @@ class DecoderLM(HybridBlock):
 
     def generate(self, *args, **kwargs):
         raise NotImplementedError(
-            "DecoderLM has no decode path yet: a latent leaf in the decode "
-            "cache is ROADMAP R1 / R6")
+            "DecoderLM has no decode path yet: a latent leaf, a window's "
+            "ring and a global layer's table in the decode cache are "
+            "ROADMAP R1 / R6")
 
     _kv_forward = generate

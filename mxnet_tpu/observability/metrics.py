@@ -738,6 +738,17 @@ FLASH_FWD_TILE = Gauge(
     "that the call counted in mxnet_flash_fwd_blocks ran with: chosen from "
     "the shapes (ops/flash_attention.py _fa_tiles) unless the caller of "
     "flash_attention gave block_q / block_k")
+FLASH_FWD_TILES = Counter(
+    "mxnet_flash_fwd_tiles_total",
+    "(query tile, key tile) pairs of the forward flash-attention calls "
+    "traced so far, over all their batch entries and heads, by kind: "
+    "visited = the pairs the kernel's loops run (mxnet_flash_fwd_blocks' "
+    "computed, added up over the calls), needed = the pairs in which the "
+    "call's mask (causal, window) leaves at least one query a key, counted "
+    "from the mask.  Added to when a call is traced, as mxnet_moe_rows is "
+    "set: nothing runs in the step, and only the ratio of the two means "
+    "anything (a program traced twice adds to both).  1.0 is a kernel "
+    "that visits no tile the mask empties")
 
 
 def watch_moe_layer(block) -> None:

@@ -1,6 +1,10 @@
-"""Operators of a current decoder block: RMS norm, rotary positions, the
-latent-attention assembly, and a mixture-of-experts feed-forward that is
-told which experts it holds.
+"""Operators of a current decoder block: RMS norm, rotary positions, two
+attention assemblies (latent keys and values at one head count;
+grouped-query heads with rotary positions or none and a sliding window or
+none), and a mixture-of-experts feed-forward that is told which experts it
+holds and how its model routes (sigmoid scores with a selection bias, or a
+softmax over the chosen logits; a SiLU or a ReLU gate; the router reading
+the experts' input or a tensor of its own).
 
 Registered as ops (not python in the gluon blocks) for the reason
 `_contrib_multihead_attention` is: the head splits, the rotation tables and
@@ -19,6 +23,8 @@ tiles its group sizes cover, so the tail of the buffer that no held expert
 owns costs no MXU time).
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -122,6 +128,50 @@ def _latent_attention(p, q, kv, k_rope):
     return out.transpose(0, 2, 1, 3).reshape(B, T, H * dv)
 
 
+@register("_contrib_grouped_query_attention",
+          input_names=("q", "k", "v"), aliases=("grouped_query_attention",),
+          args=[Arg("num_heads", int, required=True),
+                Arg("num_kv_heads", int, required=True),
+                Arg("rope", bool, True), Arg("rope_base", float, 10000.0),
+                Arg("window", int, -1), Arg("impl", str, "dense")])
+def _grouped_query_attention(p, q, k, v):
+    """Causal attention over grouped-query heads.
+
+    q: (B, T, H * D); k, v: (B, T, Hkv * D), H a multiple of Hkv: query
+    head j attends to key/value head j // (H / Hkv).  `rope`: rotary
+    positions over the whole of every query and key head ("split halves"
+    pairing, as `rotary_embedding`); off, the layer has no positions at
+    all.  `window` W > 0: query t sees keys t - W < s <= t (its own and
+    the W - 1 before it); left at -1, every key up to its own.  Scores are
+    scaled by D ** -0.5.  Returns (B, T, H * D).  impl='flash' is the
+    Pallas kernel (keys and values stay at Hkv heads; the tiles it visits
+    follow the mask), 'dense' materializes the scores.
+    """
+    B, T, _ = q.shape
+    H, Hkv = p["num_heads"], p["num_kv_heads"]
+    D = q.shape[-1] // H
+    if H % Hkv or k.shape[-1] != Hkv * D or v.shape[-1] != Hkv * D:
+        raise ValueError(
+            f"grouped_query_attention: {H} query heads of {D} over {Hkv} "
+            f"key/value heads need k and v of width {Hkv * D}, got "
+            f"{k.shape[-1]} and {v.shape[-1]}")
+    q = q.reshape(B, T, H, D)
+    k, v = k.reshape(B, T, Hkv, D), v.reshape(B, T, Hkv, D)
+    if p["rope"]:
+        q, k = _rope(q, p["rope_base"]), _rope(k, p["rope_base"])
+    q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+    scale = float(D) ** -0.5
+    window = p["window"] if p["window"] > 0 else None
+    if p["impl"] == "flash":
+        out = _flash_attention(q, k, v, scale, True, None, None, window)
+    elif p["impl"] == "dense":
+        out = _dense_reference(q, k, v, scale, True, window)
+    else:
+        raise ValueError(f"grouped_query_attention impl={p['impl']!r}: "
+                         "choose 'dense' or 'flash'")
+    return out.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+
+
 @jax.custom_vjp
 def _permute_rows(x, perm, inv):
     """x[perm] for a permutation: the backward pass is a gather through
@@ -134,26 +184,46 @@ _permute_rows.defvjp(
     lambda res, g: (_permute_rows(g, res[1], res[0]), None, None))
 
 
-def route(h, router_w, bias, top_k, scale, norm_topk):
-    """(chosen experts (T, k) int32, their weights (T, k) float32): sigmoid
-    scores in float32; the `top_k` experts of largest score + bias; weights
-    from the scores WITHOUT the bias, normalised over the chosen, times
-    `scale`."""
-    s = jax.nn.sigmoid(jnp.einsum(
+ROUTERS = ("sigmoid", "softmax_topk")
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def route(h, router_w, bias, top_k, scale, norm_topk, router="sigmoid"):
+    """(chosen experts (T, k) int32, their weights (T, k) float32), the
+    router's product and everything after it in float32.
+
+    'sigmoid': sigmoid scores; the `top_k` experts of largest score +
+    bias; weights from the scores WITHOUT the bias, normalised over the
+    chosen, times `scale`.  'softmax_topk': the `top_k` experts of largest
+    logit + bias; weights a softmax over the chosen logits alone
+    (`norm_topk`; the same numbers as a softmax over all experts
+    renormalised over the chosen) or, without `norm_topk`, the chosen
+    experts' part of a softmax over all; times `scale`."""
+    s = jnp.einsum(
         "td,ed->te", h.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
+        precision=lax.Precision.HIGHEST)
+    if router == "sigmoid":
+        s = jax.nn.sigmoid(s)
+    elif router != "softmax_topk":
+        raise ValueError(f"moe_ffn router={router!r}: choose one of "
+                         f"{ROUTERS}")
+    elif not norm_topk:
+        s = jax.nn.softmax(s, axis=-1)
     _, idx = lax.top_k(s + lax.stop_gradient(bias.astype(jnp.float32)),
                        top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
-    if norm_topk:
+    if router == "softmax_topk" and norm_topk:
+        w = jax.nn.softmax(w, axis=-1)
+    elif norm_topk:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), w * scale
 
 
-def _expert_rows(h, key, w, gate_w, up_w, down_w):
+def _expert_rows(h, key, w, gate_w, up_w, down_w, act=jax.nn.silu):
     """The held experts' part: rows sorted by `key` (held experts 0..held-1,
     absent ones `held`, which sort last), three grouped products over the
-    ragged split, unsorted and summed over a token's choices."""
+    ragged split (`act` on the gate's), unsorted and summed over a token's
+    choices."""
     held = gate_w.shape[0]
     rows, top_k = key.shape[0], w.shape[1]
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
@@ -171,49 +241,81 @@ def _expert_rows(h, key, w, gate_w, up_w, down_w):
     # every choice's copy of its token, sorted by expert
     xs = _permute_rows(jnp.repeat(h, top_k, axis=0), order, inv)
     xs = jnp.where(live, xs, jnp.zeros((), xs.dtype))
-    act = jax.nn.silu(lax.ragged_dot(xs, gate_w, groups)) \
+    mid = act(lax.ragged_dot(xs, gate_w, groups)) \
         * lax.ragged_dot(xs, up_w, groups)
-    ys = lax.ragged_dot(act, down_w, groups)                # (rows, D)
+    ys = lax.ragged_dot(mid, down_w, groups)                # (rows, D)
     ys = jnp.where(live, ys, jnp.zeros((), ys.dtype))
     y = _permute_rows(ys, inv, order).reshape(-1, top_k, ys.shape[-1])
     y = jnp.sum(y.astype(jnp.float32) * w[:, :, None], axis=1)
     return y.astype(h.dtype), sizes
 
 
-@register("_contrib_moe_ffn",
-          input_names=("data", "router_weight", "select_bias",
-                       "gate_weight", "up_weight", "down_weight", "load"),
-          aliases=("moe_ffn",), aux_inputs=[2, 6], f32_inputs=(2, 6),
-          args=[Arg("num_experts", int, required=True),
-                Arg("top_k", int, required=True),
-                Arg("first", int, 0), Arg("held", int, required=True),
-                Arg("scale", float, 1.0), Arg("norm_topk", bool, True)])
-def _moe_ffn(p, x, router_w, bias, gate_w, up_w, down_w, load):
-    """Mixture-of-experts gated feed-forward over the experts held here.
+# The outputs of one layer's three grouped products are rows x (2 F + D)
+# values, and a recorded CachedOp call keeps them for its backward program
+# (gluon/block.py _RESIDUAL_POLICY reaches into this op's own
+# `jax.checkpoint`).  Past this many bytes a layer they are not kept: the
+# backward pass runs the layer's grouped products again from the op's
+# inputs.  At 2 x 2,048 tokens, 4 a token, F 1,536, D 2,048 they are 168 MB
+# a layer and that program stays as it was; at 2 x 8,192 tokens, 6 a token,
+# F 768, D 2,560 they would be 805 MB a layer, 3.2 GB of four layers'
+# residuals, seven eighths of it rows that no held expert owns, and the step
+# does not fit the chip beside them (17.4 of 16.9 GB: v5e compiles, PR 31).
+# PROVISIONAL (PR 31): the bound lies between those two readings and no cell
+# has been measured on the other side of it.  ROADMAP S14's first step runs
+# the SiLU expert cell under `_run_again_in_backward` and keeps one mode.
+KEEP_BYTES_MAX = 2 ** 29
 
-    data (..., D); router_weight (num_experts, D); select_bias
-    (num_experts,), auxiliary, added to the scores for the selection only;
-    gate_weight, up_weight (held, D, F) and down_weight (held, F, D), the
-    stacked matrices of experts `first .. first + held - 1`; load
-    (held + 1,), auxiliary float32: the forward pass adds the assignments
-    each held expert got and, last, those that fell on absent experts.
 
-    Routes every token over all `num_experts` (sigmoid scores, `top_k` a
-    token) and returns sum_i w_i E_i(x) over the chosen experts that are
-    held: a partial result where held < num_experts.  Dropless: the buffer
-    has `T * top_k` rows, so every token sent to one held expert still
-    equals the reference.  The feed-forward's intermediates are recomputed
-    in the backward pass (`jax.checkpoint`), as jobs that fill the chip do;
-    under a caller's own `jax.checkpoint` (a recorded CachedOp call) the
-    caller's policy decides instead, and keeps the grouped products.
-    """
+def _run_again_in_backward(experts):
+    """`experts(h, key, w, gate_w, up_w, down_w) -> (y, sizes)` whose
+    backward pass starts from its inputs and nothing else, whatever an
+    enclosing `jax.checkpoint`'s policy would keep of its products."""
+    @jax.custom_vjp
+    def call(h, key, w, *weights):
+        return experts(h, key, w, *weights)
+
+    def fwd(*args):
+        return call(*args), args
+
+    def bwd(args, cots):
+        # tied to the cotangent, as `jax.checkpoint` ties its own: else the
+        # compiler is free to run every layer's products again at the head
+        # of the backward program and hold them all until their turn (12.6
+        # GB of temporaries against 6.9: v5e compiles, PR 31)
+        (h, key, *rest), dy = lax.optimization_barrier((args, cots[0]))
+        dh, *drest = jax.vjp(
+            lambda h_, *rest_: experts(h_, key, *rest_)[0], h, *rest)[1](dy)
+        return (dh, None, *drest)  # the rows' keys are integers
+
+    call.defvjp(fwd, bwd)
+    return call
+
+
+_MOE_ARGS = [Arg("num_experts", int, required=True),
+             Arg("top_k", int, required=True),
+             Arg("first", int, 0), Arg("held", int, required=True),
+             Arg("scale", float, 1.0), Arg("norm_topk", bool, True),
+             Arg("router", str, "sigmoid"), Arg("activation", str, "silu")]
+
+
+def _moe(p, x, routed_by, router_w, bias, gate_w, up_w, down_w, load):
+    """`moe_ffn`'s body: the experts read `x`, the router `routed_by`."""
     E, k, first, held = (p["num_experts"], p["top_k"], p["first"],
                          p["held"])
     if gate_w.shape[0] != held or not 0 <= first <= E - held:
         raise ValueError(f"moe_ffn: held={held} first={first} of {E} "
                          f"experts against {gate_w.shape[0]} stacked")
+    if p["activation"] not in ACTIVATIONS:
+        raise ValueError(f"moe_ffn activation={p['activation']!r}: choose "
+                         f"one of {sorted(ACTIVATIONS)}")
+    if routed_by.shape[:-1] != x.shape[:-1]:
+        raise ValueError(f"moe_ffn: the router's input {routed_by.shape} "
+                         f"and the experts' {x.shape} differ in tokens")
     h = x.reshape(-1, x.shape[-1])
-    idx, w = route(h, router_w, bias, k, p["scale"], p["norm_topk"])
+    hr = h if routed_by is x else \
+        routed_by.reshape(-1, routed_by.shape[-1])
+    idx, w = route(hr, router_w, bias, k, p["scale"], p["norm_topk"],
+                   p["router"])
     local = idx - first
     key = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
     rows = key.shape[0]
@@ -224,7 +326,64 @@ def _moe_ffn(p, x, router_w, bias, gate_w, up_w, down_w, load):
     # split rounded out to whole tiles, never more than the buffer
     _metrics.MOE_ROWS.set(
         min(rows, required + held * GROUPED_TILE_ROWS), kind="multiplied")
-    y, sizes = jax.checkpoint(_expert_rows)(h, key, w, gate_w, up_w, down_w)
+    # the function itself where it can be: `jax.checkpoint` of a `partial`
+    # traces to another program text, and the SiLU cells keep theirs
+    experts = _expert_rows if p["activation"] == "silu" else \
+        functools.partial(_expert_rows, act=ACTIVATIONS[p["activation"]])
+    kept = rows * (gate_w.shape[2] + up_w.shape[2] + down_w.shape[2]) \
+        * x.dtype.itemsize
+    if kept > KEEP_BYTES_MAX:
+        y, sizes = _run_again_in_backward(experts)(
+            h, key, w, gate_w, up_w, down_w)
+    else:
+        y, sizes = jax.checkpoint(experts)(h, key, w, gate_w, up_w, down_w)
     new_load = load + sizes.astype(load.dtype)
     return (y.reshape(x.shape), lax.stop_gradient(bias),
             lax.stop_gradient(new_load))
+
+
+@register("_contrib_moe_ffn",
+          input_names=("data", "router_weight", "select_bias",
+                       "gate_weight", "up_weight", "down_weight", "load"),
+          aliases=("moe_ffn",), aux_inputs=[2, 6], f32_inputs=(2, 6),
+          args=_MOE_ARGS)
+def _moe_ffn(p, x, router_w, bias, gate_w, up_w, down_w, load):
+    """Mixture-of-experts gated feed-forward over the experts held here.
+
+    data (..., D); router_weight (num_experts, D); select_bias
+    (num_experts,), auxiliary, added to the scores for the selection only;
+    gate_weight, up_weight (held, D, F) and down_weight (held, F, D), the
+    stacked matrices of experts `first .. first + held - 1`; load
+    (held + 1,), auxiliary float32: the forward pass adds the assignments
+    each held expert got and, last, those that fell on absent experts.
+
+    Routes every token over all `num_experts`, `top_k` a token (`router`:
+    'sigmoid' scores normalised over the chosen, or 'softmax_topk', a
+    softmax over the chosen logits; ops/decoder.py `route`) and returns
+    sum_i w_i E_i(x) over the chosen experts that are held, E_i(x) =
+    down_i(act(gate_i x) * up_i x) with `activation` 'silu' or 'relu': a
+    partial result where held < num_experts.  Dropless: the buffer has
+    `T * top_k` rows, so every token sent to one held expert still equals
+    the reference.  The feed-forward's intermediates are recomputed in the
+    backward pass (`jax.checkpoint`), as jobs that fill the chip do; under
+    a caller's own `jax.checkpoint` (a recorded CachedOp call) the caller's
+    policy decides instead, and keeps the grouped products, unless their
+    outputs pass `KEEP_BYTES_MAX` a layer: then the backward pass runs them
+    again from the op's inputs whoever calls.
+    """
+    return _moe(p, x, x, router_w, bias, gate_w, up_w, down_w, load)
+
+
+@register("_contrib_moe_ffn_routed_by",
+          input_names=("data", "router_data", "router_weight", "select_bias",
+                       "gate_weight", "up_weight", "down_weight", "load"),
+          aliases=("moe_ffn_routed_by",), aux_inputs=[3, 7],
+          f32_inputs=(3, 7), args=_MOE_ARGS)
+def _moe_ffn_routed_by(p, x, routed_by, router_w, bias, gate_w, up_w, down_w,
+                       load):
+    """`moe_ffn` whose router reads a tensor of its own: `router_data`
+    (..., D_r), as many tokens as `data`, with router_weight (num_experts,
+    D_r).  A layer that routes before its attention hands the attention's
+    input here and the feed-forward's to `data`.  Everything else as
+    `moe_ffn`."""
+    return _moe(p, x, routed_by, router_w, bias, gate_w, up_w, down_w, load)

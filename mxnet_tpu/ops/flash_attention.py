@@ -19,16 +19,29 @@ What the forward kernel does with a call (`_fa_tiles`, `_fa_kernel`):
   the accumulator are float32.
 - Under a causal mask the sub-tiles wholly above the diagonal are neither
   fetched nor computed, and only those the diagonal crosses are masked.
+  With a `window` W (query t sees keys t - W < s <= t) the sub-tiles wholly
+  left of the band are skipped the same way, and those its edge crosses
+  are masked.
+- Keys and values may have fewer heads than the queries (grouped-query
+  attention): with H query and Hkv key/value heads, query head j reads
+  key/value head j // (H / Hkv), by the block index map alone; the
+  backward pass sums a group's query heads into one key and value gradient.
 
 `mxnet_flash_fwd_blocks` / `mxnet_flash_fwd_tile` (observability/metrics.py)
-hold the grid, the computed share and the tiles of the call traced last.
+hold the grid, the computed share and the tiles of the call traced last;
+`mxnet_flash_fwd_tiles_total` adds up, over every call traced, the tiles
+the kernel visits and the tiles the mask leaves something of.
 Composes with `parallel.sequence_parallel.ring_attention`, which rotates
 K/V shards across chips while this kernel handles the on-chip block math.
 
 Backward is a custom VJP that recomputes scores blockwise (a loop over
 q-blocks of its own size, `BWD_BLOCK`): peak extra memory O(blk · Tk) per
 (batch, head) — linear in sequence length, the standard flash recompute
-trade.
+trade.  With one head count and no window it multiplies every query block
+against all the keys (`_bwd_whole_keys`, two ways of summing the key and
+value gradients); grouped heads or a window go through `_bwd_banded`,
+which takes a group's query heads together and, under a window, slices
+the band of keys a query block can see.
 """
 from __future__ import annotations
 
@@ -56,7 +69,7 @@ def _lanes(x, n):
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-               scale, scale_q, causal, blk_q, blk_k, sub):
+               scale, scale_q, causal, window, blk_q, blk_k, sub):
     """Grid (BH, nq, nk); nk is sequential — scratch carries the online
     softmax state across k steps, and within a step across the `sub`-wide
     sub-tiles of the (blk_k, D) key / value block.  The running max and
@@ -98,8 +111,13 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         if masked:
             row = jax.lax.broadcasted_iota(jnp.int32, (blk_q, sub), 0)
             col = jax.lax.broadcasted_iota(jnp.int32, (blk_q, sub), 1)
-            s = jnp.where(row - col >= first_k + c * sub - first_q, s,
-                          NEG_INF)
+            # query position - key position is behind - ahead
+            behind = row - col
+            ahead = first_k + c * sub - first_q
+            seen_ = behind >= ahead
+            if window is not None:
+                seen_ &= behind < ahead + window
+            s = jnp.where(seen_, s, NEG_INF)
         m_prev, l_prev = m_ref[...], l_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - _lanes(m_new, sub))
@@ -126,8 +144,25 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             return jnp.minimum(jnp.maximum(jax.lax.div(n, sub), 0), n_sub)
         under = count(first_q - first_k + 1)
         seen = count(first_q + blk_q - 1 - first_k + sub)
-        _over(0, under, False)
-        _over(under, seen, True)
+        if window is None:
+            _over(0, under, False)
+            _over(under, seen, True)
+        else:
+            # sub-tiles wholly left of the band (last column <= first row
+            # - window) are not visited; those the band's edge crosses
+            # (first column <= last row - window) are masked, and so are
+            # those on the diagonal; a row whose keys all lie further
+            # right meets only masked columns first, and what it summed
+            # over them is wiped when its first real score arrives
+            # (NEG_INF is finite: the correction is exp(-1e30 - m) = 0)
+            start = count(first_q - window + 1 - first_k)
+            inside = jnp.clip(
+                count(first_q + blk_q - 1 - window - first_k + sub),
+                start, seen)
+            under = jnp.clip(under, inside, seen)
+            _over(start, inside, True)
+            _over(inside, under, False)
+            _over(under, seen, True)
     else:
         _over(0, n_sub, False)
 
@@ -138,12 +173,18 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                       ).astype(o_ref.dtype)
 
 
-def _dense_reference(q, k, v, scale, causal):
+def _dense_reference(q, k, v, scale, causal, window=None):
+    group = q.shape[1] // k.shape[1]
+    if group > 1:  # query head j reads key / value head j // group
+        k, v = (jnp.repeat(a, group, axis=1) for a in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if causal:
         Tq, Tk = q.shape[2], k.shape[2]
-        mask = jnp.arange(Tq)[:, None] >= jnp.arange(Tk)[None, :]
+        ahead = jnp.arange(Tq)[:, None] - jnp.arange(Tk)[None, :]
+        mask = ahead >= 0
+        if window is not None:
+            mask &= ahead < window
         s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)
@@ -196,22 +237,57 @@ def _fa_tiles(Tq, Tk, D, dtype, budget=VMEM_BUDGET):
     return blk_q, blk_k, sub
 
 
-def _fa_blocks(Tq, Tk, blk_q, sub, causal):
+def _fa_blocks(Tq, Tk, blk_q, sub, causal, window=None):
     """(grid, computed): the (query tile, key sub-tile) pairs of one head
-    and those that a causal mask leaves something of."""
+    and those the kernel visits: under a causal mask none above the
+    diagonal, under a window none wholly left of the band either (the
+    kernel's own loop bounds, in plain integers)."""
     nq, nk = Tq // blk_q, Tk // sub
     if not causal:
         return nq * nk, nq * nk
-    return nq * nk, sum(min(nk, ((i + 1) * blk_q - 1) // sub + 1)
-                        for i in range(nq))
+    visited = 0
+    for i in range(nq):
+        seen = min(nk, ((i + 1) * blk_q - 1) // sub + 1)
+        start = 0 if window is None else \
+            min(max((i * blk_q - window + 1) // sub, 0), seen)
+        visited += seen - start
+    return nq * nk, visited
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attention(q, k, v, scale, causal, blk_q=None, blk_k=None):
-    """The forward kernel.  blk_q / blk_k None: `_fa_tiles` chooses from
-    the shapes; given, they are the scores tile and the key block both."""
+def _fa_needed(Tq, Tk, blk_q, sub, causal, window=None):
+    """The (query tile, key sub-tile) pairs of one head in which the mask
+    leaves at least one (query, key) pair: counted from the mask, not
+    from the kernel's loops."""
+    if not causal:
+        return (Tq // blk_q) * (Tk // sub)
+    needed = 0
+    for i in range(Tq // blk_q):
+        first, last = i * blk_q, (i + 1) * blk_q - 1      # query rows
+        for j in range(Tk // sub):
+            lo, hi = j * sub, (j + 1) * sub - 1            # key columns
+            # some row t in [first, last] sees some column s in [lo, hi]:
+            # s <= t, and s > t - window
+            if lo <= last and (window is None or hi > first - window):
+                needed += 1
+    return needed
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention(q, k, v, scale, causal, blk_q=None, blk_k=None,
+                     window=None):
+    """The forward kernel.  q (B, H, Tq, D); k, v (B, Hkv, Tk, D) with H a
+    multiple of Hkv.  blk_q / blk_k None: `_fa_tiles` chooses from the
+    shapes; given, they are the scores tile and the key block both.
+    window W (with `causal`): query t sees keys t - W < s <= t."""
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    Hkv, Tk = k.shape[1], k.shape[2]
+    if H % Hkv or v.shape[1] != Hkv:
+        raise ValueError(f"flash attention: {H} query heads over {Hkv} key "
+                         f"and {v.shape[1]} value heads")
+    if window is not None and (not causal or window < 1):
+        raise ValueError("flash attention: a window needs causal=True and "
+                         f"at least one key, got window={window}")
+    group = H // Hkv
     if blk_q is None or blk_k is None:
         tiles = _fa_tiles(Tq, Tk, D, q.dtype)
     else:
@@ -219,21 +295,25 @@ def _flash_attention(q, k, v, scale, causal, blk_q=None, blk_k=None):
     if tiles is None:
         # shapes the blocking cannot tile (not an escape from compile
         # trouble: on TPU the kernel below compiles or raises)
-        return _dense_reference(q, k, v, scale, causal)
+        return _dense_reference(q, k, v, scale, causal, window)
     blk_q, blk_k, sub = tiles
     from jax.experimental.pallas import tpu as pltpu
     from ..observability import metrics as _metrics
-    for kind, n in zip(("grid", "computed"),
-                       _fa_blocks(Tq, Tk, blk_q, sub, causal)):
-        _metrics.FLASH_FWD_BLOCKS.set(B * H * n, kind=kind)
+    grid, visited = _fa_blocks(Tq, Tk, blk_q, sub, causal, window)
+    _metrics.FLASH_FWD_BLOCKS.set(B * H * grid, kind="grid")
+    _metrics.FLASH_FWD_BLOCKS.set(B * H * visited, kind="computed")
+    _metrics.FLASH_FWD_TILES.inc(B * H * visited, kind="visited")
+    _metrics.FLASH_FWD_TILES.inc(
+        B * H * _fa_needed(Tq, Tk, blk_q, sub, causal, window),
+        kind="needed")
     _metrics.FLASH_FWD_TILE.set(blk_q, dim="q")
     _metrics.FLASH_FWD_TILE.set(sub, dim="k")
     # the scale goes on q where that is exact (a power of two) or float32
     # arithmetic already; else on the float32 scores
     scale_q = q.dtype == jnp.float32 or math.frexp(scale)[0] == 0.5
     kernel = functools.partial(_fa_kernel, scale=scale, scale_q=scale_q,
-                               causal=causal, blk_q=blk_q, blk_k=blk_k,
-                               sub=sub)
+                               causal=causal, window=window, blk_q=blk_q,
+                               blk_k=blk_k, sub=sub)
 
     def kv_index_map(b, i, j):
         if causal:
@@ -241,6 +321,11 @@ def _flash_attention(q, k, v, scale, causal, blk_q=None, blk_k=None):
             # block may see the map stays on that block, and the pipeline
             # issues no copy for a block it already holds
             j = jnp.minimum(j, jax.lax.div((i + 1) * blk_q - 1, blk_k))
+        if window is not None:  # nor for one wholly left of the band
+            j = jnp.maximum(j, jax.lax.div(
+                jnp.maximum(i * blk_q - window + 1, 0), blk_k))
+        if group > 1:  # the group's query heads read one key / value head
+            b = jax.lax.div(b, group)
         return b, j, 0
     # mxnet_tpu runs with jax_enable_x64 on, under which Python scalars
     # and the index maps' literals trace as f64/i64; Mosaic has neither,
@@ -267,13 +352,13 @@ def _flash_attention(q, k, v, scale, causal, blk_q=None, blk_k=None):
             # the interpreter is how the CPU runs the kernel in tests; on
             # TPU Mosaic compiles it, and a compile error is an error
             interpret=jax.default_backend() == "cpu",
-        )(q.reshape(B * H, Tq, D), k.reshape(B * H, Tk, D),
-          v.reshape(B * H, Tk, D))
+        )(q.reshape(B * H, Tq, D), k.reshape(B * Hkv, Tk, D),
+          v.reshape(B * Hkv, Tk, D))
     return out.reshape(B, H, Tq, D)
 
 
-def _fa_fwd(q, k, v, scale, causal, blk_q, blk_k):
-    o = _flash_attention(q, k, v, scale, causal, blk_q, blk_k)
+def _fa_fwd(q, k, v, scale, causal, blk_q, blk_k, window):
+    o = _flash_attention(q, k, v, scale, causal, blk_q, blk_k, window)
     # without the mark a recorded CachedOp call's backward program would run
     # the kernel again for `o` (registry.RESIDUAL_NAME)
     o = checkpoint_name(o, RESIDUAL_NAME)
@@ -293,14 +378,98 @@ STACK_BYTES_MAX = 2 ** 30
 BWD_BLOCK = 128
 
 
-def _fa_bwd(scale, causal, blk_q, blk_k, res, g):
+def _fa_bwd(scale, causal, blk_q, blk_k, window, res, g):
     """Blockwise recompute backward: a loop over q blocks keeps peak
     score memory at O(blk_q · Tk) per (batch, head).
 
     Flash backward identities (FlashAttention paper, §B):
       P = softmax(S);  D_i = rowsum(dO ∘ O)
       dV = Pᵀ dO;  dS = P ∘ (dO Vᵀ − D_i);  dQ = dS K · scale;  dK = dSᵀ Q · scale
+
+    PROVISIONAL dispatch (PR 31): one head count and no window take
+    `_bwd_whole_keys`, the pass the accepted cells were measured on, whose
+    program text a test pins; everything else takes `_bwd_banded`.  With
+    group 1 and no window `_bwd_banded` is `_bwd_whole_keys`' carried-sums
+    path written once more, so three paths (stacked, carried, banded) do one
+    thing.  ROADMAP S6's first step measures the accepted cells under
+    `_bwd_banded` and keeps one path; until then a change to the backward
+    pass is measured on all three.
     """
+    if res[0].shape[1] == res[1].shape[1] and window is None:
+        return _bwd_whole_keys(scale, causal, res, g)
+    with jax.named_scope("flash_attention_bwd"):
+        return _bwd_banded(scale, causal, window, res, g)
+
+
+def _bwd_banded(scale, causal, window, res, g):
+    """The backward pass for grouped heads or a window.  One loop over the
+    query blocks: the block's rows of all `group` query heads that read a
+    key/value head go through the products together (group x blk rows), so
+    their key and value gradients are summed by the products themselves.  Under a window the block meets only a slice of `span`
+    keys, the fewest whole multiples of 128 that hold every key its rows
+    can see, ending where the block ends; without one it meets them all.
+    The key and value gradients are summed in the loop's carry, in place
+    over the slice."""
+    q, k, v, o = res
+    B, H, Tq, D = q.shape
+    Hkv, Tk = k.shape[1], k.shape[2]
+    group = H // Hkv
+    blk = BWD_BLOCK if Tq % BWD_BLOCK == 0 else Tq
+    nq = Tq // blk
+    span = Tk if window is None else \
+        min(Tk, -(-(window + blk - 1) // 128) * 128)
+    f32 = lambda a: a.astype(jnp.float32)
+    # (N, group, Tq, D) queries, outputs and their gradients of the query
+    # heads that read each of the N = B * Hkv heads' (N, Tk, D) keys and
+    # values.  The batch axis is written out (no `vmap`): the slices below
+    # then stay slices at a scalar offset, where `vmap` would make them
+    # gathers and scatters
+    by_kv = lambda a: f32(a).reshape(B * Hkv, group, Tq, D)
+    flat = lambda a: f32(a).reshape(B * Hkv, Tk, D)
+    qg, og, gg = by_kv(q), by_kv(o), by_kv(g)
+    kf, vf = flat(k), flat(v)
+    delta = jnp.sum(gg * og, axis=-1)                     # (N, group, Tq)
+
+    def q_block(acc, i):
+        rows = lambda a: jax.lax.dynamic_slice_in_dim(
+            a, i * blk, blk, axis=2).reshape((B * Hkv, group * blk)
+                                             + a.shape[3:])
+        qs, gs, ds = rows(qg), rows(gg), rows(delta)
+        # the last `span` keys up to the block's own end, kept inside
+        first = jnp.clip((i + 1) * blk - span, 0, Tk - span)
+        ks = jax.lax.dynamic_slice_in_dim(kf, first, span, axis=1)
+        vs = jax.lax.dynamic_slice_in_dim(vf, first, span, axis=1)
+        s = jnp.einsum("nqd,nkd->nqk", qs, ks) * scale
+        if causal:
+            q_pos = jnp.tile(i * blk + jnp.arange(blk), group)
+            ahead = q_pos[:, None] - (first + jnp.arange(span))[None, :]
+            mask = ahead >= 0
+            if window is not None:
+                mask &= ahead < window
+            s = jnp.where(mask, s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        dsoft = p * (jnp.einsum("nqd,nkd->nqk", gs, vs) - ds[..., None])
+        dq = jnp.einsum("nqk,nkd->nqd", dsoft, ks) * scale
+        dk = jnp.einsum("nqk,nqd->nkd", dsoft, qs) * scale    # (N, span, D)
+        dv = jnp.einsum("nqk,nqd->nkd", p, gs)
+        add = lambda a, d: jax.lax.dynamic_update_slice_in_dim(
+            a, jax.lax.dynamic_slice_in_dim(a, first, span, axis=1) + d,
+            first, axis=1)
+        return (add(acc[0], dk), add(acc[1], dv)), \
+            dq.reshape(B * Hkv, group, blk, D)
+
+    (dk, dv), dqs = jax.lax.scan(
+        q_block, (jnp.zeros_like(kf), jnp.zeros_like(vf)), jnp.arange(nq))
+    # (nq, N, group, blk, D) -> (N, group, Tq, D)
+    dq = dqs.transpose(1, 2, 0, 3, 4)
+    return (dq.reshape(q.shape).astype(q.dtype),
+            dk.reshape(k.shape).astype(k.dtype),
+            dv.reshape(v.shape).astype(v.dtype))
+
+
+def _bwd_whole_keys(scale, causal, res, g):
+    """The backward pass for one head count and no window: every query
+    block against all the keys, a (batch, head) at a time."""
     q, k, v, o = res
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
@@ -361,18 +530,25 @@ _flash_attention.defvjp(_fa_fwd, _fa_bwd)
 @register("_contrib_flash_attention", input_names=("q", "k", "v"),
           aliases=("flash_attention",),
           args=[Arg("causal", bool, False), Arg("scale", float, -1.0),
-                Arg("block_q", int, -1), Arg("block_k", int, -1)])
+                Arg("block_q", int, -1), Arg("block_k", int, -1),
+                Arg("window", int, -1)])
 def _flash_attention_op(p, q, k, v):
-    """Memory-efficient attention: q/k/v (B, H, T, D) → (B, H, T, D).
+    """Memory-efficient attention: q (B, H, T, D), k/v (B, Hkv, T, D) with
+    H a multiple of Hkv (query head j reads key/value head j // (H / Hkv))
+    → (B, H, T, D).
 
     block_q / block_k: the scores tile of the forward kernel; left at -1
-    (both or either) the kernel chooses its tiles from the shapes."""
+    (both or either) the kernel chooses its tiles from the shapes.
+    window W > 0 (with causal): a query sees its own key and the W - 1
+    before it; left at -1, every key up to its own."""
     scale = p["scale"] if p["scale"] > 0 else q.shape[-1] ** -0.5
+    window = p["window"] if p["window"] > 0 else None
     if p["block_q"] <= 0 or p["block_k"] <= 0:
-        return _flash_attention(q, k, v, float(scale), bool(p["causal"]))
+        return _flash_attention(q, k, v, float(scale), bool(p["causal"]),
+                                None, None, window)
     return _flash_attention(q, k, v, float(scale), bool(p["causal"]),
                             min(p["block_q"], q.shape[2]),
-                            min(p["block_k"], k.shape[2]))
+                            min(p["block_k"], k.shape[2]), window)
 
 
 @register("_contrib_mha_decode_step",

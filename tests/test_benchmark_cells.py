@@ -45,3 +45,38 @@ def test_cell_rehearsal_reads_correct(cell, float32_traffic):
         tol = 1e-3 if num.startswith("dparam_norm_gap") else 1e-4
         assert rec["value"] < tol, (num, res["compared"])
     assert res["correct"] is True, res["compared"]
+
+
+FOUR_CHIP_CELLS = [w["name"] for w in cellmod.benchmark()["workloads"]
+                   if w["chips"] == 4]
+
+
+@pytest.mark.parametrize("cell", FOUR_CHIP_CELLS)
+def test_gradient_exchange_left_out_reads_not_correct(cell, float32_traffic,
+                                                      monkeypatch):
+    """The fault that exists only across chips.  The exchange is the
+    all-reduce XLA puts into the one program over the mesh, so leaving it
+    out means that a chip steps on the gradient, and the batch statistics,
+    of its own rows alone.  Planted as that: every chip's rows repeat the
+    first chip's, and the mean over the batch is the first chip's own.  The
+    reference makes its batches from the seed itself and keeps them whole."""
+    real = cellmod.Cell.batches
+
+    def batches(self):
+        import jax.numpy as jnp
+        out = []
+        for x, y in real(self):
+            own = x.shape[0] // self.chips
+            out.append((jnp.concatenate([x[:own]] * self.chips),
+                        jnp.concatenate([y[:own]] * self.chips)))
+        return out
+
+    monkeypatch.setattr(cellmod.Cell, "batches", batches)
+    res = run.run_cell(cell, 7, 0.5, False, rehearsal=True)
+    assert res["device"]["count"] >= 4
+    assert res["correct"] is False, res["compared"]
+    over = {n for n, r in res["compared"].items()
+            if r["limit"] is not None and r["value"] > r["limit"]}
+    # not by the loss alone: the gradient and the change of the weights
+    assert {"grad_norm_gap_median", "dparam_norm_gap_median"} <= over, \
+        res["compared"]

@@ -166,7 +166,7 @@ def test_flash_backward_does_not_follow_the_forward_tiles():
     from mxnet_tpu.ops import flash_attention as fa
     x = jnp.ones((1, 2, 256, 16), jnp.float32)
     programs = {str(jax.make_jaxpr(lambda res, g: fa._fa_bwd(
-        0.25, True, bq, bk, res, g))((x, x, x, x), x))
+        0.25, True, bq, bk, None, res, g))((x, x, x, x), x))
         for bq, bk in ((32, 32), (64, 128), (None, None))}
     assert len(programs) == 1
     assert fa.BWD_BLOCK == 128

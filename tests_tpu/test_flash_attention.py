@@ -1,8 +1,9 @@
-"""The forward flash kernel on the chip at the two benchmark cells' call
+"""The forward flash kernel on the chip at the benchmark cells' call
 shapes (opt1.3b_train_gluon: 2 x 32 heads of 64; glm4.7flash_train_gluon:
-2 x 20 heads of 256; T 2048, bfloat16, causal) against the dense reference
-in float32 at "highest", forward and gradients, and the counter the tile
-choice sets."""
+2 x 20 heads of 256; T 2048, bfloat16, causal; smallthinker21b_train_gluon:
+2 x 28 query heads over 4 key/value heads of 128, T 8192, with a window of
+4,096 and without) against the dense reference in float32 at "highest",
+forward and gradients, and the counters the tile choice sets."""
 import numpy as np
 import pytest
 
@@ -55,4 +56,81 @@ def test_flash_kernel_at_the_cells_shapes(shape):
         assert np.isfinite(got).all(), name
         # bfloat16 operands and results: 2^-8 of the largest value
         err = float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+        assert err <= 2e-2, (name, err)
+
+
+def _blocked_reference(q, k, v, scale, window, block=1024):
+    """Dense causal attention of one key/value head's group, (G, T, D)
+    queries over (T, D) keys and values, the queries in blocks so that a
+    block's scores against all keys is what is held."""
+    T = q.shape[1]
+    block = min(block, T)
+
+    def one(i):
+        qs = jax.lax.dynamic_slice_in_dim(q, i * block, block, axis=1)
+        s = jnp.einsum("gqd,kd->gqk", qs, k) * scale
+        ahead = (i * block + jnp.arange(block))[:, None] \
+            - jnp.arange(T)[None, :]
+        mask = ahead >= 0
+        if window is not None:
+            mask &= ahead < window
+        s = jnp.where(mask, s, fa.NEG_INF)
+        return jnp.einsum("gqk,kd->gqd", jax.nn.softmax(s, axis=-1), v)
+
+    out = jax.lax.map(jax.checkpoint(one), jnp.arange(T // block))
+    return jnp.moveaxis(out, 0, 1).reshape(q.shape)
+
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["global", "window"])
+def test_flash_kernel_at_the_grouped_query_cells_shape(window):
+    """2 x 28 query heads over 4 key/value heads of 128 at T 8,192: the
+    whole call through the kernel and its banded backward; the last batch
+    entry's last group (7 query heads, 1 key/value head) against the
+    blocked dense reference, whose gradients are that group's alone since
+    the loss is a sum over heads.  On the CPU (the harness's self-test)
+    the same layout at T 1,024 with a window of 512."""
+    on_chip = jax.default_backend() == "tpu"
+    T = 8192 if on_chip else 1024
+    if window is not None and not on_chip:
+        window = 512
+    H, Hkv, D = 28, 4, 128
+    rng = np.random.default_rng(12)
+    q, r = (jnp.asarray(rng.standard_normal((2, H, T, D), dtype=np.float32))
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.standard_normal((2, Hkv, T, D),
+                                            dtype=np.float32))
+            for _ in range(2))
+    q, k, v = (a.astype(jnp.bfloat16) for a in (q, k, v))
+    scale = D ** -0.5
+    metrics.FLASH_FWD_TILES.reset()
+
+    def f(a, b, c):
+        o = fa._flash_attention(a, b, c, scale, True, None, None, window)
+        return jnp.sum(o.astype(jnp.float32) * r), o
+
+    (_, out), (dq, dk, dv) = jax.jit(jax.value_and_grad(
+        f, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    visited = metrics.FLASH_FWD_TILES.get(kind="visited")
+    assert visited == metrics.FLASH_FWD_TILES.get(kind="needed") > 0
+    if on_chip:  # 512 x 512 tiles: 136 a head under the causal mask, 108
+        assert visited == 2 * H * (108 if window else 136)
+    g = H // Hkv
+
+    def ref(a, b, c):
+        o = _blocked_reference(a, b, c, scale, window)
+        return jnp.sum(o * r[1, -g:]), o
+
+    with jax.default_matmul_precision("highest"):
+        (_, want), (wq, wk, wv) = jax.jit(jax.value_and_grad(
+            ref, argnums=(0, 1, 2), has_aux=True))(
+                q[1, -g:].astype(jnp.float32), k[1, -1].astype(jnp.float32),
+                v[1, -1].astype(jnp.float32))
+    for name, got, want_ in (("out", out[1, -g:], want),
+                             ("dq", dq[1, -g:], wq), ("dk", dk[1, -1], wk),
+                             ("dv", dv[1, -1], wv)):
+        assert got.dtype == jnp.bfloat16, name
+        got = np.asarray(got.astype(jnp.float32))
+        want_ = np.asarray(want_)
+        assert np.isfinite(got).all(), name
+        err = float(np.max(np.abs(got - want_)) / np.max(np.abs(want_)))
         assert err <= 2e-2, (name, err)
