@@ -749,6 +749,21 @@ FLASH_FWD_TILES = Counter(
     "set: nothing runs in the step, and only the ratio of the two means "
     "anything (a program traced twice adds to both).  1.0 is a kernel "
     "that visits no tile the mask empties")
+FLASH_BWD = Counter(
+    "mxnet_flash_bwd_total",
+    "Backward flash-attention calls traced so far, by path: kernel = the "
+    "two Pallas kernels (ops/flash_attention.py _bwd_kernels), reference = "
+    "the plain float32 pass, taken where the tiles cannot cover the shapes "
+    "(the forward then took the dense reference and kept no statistics).  "
+    "Added to when a call is traced: nothing runs in the step")
+FLASH_BWD_TILES = Counter(
+    "mxnet_flash_bwd_tiles_total",
+    "Scores tiles of the backward flash-attention kernels traced so far "
+    "(the dQ kernel's and the dK/dV kernel's together), over all their "
+    "batch entries and query heads, by kind: visited = the tiles the "
+    "kernels' loops run, needed = those in which the call's mask (causal, "
+    "window) leaves at least one query a key, counted from the mask.  "
+    "Added to when a call is traced, as mxnet_flash_fwd_tiles_total is")
 
 
 def watch_moe_layer(block) -> None:

@@ -34,14 +34,42 @@ the kernel visits and the tiles the mask leaves something of.
 Composes with `parallel.sequence_parallel.ring_attention`, which rotates
 K/V shards across chips while this kernel handles the on-chip block math.
 
-Backward is a custom VJP that recomputes scores blockwise (a loop over
-q-blocks of its own size, `BWD_BLOCK`): peak extra memory O(blk · Tk) per
-(batch, head) — linear in sequence length, the standard flash recompute
-trade.  With one head count and no window it multiplies every query block
-against all the keys (`_bwd_whole_keys`, two ways of summing the key and
-value gradients); grouped heads or a window go through `_bwd_banded`,
-which takes a group's query heads together and, under a window, slices
-the band of keys a query block can see.
+The backward pass is two Pallas kernels behind a custom VJP (`_fa_bwd`,
+`_bwd_kernels`), fed by what the forward kept:
+
+- Where gradients are recorded the forward kernel has a second output, the
+  rows' log-sum-exp `m + log l` in float32, stored compact (B * H * Tq
+  floats: one (1, blk_q) row a query block; the 128-lane replication of
+  the scratch never leaves VMEM) and marked `RESIDUAL_NAME` like the
+  output, so a recorded CachedOp call's backward program is handed both
+  and runs no forward kernel.  Outside a recording the forward program has
+  the one output it always had.
+- `flash_bwd_dkv` (`_fa_dkv_kernel`): a (blk_k, D) key / value block a grid
+  step; the sequential grid axes run over the `group` query heads that
+  read it and over their (blk_q, D) blocks of q and dO, walked in
+  sub-tiles, and dK and dV are summed in float32 VMEM scratch over all of
+  them.  The scores are computed transposed (keys down the rows), so
+  every product is a plain or a b^T product and the statistics broadcast
+  as the rows they are stored as.
+- `flash_bwd_dq` (`_fa_dq_kernel`): the forward kernel's grid and loop
+  bounds with dS K summed in scratch.  Seven products for the five the
+  mathematics needs, no atomics.
+- Both follow the forward kernel's rules: operands reach the MXU in the
+  dtype they came in, P and dS are cast to it for the second products,
+  scores, exponentials, `delta = rowsum(dO * O)` (one XLA fusion outside
+  the kernels) and the accumulators are float32; blocks wholly above the
+  diagonal or wholly outside a window's band are neither fetched nor
+  computed, by position (`_key_ranges`, `_query_ranges`, clamped index
+  maps), and only blocks an edge crosses are masked; the tiles come from
+  the shapes under a VMEM budget of the backward's own (`_fa_bwd_tiles`).
+- Each `pallas_call` sits directly inside a `jax.named_scope` of its own
+  (`flash_bwd_dkv`, `flash_bwd_dq`): its device events carry that name and
+  not the layer's, so a reader of forward events never counts them.
+  `mxnet_flash_bwd_total{path}` counts the calls traced by path and
+  `mxnet_flash_bwd_tiles_total{kind}` the tiles visited and needed.
+- Shapes the tiles cannot cover, for which the forward answered with
+  `_dense_reference` and kept no statistics, take `_bwd_banded`, the plain
+  float32 pass in XLA that the tests hold the kernels against.
 """
 from __future__ import annotations
 
@@ -68,8 +96,142 @@ def _lanes(x, n):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
-def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-               scale, scale_q, causal, window, blk_q, blk_k, sub):
+def _count(n, sub, n_sub):
+    """How many whole `sub`-wide sub-tiles lie before position offset n,
+    kept inside [0, n_sub].  `lax.div`, not `//`, on traced integers: it
+    rounds toward zero, which below zero clamps to 0 all the same, and `//`
+    costs every lowering of a kernel a traced helper for each sign.  On
+    plain integers (the counters' books) it is the same number."""
+    if isinstance(n, int):
+        return min(max(n // sub, 0), n_sub)
+    return jnp.minimum(jnp.maximum(jax.lax.div(n, sub), 0), n_sub)
+
+
+def _clip(x, lo, hi):
+    if all(isinstance(a, int) for a in (x, lo, hi)):
+        return min(max(x, lo), hi)
+    return jnp.clip(x, lo, hi)
+
+
+def _key_ranges(first_q, first_k, blk_q, sub, n_sub, causal, window):
+    """[(lo, hi, masked)]: the runs of `sub`-wide key sub-tiles, counted
+    from column first_k, that a query block of blk_q rows from row first_q
+    meets, and whether the mask cuts them.  Under a causal mask: sub-tiles
+    wholly under the diagonal (last column <= first row) need no mask;
+    those the diagonal crosses are masked; those wholly above it (first
+    column > last row) are not visited.  Under a window those wholly left
+    of the band (last column <= first row - window) are not visited either,
+    and those its edge crosses (first column <= last row - window) are
+    masked.  The forward kernel, the dQ kernel and the counters' books
+    (plain integers) all run on these bounds."""
+    if not causal:
+        return [(0, n_sub, False)]
+    count = functools.partial(_count, sub=sub, n_sub=n_sub)
+    under = count(first_q - first_k + 1)
+    seen = count(first_q + blk_q - 1 - first_k + sub)
+    if window is None:
+        return [(0, under, False), (under, seen, True)]
+    # a row whose keys all lie further right meets only masked columns
+    # first, and what it summed over them is wiped when its first real
+    # score arrives (NEG_INF is finite: the correction is
+    # exp(-1e30 - m) = 0)
+    start = count(first_q - window + 1 - first_k)
+    inside = _clip(count(first_q + blk_q - 1 - window - first_k + sub),
+                   start, seen)
+    under = _clip(under, inside, seen)
+    return [(start, inside, True), (inside, under, False),
+            (under, seen, True)]
+
+
+def _query_ranges(first_k, first_q, blk_k, sub, n_sub, causal, window):
+    """[(lo, hi, masked)]: the runs of `sub`-tall query sub-tiles, counted
+    from row first_q, that a key block of blk_k columns from column first_k
+    is seen by: `_key_ranges` with the roles exchanged (the dK/dV kernel's
+    loop).  Sub-tiles wholly above the diagonal (last row < first column)
+    come first and are not visited; then those the diagonal crosses
+    (masked), those wholly under it and inside the band, those the band's
+    edge crosses (last row - first column >= window; masked), and those
+    wholly past it (first row - last column >= window; not visited)."""
+    if not causal:
+        return [(0, n_sub, False)]
+    count = functools.partial(_count, sub=sub, n_sub=n_sub)
+    ahead = first_k - first_q
+    lo = count(ahead)
+    under = count(ahead + blk_k - 1 + sub - 1)
+    if window is None:
+        under = _clip(under, lo, n_sub)
+        return [(lo, under, True), (under, n_sub, False)]
+    hi = _clip(count(ahead + window + blk_k - 1 + sub - 1), lo, n_sub)
+    under = _clip(under, lo, hi)
+    edge = _clip(count(ahead + window), under, hi)
+    return [(lo, under, True), (under, edge, False), (edge, hi, True)]
+
+
+def _seen(rows, cols, ahead, window, transposed=False):
+    """The mask of a (rows, cols) scores tile whose corner key lies `ahead`
+    positions past its corner query; `transposed`: keys down the rows."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    # query position - key position is behind - ahead
+    behind = col - row if transposed else row - col
+    seen = behind >= ahead
+    if window is not None:
+        seen &= behind < ahead + window
+    return seen
+
+
+def _row_of(x):
+    """A lane-replicated (rows, 128) value as one (1, rows) row: column j
+    of each 128-row piece is picked out on the diagonal and summed down the
+    sublanes, which Mosaic lowers without a transpose."""
+    pieces = []
+    for i in range(0, x.shape[0], 128):
+        n = min(128, x.shape[0] - i)
+        eye = jax.lax.broadcasted_iota(jnp.int32, (n, 128), 0) == \
+            jax.lax.broadcasted_iota(jnp.int32, (n, 128), 1)
+        pieces.append(jnp.sum(jnp.where(eye, x[i:i + n], 0.0), axis=0,
+                              keepdims=True)[:, :n])
+    return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=1)
+
+
+def _lse_row(m, l):
+    """The rows' log-sum-exp `m + log l` as a (1, rows) row, from the
+    lane-replicated (rows, 128) running max and sum.  Whole vregs of eight
+    rows, all 128-row pieces at once: row 8k + s of a piece is selected
+    into lane 8k + s of sublane s of the piece's (8, 128) value (m over
+    zeros, l over ones), so the logarithm runs over one vreg a piece and
+    not sixteen, every lane's other sublanes read 0 + log 1, and a sum down
+    the sublanes leaves the row.  The forward kernel pays this once a query
+    block: at 512 rows 2.3% of the call at T 2,048 and D 64, where
+    `_row_of` of the finished sum cost 3.9% (chip runs, PR 32).  The chain
+    is sixteen selects whatever the rows: a kernel's lowering, which every
+    process pays for every layer, grows with the operations traced."""
+    rows = m.shape[0]
+    if rows % 128:
+        return _row_of(m + jnp.log(l))
+    pieces = rows // 128
+    m, l = (a.reshape(pieces, 128, 128) for a in (m, l))
+    d = jax.lax.broadcasted_iota(jnp.int32, (pieces, 8, 128), 2) - \
+        jax.lax.broadcasted_iota(jnp.int32, (pieces, 8, 128), 1)
+    row_m = jnp.zeros((pieces, 8, 128), jnp.float32)
+    row_l = jnp.ones((pieces, 8, 128), jnp.float32)
+    for k in range(0, 128, 8):
+        here = d == k
+        row_m = jnp.where(here, m[:, k:k + 8], row_m)
+        row_l = jnp.where(here, l[:, k:k + 8], row_l)
+    row = jnp.sum(row_m + jnp.log(row_l), axis=1, keepdims=True)
+    return jnp.concatenate([row[i] for i in range(pieces)], axis=1)
+
+
+def _column_of(row):
+    """A (1, rows) row as a (rows, 128) lane-replicated value, the way back
+    from `_lse_row`."""
+    return jnp.broadcast_to(jnp.expand_dims(row[0], -1),
+                            (row.shape[1], 128))
+
+
+def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *refs, scale, scale_q, causal,
+               window, blk_q, blk_k, sub):
     """Grid (BH, nq, nk); nk is sequential — scratch carries the online
     softmax state across k steps, and within a step across the `sub`-wide
     sub-tiles of the (blk_k, D) key / value block.  The running max and
@@ -80,7 +242,14 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     q, k and v reach the MXU in the dtype they came in; scores, softmax
     state and the accumulator are float32.  `scale_q` says the scale may
     go on q (the product is exact or q is float32); else it goes on the
-    float32 scores."""
+    float32 scores.
+
+    `refs` is the scratch (acc, m, l), after a second output where the
+    call keeps the row statistics for the backward kernels: the rows'
+    log-sum-exp `m + log l` as one (1, blk_q) row, so that B*H*Tq floats
+    reach HBM and the 128-lane replication never leaves VMEM."""
+    lse_ref = refs[0] if len(refs) == 4 else None
+    acc_ref, m_ref, l_ref = refs[-3:]
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -109,15 +278,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         if not scale_q:
             s = s * scale
         if masked:
-            row = jax.lax.broadcasted_iota(jnp.int32, (blk_q, sub), 0)
-            col = jax.lax.broadcasted_iota(jnp.int32, (blk_q, sub), 1)
-            # query position - key position is behind - ahead
-            behind = row - col
-            ahead = first_k + c * sub - first_q
-            seen_ = behind >= ahead
-            if window is not None:
-                seen_ &= behind < ahead + window
-            s = jnp.where(seen_, s, NEG_INF)
+            s = jnp.where(_seen(blk_q, sub, first_k + c * sub - first_q,
+                                window), s, NEG_INF)
         m_prev, l_prev = m_ref[...], l_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - _lanes(m_new, sub))
@@ -129,48 +291,18 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
         m_ref[...] = m_new
 
-    def _over(lo, hi, masked):
-        jax.lax.fori_loop(lo, hi, lambda c, _: _update(c, masked), None)
-
-    if causal:
-        # sub-tiles wholly under the diagonal (last column <= first row)
-        # need no mask; those the diagonal crosses are masked; those
-        # wholly above it (first column > last row) are not visited, and a
-        # key block that holds no other is not fetched (`kv_index_map`).
-        # `lax.div`, not `//`: it rounds toward zero, which below zero
-        # clamps to 0 all the same, and `//` on traced integers costs
-        # every lowering of the kernel a traced helper for each sign
-        def count(n):
-            return jnp.minimum(jnp.maximum(jax.lax.div(n, sub), 0), n_sub)
-        under = count(first_q - first_k + 1)
-        seen = count(first_q + blk_q - 1 - first_k + sub)
-        if window is None:
-            _over(0, under, False)
-            _over(under, seen, True)
-        else:
-            # sub-tiles wholly left of the band (last column <= first row
-            # - window) are not visited; those the band's edge crosses
-            # (first column <= last row - window) are masked, and so are
-            # those on the diagonal; a row whose keys all lie further
-            # right meets only masked columns first, and what it summed
-            # over them is wiped when its first real score arrives
-            # (NEG_INF is finite: the correction is exp(-1e30 - m) = 0)
-            start = count(first_q - window + 1 - first_k)
-            inside = jnp.clip(
-                count(first_q + blk_q - 1 - window - first_k + sub),
-                start, seen)
-            under = jnp.clip(under, inside, seen)
-            _over(start, inside, True)
-            _over(inside, under, False)
-            _over(under, seen, True)
-    else:
-        _over(0, n_sub, False)
+    # a key block that holds no sub-tile to visit is not fetched
+    # (`kv_index_map`)
+    for lo, hi, masked in _key_ranges(first_q, first_k, blk_q, sub, n_sub,
+                                      causal, window):
+        jax.lax.fori_loop(lo, hi, lambda c, _, m=masked: _update(c, m), None)
 
     @pl.when(ki == nk - 1)
     def _finish():
-        o_ref[...] = (acc_ref[...] / _lanes(
-            jnp.maximum(l_ref[...], 1e-30), acc_ref.shape[1])
-                      ).astype(o_ref.dtype)
+        acc, l = acc_ref[...], jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc / _lanes(l, acc.shape[1])).astype(o_ref.dtype)
+        if lse_ref is not None:
+            lse_ref[...] = _lse_row(m_ref[...], l)
 
 
 def _dense_reference(q, k, v, scale, causal, window=None):
@@ -211,46 +343,50 @@ def _fa_vmem_bytes(blk_q, blk_k, sub, D, itemsize):
     return blocks + scratch + _fa_scores_bytes(blk_q, sub, itemsize)
 
 
-def _fa_tiles(Tq, Tk, D, dtype, budget=VMEM_BUDGET):
-    """(blk_q, blk_k, sub) for a call, or None where nothing fits: the
-    query tile and the key sub-tile that give the largest scores tile
-    within a third of the budget (the squarer on a tie: what a causal mask
-    wastes grows with the longer side), then the largest key / value block
-    of whole sub-tiles that the rest of the budget holds, the whole of Tk
-    where it can: k and v are then fetched once a head and no grid step is
-    spent above the diagonal.  Tiles divide Tq and Tk and are multiples of
-    128, or the whole length."""
+def _choose_tiles(T, T_walked, D, dtype, budget, scores_bytes, vmem_bytes):
+    """(blk, major, sub), or None where nothing fits: `blk` rows of T stay
+    a grid step; the other side's T_walked rows come in blocks of `major`
+    and are walked `sub` at a time.  blk and sub give the largest scores
+    tile within a third of the budget (the squarer on a tie: what a causal
+    mask wastes grows with the longer side); major is the largest block of
+    whole sub-tiles that the rest of the budget holds, the whole of
+    T_walked where it can: it is then fetched once a head and no grid step
+    is spent on what the mask empties.  Tiles divide their lengths and are
+    multiples of 128, or the whole length."""
     itemsize = jnp.dtype(dtype).itemsize
 
-    def sizes(T):
-        return [d for d in range(128, T, 128) if T % d == 0] + [T]
+    def sizes(n):
+        return [d for d in range(128, n, 128) if n % d == 0] + [n]
 
-    fits = [(bq * bs, -abs(bq - bs), bq, bs)
-            for bq in sizes(Tq) for bs in sizes(Tk)
-            if _fa_scores_bytes(bq, bs, itemsize) <= budget // 3]
+    fits = [(b * s, -abs(b - s), b, s)
+            for b in sizes(T) for s in sizes(T_walked)
+            if scores_bytes(b, s, itemsize) <= budget // 3]
     if not fits:
         return None
-    _, _, blk_q, sub = max(fits)
-    blk_k = max([d for d in range(sub, Tk + 1, sub) if Tk % d == 0 and
-                 _fa_vmem_bytes(blk_q, d, sub, D, itemsize) <= budget],
+    _, _, blk, sub = max(fits)
+    major = max([d for d in range(sub, T_walked + 1, sub)
+                 if T_walked % d == 0 and
+                 vmem_bytes(blk, d, sub, D, itemsize) <= budget],
                 default=sub)
-    return blk_q, blk_k, sub
+    return blk, major, sub
+
+
+def _fa_tiles(Tq, Tk, D, dtype, budget=VMEM_BUDGET):
+    """(blk_q, blk_k, sub) of the forward kernel for a call, or None: a
+    query tile, the key / value block and its sub-tile."""
+    return _choose_tiles(Tq, Tk, D, dtype, budget, _fa_scores_bytes,
+                         _fa_vmem_bytes)
 
 
 def _fa_blocks(Tq, Tk, blk_q, sub, causal, window=None):
     """(grid, computed): the (query tile, key sub-tile) pairs of one head
-    and those the kernel visits: under a causal mask none above the
-    diagonal, under a window none wholly left of the band either (the
-    kernel's own loop bounds, in plain integers)."""
+    and those the forward kernel (and the dQ kernel) visits: under a causal
+    mask none above the diagonal, under a window none wholly left of the
+    band either (`_key_ranges`, the kernels' own loop bounds, on plain
+    integers)."""
     nq, nk = Tq // blk_q, Tk // sub
-    if not causal:
-        return nq * nk, nq * nk
-    visited = 0
-    for i in range(nq):
-        seen = min(nk, ((i + 1) * blk_q - 1) // sub + 1)
-        start = 0 if window is None else \
-            min(max((i * blk_q - window + 1) // sub, 0), seen)
-        visited += seen - start
+    visited = sum(hi - lo for i in range(nq) for lo, hi, _ in _key_ranges(
+        i * blk_q, 0, blk_q, sub, nk, causal, window))
     return nq * nk, visited
 
 
@@ -272,13 +408,12 @@ def _fa_needed(Tq, Tk, blk_q, sub, causal, window=None):
     return needed
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_attention(q, k, v, scale, causal, blk_q=None, blk_k=None,
-                     window=None):
-    """The forward kernel.  q (B, H, Tq, D); k, v (B, Hkv, Tk, D) with H a
-    multiple of Hkv.  blk_q / blk_k None: `_fa_tiles` chooses from the
-    shapes; given, they are the scores tile and the key block both.
-    window W (with `causal`): query t sees keys t - W < s <= t."""
+def _fa_call(q, k, v, scale, causal, blk_q, blk_k, window, stats):
+    """(o, lse): the forward kernel over q (B, H, Tq, D) and k, v (B, Hkv,
+    Tk, D).  `stats`: a second output, the rows' log-sum-exp (B * H, 1, Tq)
+    in float32, for the backward kernels; without it the program is the
+    forward-only one.  lse is None without `stats` and where the shapes
+    cannot be tiled (the dense reference answers)."""
     B, H, Tq, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
     if H % Hkv or v.shape[1] != Hkv:
@@ -295,7 +430,7 @@ def _flash_attention(q, k, v, scale, causal, blk_q=None, blk_k=None,
     if tiles is None:
         # shapes the blocking cannot tile (not an escape from compile
         # trouble: on TPU the kernel below compiles or raises)
-        return _dense_reference(q, k, v, scale, causal, window)
+        return _dense_reference(q, k, v, scale, causal, window), None
     blk_q, blk_k, sub = tiles
     from jax.experimental.pallas import tpu as pltpu
     from ..observability import metrics as _metrics
@@ -308,25 +443,19 @@ def _flash_attention(q, k, v, scale, causal, blk_q=None, blk_k=None,
         kind="needed")
     _metrics.FLASH_FWD_TILE.set(blk_q, dim="q")
     _metrics.FLASH_FWD_TILE.set(sub, dim="k")
-    # the scale goes on q where that is exact (a power of two) or float32
-    # arithmetic already; else on the float32 scores
-    scale_q = q.dtype == jnp.float32 or math.frexp(scale)[0] == 0.5
-    kernel = functools.partial(_fa_kernel, scale=scale, scale_q=scale_q,
+    kernel = functools.partial(_fa_kernel, scale=scale,
+                               scale_q=_scale_on_q(q.dtype, scale),
                                causal=causal, window=window, blk_q=blk_q,
                                blk_k=blk_k, sub=sub)
-
-    def kv_index_map(b, i, j):
-        if causal:
-            # past the last key block that holds a position this query
-            # block may see the map stays on that block, and the pipeline
-            # issues no copy for a block it already holds
-            j = jnp.minimum(j, jax.lax.div((i + 1) * blk_q - 1, blk_k))
-        if window is not None:  # nor for one wholly left of the band
-            j = jnp.maximum(j, jax.lax.div(
-                jnp.maximum(i * blk_q - window + 1, 0), blk_k))
-        if group > 1:  # the group's query heads read one key / value head
-            b = jax.lax.div(b, group)
-        return b, j, 0
+    kv_index_map = _kv_index_map(blk_q, blk_k, group, causal, window)
+    q_spec = pl.BlockSpec((None, blk_q, D), lambda b, i, j: (b, i, 0))
+    out_specs, out_shape = q_spec, jax.ShapeDtypeStruct((B * H, Tq, D),
+                                                        q.dtype)
+    if stats:
+        out_specs = [out_specs, pl.BlockSpec((None, 1, blk_q),
+                                             lambda b, i, j: (b, 0, i))]
+        out_shape = [out_shape,
+                     jax.ShapeDtypeStruct((B * H, 1, Tq), jnp.float32)]
     # mxnet_tpu runs with jax_enable_x64 on, under which Python scalars
     # and the index maps' literals trace as f64/i64; Mosaic has neither,
     # so the kernel is traced with 32-bit defaults
@@ -335,13 +464,12 @@ def _flash_attention(q, k, v, scale, causal, blk_q=None, blk_k=None,
             kernel,
             grid=(B * H, Tq // blk_q, Tk // blk_k),
             in_specs=[
-                pl.BlockSpec((None, blk_q, D), lambda b, i, j: (b, i, 0)),
+                q_spec,
                 pl.BlockSpec((None, blk_k, D), kv_index_map),
                 pl.BlockSpec((None, blk_k, D), kv_index_map),
             ],
-            out_specs=pl.BlockSpec((None, blk_q, D),
-                                   lambda b, i, j: (b, i, 0)),
-            out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
+            out_specs=out_specs,
+            out_shape=out_shape,
             scratch_shapes=[
                 pltpu.VMEM((blk_q, D), jnp.float32),    # acc
                 pltpu.VMEM((blk_q, 128), jnp.float32),  # running max
@@ -354,51 +482,347 @@ def _flash_attention(q, k, v, scale, causal, blk_q=None, blk_k=None,
             interpret=jax.default_backend() == "cpu",
         )(q.reshape(B * H, Tq, D), k.reshape(B * Hkv, Tk, D),
           v.reshape(B * Hkv, Tk, D))
-    return out.reshape(B, H, Tq, D)
+    o, lse = out if stats else (out, None)
+    return o.reshape(B, H, Tq, D), lse
+
+
+def _scale_on_q(dtype, scale):
+    """The scale goes on q where that is exact (a power of two) or float32
+    arithmetic already; else on the float32 scores."""
+    return dtype == jnp.float32 or math.frexp(scale)[0] == 0.5
+
+
+def _kv_index_map(blk_q, blk_k, group, causal, window):
+    """The key / value block of grid step (b, i, j) over (query heads, query
+    blocks, key blocks), forward and dQ kernels alike."""
+    def kv_index_map(b, i, j):
+        if causal:
+            # past the last key block that holds a position this query
+            # block may see the map stays on that block, and the pipeline
+            # issues no copy for a block it already holds
+            j = jnp.minimum(j, jax.lax.div((i + 1) * blk_q - 1, blk_k))
+        if window is not None:  # nor for one wholly left of the band
+            j = jnp.maximum(j, jax.lax.div(
+                jnp.maximum(i * blk_q - window + 1, 0), blk_k))
+        if group > 1:  # the group's query heads read one key / value head
+            b = jax.lax.div(b, group)
+        return b, j, 0
+    return kv_index_map
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention(q, k, v, scale, causal, blk_q=None, blk_k=None,
+                     window=None):
+    """The forward kernel.  q (B, H, Tq, D); k, v (B, Hkv, Tk, D) with H a
+    multiple of Hkv.  blk_q / blk_k None: `_fa_tiles` chooses from the
+    shapes; given, they are the scores tile and the key block both, and the
+    backward kernels' tiles too.  window W (with `causal`): query t sees
+    keys t - W < s <= t."""
+    return _fa_call(q, k, v, scale, causal, blk_q, blk_k, window, False)[0]
 
 
 def _fa_fwd(q, k, v, scale, causal, blk_q, blk_k, window):
-    o = _flash_attention(q, k, v, scale, causal, blk_q, blk_k, window)
-    # without the mark a recorded CachedOp call's backward program would run
-    # the kernel again for `o` (registry.RESIDUAL_NAME)
+    o, lse = _fa_call(q, k, v, scale, causal, blk_q, blk_k, window, True)
+    # without the marks a recorded CachedOp call's backward program would
+    # run the kernel again for `o` and the statistics
+    # (registry.RESIDUAL_NAME)
     o = checkpoint_name(o, RESIDUAL_NAME)
-    return o, (q, k, v, o)
+    if lse is not None:
+        lse = checkpoint_name(lse, RESIDUAL_NAME)
+    return o, (q, k, v, o, lse)
 
 
-# The backward pass's per-block key and value gradients, stacked over the
-# query blocks before they are summed, are B*H x (Tq / blk) x Tk x D floats
-# each.  Past this many bytes a stack is not built: the blocks' gradients
-# are summed in the loop's carry instead.  At B*H 64, T 2048, D 64 a stack
-# is 0.5 GiB and that program stays as it was; at B*H 40, D 256 it would be
-# 1.25 GiB twice over, 2.9 GB of a backward program's temporaries (4.62 GB
-# stacked, 1.65 GB carried: v5e compiles, PR 27).
-STACK_BYTES_MAX = 2 ** 30
-# The backward pass's query block: its own, whatever tiles the forward
-# kernel chose, so its loops and their score temporaries stay as they were.
+# What one grid step of a backward kernel may hold in VMEM by
+# `_fa_bwd_vmem_bytes`, and what the kernels ask Mosaic for (the v5e has
+# 128 MiB; 16 are granted without asking).
+BWD_VMEM_BUDGET = 24 * 2 ** 20
+BWD_VMEM_LIMIT = 48 * 2 ** 20
+# The plain float32 pass's query block.
 BWD_BLOCK = 128
 
 
+def _fa_bwd_scores_bytes(blk, sub, itemsize):
+    """A backward scores tile: scores, exponentials, dO V^T and dS in
+    float32, and P and dS again in the operands' dtype for the second
+    products."""
+    return blk * sub * (4 * 4 + 2 * itemsize)
+
+
+def _fa_bwd_vmem_bytes(blk, major, sub, D, itemsize):
+    """VMEM of one grid step of a backward kernel: the blocks that stay for
+    a tile (two in, two out at the most: k, v, dK, dV) and the two that are
+    walked in sub-tiles (q and dO, or k and v), each twice for the
+    pipeline's two buffers; the float32 accumulators and statistics; the
+    scores tile."""
+    blocks = 2 * (4 * blk + 2 * major) * D * itemsize
+    scratch = 2 * blk * (D + 128) * 4 + 2 * 2 * 8 * major * 4
+    return blocks + scratch + _fa_bwd_scores_bytes(blk, sub, itemsize)
+
+
+def _fa_bwd_tiles(T, T_walked, D, dtype, budget=BWD_VMEM_BUDGET):
+    """(blk, major, sub) of one backward kernel, or None: query rows against
+    walked keys in the dQ kernel, keys against walked query rows in the
+    dK/dV kernel."""
+    return _choose_tiles(T, T_walked, D, dtype, budget,
+                         _fa_bwd_scores_bytes, _fa_bwd_vmem_bytes)
+
+
+def _fa_bwd_visited(Tq, Tk, dq_tiles, dkv_tiles, causal, window=None):
+    """(visited, needed): the scores tiles of one query head that the two
+    backward kernels' loops run (the kernels' own bounds, on plain
+    integers) and those in which the mask leaves a query a key, counted
+    from the mask."""
+    blk_q, _, sub_k = dq_tiles
+    blk_k, major_q, sub_q = dkv_tiles
+    visited = _fa_blocks(Tq, Tk, blk_q, sub_k, causal, window)[1] + sum(
+        hi - lo for first_k in range(0, Tk, blk_k)
+        for first_q in range(0, Tq, major_q)
+        for lo, hi, _ in _query_ranges(first_k, first_q, blk_k, sub_q,
+                                       major_q // sub_q, causal, window))
+    return visited, _fa_needed(Tq, Tk, blk_q, sub_k, causal, window) \
+        + _fa_needed(Tq, Tk, sub_q, blk_k, causal, window)
+
+
+def _fa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                  acc_ref, lse_col, delta_col, *, scale, scale_q, causal,
+                  window, blk_q, blk_k, sub):
+    """dQ of one (blk_q, D) query block.  Grid (BH, nq, nk), the forward
+    kernel's, with nk sequential: the key / value block (blk_k, D) is
+    walked in `sub`-wide sub-tiles on the forward kernel's own bounds, and
+    dS K is summed in float32 scratch.  The rows' statistics come as
+    (1, blk_q) rows and are turned into lane-replicated columns once a
+    query block."""
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+    n_sub = blk_k // sub
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        lse_col[...] = _column_of(lse_ref[...])
+        delta_col[...] = _column_of(delta_ref[...])
+
+    first_q, first_k = qi * blk_q, ki * blk_k
+    q = q_ref[...]
+    if scale_q:
+        q = q * scale
+    do = do_ref[...]
+    nt = (((1,), (1,)), ((), ()))    # a @ b^T, no transpose
+
+    def _update(c, masked):
+        rows = slice(None) if n_sub == 1 else \
+            pl.ds(pl.multiple_of(c * sub, sub), sub)
+        k, v = k_ref[rows, :], v_ref[rows, :]
+        s = jax.lax.dot_general(q, k, nt, preferred_element_type=jnp.float32)
+        if not scale_q:
+            s = s * scale
+        if masked:
+            s = jnp.where(_seen(blk_q, sub, first_k + c * sub - first_q,
+                                window), s, NEG_INF)
+        p = jnp.exp(s - _lanes(lse_col[...], sub))
+        dp = jax.lax.dot_general(do, v, nt,
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - _lanes(delta_col[...], sub))
+        acc_ref[...] += jnp.dot(ds.astype(k.dtype), k,
+                                preferred_element_type=jnp.float32)
+
+    for lo, hi, masked in _key_ranges(first_q, first_k, blk_q, sub, n_sub,
+                                      causal, window):
+        jax.lax.fori_loop(lo, hi, lambda c, _, m=masked: _update(c, m), None)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finish():
+        dq_ref[...] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+
+
+def _fa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                   dv_ref, dk_acc, dv_acc, *, scale, scale_q, causal, window,
+                   blk_k, blk_q, sub):
+    """dK and dV of one (blk_k, D) key / value block.  Grid (B * Hkv, nk,
+    group, nq) with the last two sequential: the `group` query heads that
+    read this key / value head, and each head's (blk_q, D) blocks of q and
+    dO, walked in `sub`-tall sub-tiles; both gradients are summed in
+    float32 scratch over all of them, so a group's sum never reaches HBM.
+    The scores are computed TRANSPOSED, keys down the rows (k q^T, v dO^T):
+    P^T dO and dS^T q are then plain products, and the rows' statistics
+    broadcast down the sublanes as the (1, sub) rows they are stored as."""
+    ki = pl.program_id(1)
+    g = pl.program_id(2)
+    qi = pl.program_id(3)
+    n_sub = blk_q // sub
+
+    @pl.when((g == 0) & (qi == 0))
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    first_k, first_q = ki * blk_k, qi * blk_q
+    k, v = k_ref[...], v_ref[...]
+    nt = (((1,), (1,)), ((), ()))
+
+    def _update(c, masked):
+        if n_sub == 1:
+            rows = cols = slice(None)
+        else:
+            rows = cols = pl.ds(pl.multiple_of(c * sub, sub), sub)
+        q, do = q_ref[rows, :], do_ref[rows, :]
+        s = jax.lax.dot_general(k, q * scale if scale_q else q, nt,
+                                preferred_element_type=jnp.float32)
+        if not scale_q:
+            s = s * scale
+        if masked:
+            s = jnp.where(_seen(blk_k, sub, first_k - first_q - c * sub,
+                                window, transposed=True), s, NEG_INF)
+        p = jnp.exp(s - lse_ref[:, cols])                    # (blk_k, sub)
+        dv_acc[...] += jnp.dot(p.astype(do.dtype), do,
+                               preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v, do, nt,
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[:, cols])
+        dk_acc[...] += jnp.dot(ds.astype(q.dtype), q,
+                               preferred_element_type=jnp.float32)
+
+    for lo, hi, masked in _query_ranges(first_k, first_q, blk_k, sub, n_sub,
+                                        causal, window):
+        jax.lax.fori_loop(lo, hi, lambda c, _, m=masked: _update(c, m), None)
+
+    @pl.when((g == pl.num_programs(2) - 1) & (qi == pl.num_programs(3) - 1))
+    def _finish():
+        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+# Both backward calls are `jit`s of their own: the layers of a model that
+# make the same call share one trace and one lowering of each kernel (a
+# called function in the step's program), where every layer's own copy
+# cost the start of every process a kernel lowering more.
+_BWD_STATIC = dict(static_argnums=(0, 1),
+                   static_argnames=("scale", "scale_q", "causal", "window"))
+
+
+@functools.partial(jax.jit, **_BWD_STATIC)
+def _dkv_call(tiles, group, q, k, v, do, lse, delta, **how):
+    """dK, dV (B * Hkv, Tk, D) from q, dO (B * H, Tq, D), k, v and the
+    rows' statistics (B * H, 1, Tq)."""
+    from jax.experimental.pallas import tpu as pltpu
+    blk_k, blk_q, sub = tiles
+    Tq, (N, Tk, D) = q.shape[1], k.shape
+    causal, window = how["causal"], how["window"]
+
+    def q_block(j, i):
+        if causal:  # query blocks wholly above the diagonal are not fetched
+            i = jnp.maximum(i, jax.lax.div(j * blk_k, blk_q))
+        if window is not None:  # nor those wholly past the band
+            i = jnp.minimum(i, jax.lax.div(
+                (j + 1) * blk_k - 1 + window - 1, blk_q))
+        return i
+    rows_spec = pl.BlockSpec(
+        (None, blk_q, D), lambda n, j, h, i: (n * group + h, q_block(j, i), 0))
+    stat_spec = pl.BlockSpec(
+        (None, 1, blk_q), lambda n, j, h, i: (n * group + h, 0, q_block(j, i)))
+    kv_spec = pl.BlockSpec((None, blk_k, D), lambda n, j, h, i: (n, j, 0))
+    with jax.enable_x64(False), jax.named_scope("flash_bwd_dkv"):
+        return pl.pallas_call(
+            functools.partial(_fa_dkv_kernel, blk_k=blk_k, blk_q=blk_q,
+                              sub=sub, **how),
+            grid=(N, Tk // blk_k, group, Tq // blk_q),
+            in_specs=[rows_spec, kv_spec, kv_spec, rows_spec, stat_spec,
+                      stat_spec],
+            out_specs=[kv_spec, kv_spec],
+            out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                       jax.ShapeDtypeStruct(v.shape, v.dtype)],
+            scratch_shapes=[pltpu.VMEM((blk_k, D), jnp.float32),
+                            pltpu.VMEM((blk_k, D), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary",
+                                     "arbitrary"),
+                vmem_limit_bytes=BWD_VMEM_LIMIT),
+            interpret=jax.default_backend() == "cpu",
+        )(q, k, v, do, lse, delta)
+
+
+@functools.partial(jax.jit, **_BWD_STATIC)
+def _dq_call(tiles, group, q, k, v, do, lse, delta, **how):
+    """dQ (B * H, Tq, D), from the same."""
+    from jax.experimental.pallas import tpu as pltpu
+    blk_q, blk_k, sub = tiles
+    (BH, Tq, D), Tk = q.shape, k.shape[1]
+    rows_spec = pl.BlockSpec((None, blk_q, D), lambda b, i, j: (b, i, 0))
+    stat_spec = pl.BlockSpec((None, 1, blk_q), lambda b, i, j: (b, 0, i))
+    kv_spec = pl.BlockSpec((None, blk_k, D), _kv_index_map(
+        blk_q, blk_k, group, how["causal"], how["window"]))
+    with jax.enable_x64(False), jax.named_scope("flash_bwd_dq"):
+        return pl.pallas_call(
+            functools.partial(_fa_dq_kernel, blk_q=blk_q, blk_k=blk_k,
+                              sub=sub, **how),
+            grid=(BH, Tq // blk_q, Tk // blk_k),
+            in_specs=[rows_spec, kv_spec, kv_spec, rows_spec, stat_spec,
+                      stat_spec],
+            out_specs=rows_spec,
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32),
+                            pltpu.VMEM((blk_q, 128), jnp.float32),
+                            pltpu.VMEM((blk_q, 128), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=BWD_VMEM_LIMIT),
+            interpret=jax.default_backend() == "cpu",
+        )(q, k, v, do, lse, delta)
+
+
+def _bwd_kernels(scale, causal, window, dq_tiles, dkv_tiles, res, g):
+    """The backward pass as two Pallas kernels (seven products for the five
+    required, no atomics): dK and dV by key block, dQ by query block, each
+    `pallas_call` directly inside a `jax.named_scope` of its own, so that
+    its device events carry that name and not the layer's."""
+    q, k, v, o, lse = res
+    B, H, Tq, D = q.shape
+    Hkv, Tk = k.shape[1], k.shape[2]
+    delta = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32), axis=-1
+                    ).reshape(B * H, 1, Tq)
+    args = (H // Hkv, q.reshape(B * H, Tq, D), k.reshape(B * Hkv, Tk, D),
+            v.reshape(B * Hkv, Tk, D), g.reshape(B * H, Tq, D), lse, delta)
+    how = dict(scale=scale, scale_q=_scale_on_q(q.dtype, scale),
+               causal=causal, window=window)
+    dk, dv = _dkv_call(dkv_tiles, *args, **how)
+    dq = _dq_call(dq_tiles, *args, **how)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
 def _fa_bwd(scale, causal, blk_q, blk_k, window, res, g):
-    """Blockwise recompute backward: a loop over q blocks keeps peak
-    score memory at O(blk_q · Tk) per (batch, head).
+    """The backward pass, from the forward's residuals (q, k, v, o and the
+    rows' log-sum-exp L) and the output's gradient.
 
     Flash backward identities (FlashAttention paper, §B):
-      P = softmax(S);  D_i = rowsum(dO ∘ O)
+      P = exp(S - L);  D_i = rowsum(dO ∘ O)
       dV = Pᵀ dO;  dS = P ∘ (dO Vᵀ − D_i);  dQ = dS K · scale;  dK = dSᵀ Q · scale
 
-    PROVISIONAL dispatch (PR 31): one head count and no window take
-    `_bwd_whole_keys`, the pass the accepted cells were measured on, whose
-    program text a test pins; everything else takes `_bwd_banded`.  With
-    group 1 and no window `_bwd_banded` is `_bwd_whole_keys`' carried-sums
-    path written once more, so three paths (stacked, carried, banded) do one
-    thing.  ROADMAP S6's first step measures the accepted cells under
-    `_bwd_banded` and keeps one path; until then a change to the backward
-    pass is measured on all three.
-    """
-    if res[0].shape[1] == res[1].shape[1] and window is None:
-        return _bwd_whole_keys(scale, causal, res, g)
-    with jax.named_scope("flash_attention_bwd"):
-        return _bwd_banded(scale, causal, window, res, g)
+    One path: `_bwd_kernels`, whose tiles depend on what the call shows
+    (Tq, Tk, D, dtype; the caller's blk_q / blk_k where the forward got
+    them).  Shapes the tiles cannot cover, for which the forward kept no
+    statistics either, take the plain float32 pass `_bwd_banded`, as the
+    forward takes `_dense_reference`; `mxnet_flash_bwd_total{path}` counts
+    both."""
+    from ..observability import metrics as _metrics
+    q, k, v, o, lse = res
+    B, H, Tq, D = q.shape
+    Tk = k.shape[2]
+    dq_tiles = dkv_tiles = None
+    if lse is not None and blk_q is not None and blk_k is not None:
+        dq_tiles, dkv_tiles = (blk_q, blk_k, blk_k), (blk_k, blk_q, blk_q)
+    elif lse is not None:
+        dq_tiles = _fa_bwd_tiles(Tq, Tk, D, q.dtype)
+        dkv_tiles = _fa_bwd_tiles(Tk, Tq, D, q.dtype)
+    if dq_tiles is None or dkv_tiles is None:
+        _metrics.FLASH_BWD.inc(path="reference")
+        with jax.named_scope("flash_attention_bwd"):
+            return _bwd_banded(scale, causal, window, (q, k, v, o), g)
+    _metrics.FLASH_BWD.inc(path="kernel")
+    visited, needed = _fa_bwd_visited(Tq, Tk, dq_tiles, dkv_tiles, causal,
+                                      window)
+    _metrics.FLASH_BWD_TILES.inc(B * H * visited, kind="visited")
+    _metrics.FLASH_BWD_TILES.inc(B * H * needed, kind="needed")
+    return _bwd_kernels(scale, causal, window, dq_tiles, dkv_tiles, res, g)
 
 
 def _bwd_banded(scale, causal, window, res, g):
@@ -462,63 +886,6 @@ def _bwd_banded(scale, causal, window, res, g):
         q_block, (jnp.zeros_like(kf), jnp.zeros_like(vf)), jnp.arange(nq))
     # (nq, N, group, blk, D) -> (N, group, Tq, D)
     dq = dqs.transpose(1, 2, 0, 3, 4)
-    return (dq.reshape(q.shape).astype(q.dtype),
-            dk.reshape(k.shape).astype(k.dtype),
-            dv.reshape(v.shape).astype(v.dtype))
-
-
-def _bwd_whole_keys(scale, causal, res, g):
-    """The backward pass for one head count and no window: every query
-    block against all the keys, a (batch, head) at a time."""
-    q, k, v, o = res
-    B, H, Tq, D = q.shape
-    Tk = k.shape[2]
-    blk = BWD_BLOCK if Tq % BWD_BLOCK == 0 else Tq
-    nq = Tq // blk
-    carry_kv = B * H * nq * Tk * D * 4 > STACK_BYTES_MAX
-
-    qf = q.astype(jnp.float32)
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    of = o.astype(jnp.float32)
-    gf = g.astype(jnp.float32)
-
-    def per_head(q1, k1, v1, o1, g1):
-        # (Tq,D),(Tk,D),... for one (batch,head)
-        delta = jnp.sum(g1 * o1, axis=-1)                     # (Tq,)
-
-        def q_block(i):
-            qs = jax.lax.dynamic_slice_in_dim(q1, i * blk, blk)
-            gs = jax.lax.dynamic_slice_in_dim(g1, i * blk, blk)
-            ds = jax.lax.dynamic_slice_in_dim(delta, i * blk, blk)
-            s = qs @ k1.T * scale                             # (blk, Tk)
-            if causal:
-                q_pos = i * blk + jnp.arange(blk)
-                mask = q_pos[:, None] >= jnp.arange(Tk)[None, :]
-                s = jnp.where(mask, s, NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            dp = gs @ v1.T                                    # (blk, Tk)
-            dsoft = p * (dp - ds[:, None])
-            dq = dsoft @ k1 * scale                           # (blk, D)
-            dk = dsoft.T @ qs * scale                         # (Tk, D)
-            dv = p.T @ gs                                     # (Tk, D)
-            return dq, dk, dv
-
-        if carry_kv:
-            def step(acc, i):
-                dq, dk, dv = q_block(i)
-                return (acc[0] + dk, acc[1] + dv), dq
-
-            (dk, dv), dqs = jax.lax.scan(
-                step, (jnp.zeros_like(k1), jnp.zeros_like(v1)),
-                jnp.arange(nq))
-            return dqs.reshape(Tq, D), dk, dv
-        dqs, dks, dvs = jax.lax.map(q_block, jnp.arange(nq))
-        return dqs.reshape(Tq, D), dks.sum(0), dvs.sum(0)
-
-    flat = lambda a: a.reshape(B * H, a.shape[2], a.shape[3])
-    dq, dk, dv = jax.vmap(per_head)(flat(qf), flat(kf), flat(vf),
-                                    flat(of), flat(gf))
     return (dq.reshape(q.shape).astype(q.dtype),
             dk.reshape(k.shape).astype(k.dtype),
             dv.reshape(v.shape).astype(v.dtype))

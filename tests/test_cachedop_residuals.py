@@ -25,10 +25,12 @@ from mxnet_tpu.gluon.model_zoo.decoder import DecoderLM
 from mxnet_tpu.gluon.model_zoo.transformer import TransformerLM
 from mxnet_tpu.observability import metrics
 
+from test_flash_attention import _kernel_eqns
+
 NETS = ("convnet", "transformer", "decoder")
 PRODUCTS = ("dot_general", "conv_general_dilated", "ragged_dot_general")
-# the attention backward's own products sit in its loops (lax.map); the
-# kernel's in its body: neither is a forward product of the graph
+# a kernel's own products sit in its body (the forward's and the attention
+# backward's alike): none is a forward product of the graph
 OPAQUE = ("scan", "while", "pallas_call")
 
 
@@ -143,11 +145,40 @@ def test_backward_program_runs_no_forward_product(case, fixed_key):
     # dx and dW of every product, and no third: the forward is not there
     assert sum(bwd[p] for p in PRODUCTS) == 2 * n_fwd, (fwd, bwd)
     assert bwd["ragged_dot_general"] == 2 * fwd["ragged_dot_general"]
-    assert bwd["pallas_call"] == 0
+    # the attention backward's two kernels a layer, and no forward kernel:
+    # every `pallas_call` of the backward program sits under `flash_bwd_*`
+    assert bwd["pallas_call"] == 2 * fwd["pallas_call"]
     if which != "convnet":
         assert fwd["pallas_call"] == {"transformer": 2, "decoder": 3}[which]
-        assert "tpu_custom_call" not in op._bwd.lower(
-            pull, (args, aux, fixed_key), cots).as_text()
+
+
+@pytest.mark.parametrize("which", ["transformer", "decoder"])
+def test_attention_backward_is_kernels_fed_by_kept_statistics(
+        which, fixed_key):
+    """The recorded forward program hands the rows' log-sum-exp of every
+    attention layer to the backward program (B * H, 1, T floats a layer,
+    compact), and the backward program's attention is `pallas_call`s under
+    the `flash_bwd_*` scopes: no `scan` or `while` of plain XLA is left."""
+    net, x = _build(which)
+    op = net._cached_op
+    args, aux = _program_inputs(net, x)
+    layers = {"transformer": 2, "decoder": 3}[which]
+    heads, T = 2, x.shape[1]
+    outs, new_aux, kept, _refs = _pullback_nodes(op, args, aux, fixed_key)
+    stats = [n for n in kept if n.shape == (x.shape[0] * heads, 1, T)]
+    assert len(stats) == layers and all(n.dtype == jnp.float32 for n in stats)
+    _o, _a, pull = op._fwd(args, aux, fixed_key, True, True)
+    cots = (tuple(jnp.ones_like(o) for o in outs),
+            {k: jnp.zeros_like(v) for k, v in new_aux.items()})
+    jaxpr = jax.make_jaxpr(op._bwd.__wrapped__)(
+        pull, (args, aux, fixed_key), cots).jaxpr
+    bwd = _count(jaxpr)
+    assert bwd["scan"] == bwd["while"] == 0, bwd
+    scopes = collections.Counter(
+        str(e.source_info.name_stack).rsplit("/", 1)[-1]
+        for e in _kernel_eqns(jaxpr) if e.primitive.name == "pallas_call")
+    assert scopes == {"flash_bwd_dkv": layers, "flash_bwd_dq": layers}
+    assert metrics.FLASH_BWD.get(path="kernel") >= layers
 
 
 def test_retained_graph_gives_the_same_gradients_again(case, fixed_key):

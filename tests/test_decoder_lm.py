@@ -322,52 +322,65 @@ def test_tile_counter_adds_up_over_the_layers_of_the_new_cell():
 
 
 def _loops(jaxpr):
-    return [e for e in _eqns(jaxpr) if e.primitive.name in ("scan", "while")]
+    """Loops of plain XLA: not those inside a kernel's body."""
+    return [e for e in jaxpr.eqns if e.primitive.name in ("scan", "while")]
 
 
-@pytest.mark.parametrize("shape, carried", [((2, 32, 2048, 64), False),
-                                            ((2, 20, 2048, 256), True)],
-                         ids=["opt1.3b", "glm4.7flash"])
-def test_accepted_cells_keep_their_tiles_and_their_backward(shape, carried):
-    """What `opt1.3b_train_gluon` and `glm4.7flash_train_gluon` send: the
-    tiles PR 28 chose, one head count and no window, so the backward pass is
-    `_bwd_whole_keys` as it was: one loop over 16 query blocks of 128
-    against all 2,048 keys, the key and value gradients stacked (OPT) or
-    carried (the expert cell), and never a slice of the keys."""
+CELL_CALLS = {  # (B, H, Hkv, T, D), the layers' windows
+    "opt1.3b": ((2, 32, 32, 2048, 64), (None,)),
+    "glm4.7flash": ((2, 20, 20, 2048, 256), (None,)),
+    "smallthinker21b": ((2, 28, 4, 8192, 128), (None, 4096)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_CALLS))
+def test_cells_backward_is_two_kernels_on_the_needed_tiles(cell):
+    """What the three token cells send (traced, nothing runs): the tiles
+    PR 28 chose for the forward, a backward pass of two `pallas_call`s and
+    no loop of plain XLA, each under a scope of its own that no forward
+    reader matches, and kernels that visit exactly the tiles their masks
+    leave something of."""
     from mxnet_tpu.ops import flash_attention as fa
-    assert fa._fa_tiles(2048, 2048, shape[-1], jnp.bfloat16) == \
-        (512, 2048, 512)
-    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
-    jaxpr = jax.make_jaxpr(lambda q, k, v, o, g: fa._fa_bwd(
-        shape[-1] ** -0.5, True, None, None, None, (q, k, v, o), g))(
-            x, x, x, x, x).jaxpr
-    loops = _loops(jaxpr)
-    assert len(loops) == 1 and loops[0].params["length"] == 16
-    assert (loops[0].params["num_carry"] == 2) is carried
-    names = {e.primitive.name for e in _eqns(jaxpr)}
-    assert "dynamic_update_slice" not in names
-    assert not any("flash_attention_bwd" in str(e.source_info.name_stack)
-                   for e in _eqns(jaxpr))
-    # the new cell's calls take the other path: a band of 4,224 keys
-    q = jax.ShapeDtypeStruct((2, 28, 8192, 128), jnp.bfloat16)
-    kv = jax.ShapeDtypeStruct((2, 4, 8192, 128), jnp.bfloat16)
-    banded = jax.make_jaxpr(lambda q_, k, v, o, g: fa._fa_bwd(
-        128 ** -0.5, True, None, None, 4096, (q_, k, v, o), g))(
-            q, kv, kv, q, q).jaxpr
-    (loop,) = _loops(banded)
-    assert loop.params["length"] == 64 and loop.params["num_carry"] == 2
-    sliced = {e.outvars[0].aval.shape[-2:] for e in _eqns(banded)
-              if e.primitive.name in ("dynamic_slice", "gather")}
-    assert (4224, 128) in sliced and (8192, 128) not in sliced, sliced
+    (B, H, Hkv, T, D), windows = CELL_CALLS[cell]
+    assert fa._fa_tiles(T, T, D, jnp.bfloat16) == (512, T, 512)
+    q = jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, Hkv, T, D), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32)
+    for window in windows:
+        metrics.FLASH_BWD_TILES.reset()
+        metrics.FLASH_BWD.reset()
+        jaxpr = jax.make_jaxpr(lambda q_, k, v, o, l, g: fa._fa_bwd(
+            D ** -0.5, True, None, None, window, (q_, k, v, o, l), g))(
+                q, kv, kv, q, lse, q).jaxpr
+        assert not _loops(jaxpr)
+        # each call a `jit` of its own (one lowering for all the layers
+        # that make it), the kernel directly inside its scope
+        assert [e.params["name"] for e in jaxpr.eqns
+                if e.primitive.name in ("pjit", "jit")] == \
+            ["_dkv_call", "_dq_call"]
+        calls = [e for e in _eqns(jaxpr) if e.primitive.name == "pallas_call"]
+        assert [str(e.source_info.name_stack) for e in calls] == \
+            ["flash_bwd_dkv", "flash_bwd_dq"]
+        assert [[v.aval.shape for v in e.outvars] for e in calls] == \
+            [[(B * Hkv, T, D)] * 2, [(B * H, T, D)]]
+        assert metrics.FLASH_BWD.get(path="kernel") == 1
+        assert metrics.FLASH_BWD.get(path="reference") == 0
+        visited = metrics.FLASH_BWD_TILES.get(kind="visited")
+        assert visited == metrics.FLASH_BWD_TILES.get(kind="needed") > 0
+        # both kernels' tiles by hand: a triangle, or a band of it
+        dq, dkv = (fa._fa_bwd_tiles(T, T, D, jnp.bfloat16),) * 2
+        assert visited == B * H * (
+            _hand_count(T, dq[0], dq[2], window) +
+            _hand_count(T, dkv[2], dkv[0], window))
+    metrics.FLASH_BWD_TILES.reset()
+    metrics.FLASH_BWD.reset()
 
 
-@pytest.mark.parametrize("what", ["forward", "backward_stacked",
-                                  "backward_carried"])
-def test_flash_kernel_at_head_size_256(what, monkeypatch):
-    """The kernel's interpreter at the new model's head size against the
-    dense reference; the backward pass both ways it sums the key and value
-    gradients of its query blocks (stacked, or in the loop's carry as it
-    does where a stack would pass STACK_BYTES_MAX)."""
+@pytest.mark.parametrize("what", ["forward", "backward", "statistics"])
+def test_flash_kernel_at_head_size_256(what):
+    """The kernels' interpreter at the new model's head size against the
+    dense reference: the output, the three gradients, and the rows'
+    log-sum-exp the forward keeps for the backward kernels."""
     from mxnet_tpu.ops import flash_attention as fa
     rs = np.random.RandomState(4)
     q, k, v = (jnp.asarray(_rand(rs, 1, 2, 256, 256)) for _ in range(3))
@@ -376,8 +389,13 @@ def test_flash_kernel_at_head_size_256(what, monkeypatch):
         _close(fa._flash_attention(q, k, v, scale, True, 128, 128),
                _dense_reference(q, k, v, scale, True), rtol=1e-4, atol=1e-5)
         return
-    if what == "backward_carried":
-        monkeypatch.setattr(fa, "STACK_BYTES_MAX", 0)
+    if what == "statistics":
+        _, lse = fa._fa_call(q, k, v, scale, True, 128, 128, None, True)
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+        s = jnp.where(jnp.tril(jnp.ones((256, 256), bool)), s, -jnp.inf)
+        _close(lse.reshape(1, 2, 256), jax.nn.logsumexp(s, axis=-1),
+               rtol=1e-5, atol=1e-5)
+        return
     r = jnp.asarray(_rand(rs, 1, 2, 256, 256))
     got = jax.grad(lambda a, b, c: jnp.sum(
         fa._flash_attention(a, b, c, scale, True, 128, 128) * r),
@@ -387,14 +405,6 @@ def test_flash_kernel_at_head_size_256(what, monkeypatch):
             q, k, v)
     for g, w in zip(got, want):
         _close(g, w, rtol=1e-3, atol=1e-4)
-
-
-def test_flash_backward_keeps_the_stack_at_the_other_cells_shape():
-    """B*H 64, T 2048, D 64 (opt-1.3b's calls) stay under the bound, so
-    that program is as it was; B*H 40, D 256 passes it."""
-    from mxnet_tpu.ops.flash_attention import STACK_BYTES_MAX
-    assert 64 * 16 * 2048 * 64 * 4 <= STACK_BYTES_MAX
-    assert 40 * 16 * 2048 * 256 * 4 > STACK_BYTES_MAX
 
 
 # -- the expert op -----------------------------------------------------------
@@ -975,6 +985,55 @@ def test_gqa_flash_reader_on_synthetic_events():
     # the accepted readers do not take the new cell's events for theirs
     assert _reader("mla_flash_fwd_roofline").read(_ctx(cell, ops)) is None
     assert _reader("expert_gmm_roofline").read(_ctx(cell, ops)) is None
+
+
+@pytest.mark.parametrize("name, shapes", [
+    ("opt1.3b_train_gluon", [(32, 32, 2048, 64, None)] * 6),
+    ("glm4.7flash_train_gluon", [(20, 20, 2048, 256, None)] * 5),
+    ("smallthinker21b_train_gluon",
+     [(28, 4, 8192, 128, w) for w in (None, 4096, 4096, 4096)])])
+def test_flash_bwd_reader_on_synthetic_events(name, shapes):
+    """`flash_bwd_roofline` in each token cell: the required work of a
+    step's attention backward (five products a pair inside each layer's
+    mask) over the device time of ALL `flash_bwd*` kernel events divided
+    by the window's steps, so two kernels a layer do not read as twice the
+    roofline; no forward reader takes a backward event for its own, and a
+    trace without such events (the parent's loops) reads None."""
+    rd = _reader("flash_bwd_roofline")
+    cost = cellmod.load_module(os.path.join(
+        cellmod.HERE, "flash_bwd_cost.py"), "flash_bwd_cost")
+    fwd = cellmod.load_module(os.path.join(
+        cellmod.HERE, "gqa_kernel_cost.py"), "gqa_cost")
+    cell = cellmod.Cell(name, 1)
+    ms = 1_000_000
+    # two steps: a dK/dV event of 3 ms and a dQ event of 2 ms a layer
+    ops, t = [], 0
+    for i in range(2 * len(shapes)):
+        ops += [(_call("flash_bwd_dkv.%d" % i), t, t + 3 * ms),
+                (_call("flash_bwd_dq.%d" % i), t + 4 * ms, t + 6 * ms)]
+        t += 10 * ms
+    others = [("%while.7 = (s32[]) while(%p)", t, t + 50 * ms),
+              (_call("flash_bwd_dq.9", target="other"), t, t + ms),
+              ("%flash_bwd_delta.1 = f32[8] fusion(%p)", t, t + ms)]
+    flops, nbytes = cost.attention_backward(2, *shapes[-1])
+    h, hkv, T, D, w = shapes[-1]
+    assert flops == 2.5 * fwd.attention_forward(2, h, hkv, T, D, w)[0] \
+        == 5 * 2 * 2 * h * D * fwd.pairs_in_mask(T, w)
+    assert nbytes == 4 * 2 * (h + hkv) * T * D * 2
+    step = sum(max(f / 197e12, b / 819e9) for f, b in
+               (cost.attention_backward(2, *sh) for sh in shapes))
+    assert step == sum(cost.attention_backward(2, *sh)[0]
+                       for sh in shapes) / 197e12     # compute-bound
+    got = rd.read(_ctx(cell, ops + others))
+    assert got == pytest.approx(
+        100 * step * 2 / (2 * len(shapes) * 0.005), rel=1e-9)
+    assert rd.read(_ctx(cell, others)) is None
+    assert rd.read({"cell": cell, "peaks": PEAKS, "window": {}}) is None
+    assert rd.read(_ctx(cellmod.Cell("resnet50_train_module", 1), ops)) \
+        is None
+    for forward in ("flash_fwd_roofline", "mla_flash_fwd_roofline",
+                    "gqa_flash_fwd_roofline"):
+        assert _reader(forward).read(_ctx(cell, ops)) is None
 
 
 def test_grouped_ffn_reader_on_synthetic_events():

@@ -138,8 +138,8 @@ def test_flash_scale_that_is_no_power_of_two():
 
 
 def test_flash_gradients_bf16():
-    """Gradients after the forward change: the backward pass recomputes
-    its own float32 softmax from (q, k, v, o)."""
+    """Gradients through the rule's own tiles in bfloat16 against the dense
+    reference's in float32."""
     from mxnet_tpu.ops import flash_attention as fa
     q, k, v = _rand_qkv(9, (1, 2, 256, 64), jnp.bfloat16)
     r = _rand_qkv(10, (1, 2, 256, 64), jnp.float32)[0]
@@ -160,16 +160,221 @@ def test_flash_gradients_bf16():
                             rtol=3e-2, atol=3e-2 * float(np.abs(w).max()))
 
 
-def test_flash_backward_does_not_follow_the_forward_tiles():
-    """The backward pass has its own block: its program is the same
-    whatever tiles the forward kernel ran with."""
+def _qkvg(seed, H, Hkv, T, D, dtype):
+    rs = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rs.normal(0, 1, (1, h, T, D)).astype("f")
+                             ).astype(dtype) for h in (H, Hkv, Hkv, H))
+
+
+# causal, group, window, D, T, (blk_q, blk_k) or None for the rule's tiles
+BACKWARD_CALLS = {
+    "full": (False, 1, None, 64, 256, (128, 128)),
+    "causal": (True, 1, None, 64, 256, (128, 128)),
+    "group7": (True, 7, None, 128, 256, (128, 128)),
+    "window_in_a_tile": (True, 1, 50, 64, 384, (128, 128)),
+    "window_over_tiles": (True, 7, 200, 128, 384, (128, 128)),
+    "head256": (True, 1, None, 256, 256, (128, 128)),
+    "tall_tiles": (True, 1, 129, 64, 512, (256, 128)),
+    "wide_tiles": (True, 7, 129, 64, 512, (128, 256)),
+    "rule_tiles": (True, 7, 300, 128, 512, None),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("call", sorted(BACKWARD_CALLS))
+def test_backward_kernels_match_both_references(call, dtype):
+    """dQ, dK and dV of the two backward kernels (the interpreter) against
+    the plain float32 pass `_bwd_banded` on the same residuals and against
+    `jax.grad` of the dense reference: with a causal mask or none, one
+    query head a key/value head or seven, no window, one inside a tile and
+    one across tiles, head sizes 64, 128 and 256, tiles given (equal,
+    taller, wider) or chosen by the rule."""
+    from mxnet_tpu.observability import metrics
     from mxnet_tpu.ops import flash_attention as fa
-    x = jnp.ones((1, 2, 256, 16), jnp.float32)
-    programs = {str(jax.make_jaxpr(lambda res, g: fa._fa_bwd(
-        0.25, True, bq, bk, None, res, g))((x, x, x, x), x))
-        for bq, bk in ((32, 32), (64, 128), (None, None))}
-    assert len(programs) == 1
-    assert fa.BWD_BLOCK == 128
+    causal, group, window, D, T, blocks = BACKWARD_CALLS[call]
+    blk_q, blk_k = blocks or (None, None)
+    q, k, v, g = _qkvg(11, group, 1, T, D, dtype)
+    scale = D ** -0.5
+    metrics.FLASH_BWD.reset()
+    o, pull = jax.vjp(lambda a, b, c: fa._flash_attention(
+        a, b, c, scale, causal, blk_q, blk_k, window), q, k, v)
+    got = pull(g)
+    assert metrics.FLASH_BWD.get(path="kernel") == 1
+    assert metrics.FLASH_BWD.get(path="reference") == 0
+    plain = fa._bwd_banded(scale, causal, window, (q, k, v, o), g)
+    with jax.default_matmul_precision("highest"):
+        dense = jax.vjp(lambda a, b, c: _dense_reference(
+            a, b, c, scale, causal, window),
+            *(a.astype(jnp.float32) for a in (q, k, v)))[1](
+                g.astype(jnp.float32))
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    for a, b, c, like in zip(got, plain, dense, (q, k, v)):
+        assert a.shape == like.shape and a.dtype == like.dtype
+        peak = float(jnp.max(jnp.abs(c)))
+        for want in (b, c):
+            np.testing.assert_allclose(
+                np.asarray(a.astype(jnp.float32)),
+                np.asarray(want.astype(jnp.float32)), rtol=0,
+                atol=tol * peak)
+
+
+@pytest.mark.parametrize("T, block", [(100, 64), (192, 128)])
+def test_backward_takes_the_plain_pass_where_tiles_do_not_divide(T, block):
+    """Given tiles that do not divide the length the forward is the dense
+    reference and keeps no statistics; the backward is then the plain
+    float32 pass, and `mxnet_flash_bwd_total{path=reference}` counts it."""
+    from mxnet_tpu.observability import metrics
+    from mxnet_tpu.ops import flash_attention as fa
+    q, k, v, g = _qkvg(12, 2, 2, T, 32, "float32")
+    scale = 32 ** -0.5
+    metrics.FLASH_BWD.reset()
+    f = lambda a, b, c: fa._flash_attention(a, b, c, scale, True, block,
+                                            block)
+    assert "pallas_call" not in str(jax.make_jaxpr(
+        lambda a, b, c: jax.vjp(f, a, b, c)[1](g))(q, k, v))
+    got = jax.vjp(f, q, k, v)[1](g)
+    assert metrics.FLASH_BWD.get(path="reference") == 2
+    assert metrics.FLASH_BWD.get(path="kernel") == 0
+    want = jax.vjp(lambda a, b, c: _dense_reference(a, b, c, scale, True),
+                   q, k, v)[1](g)
+    for a, b in zip(got, want):
+        assert_almost_equal(np.asarray(a), np.asarray(b), rtol=1e-4,
+                            atol=1e-5)
+
+
+@pytest.mark.parametrize("causal, window, blocks", [
+    (False, None, (64, 64)), (True, None, (128, 64)), (True, 40, (64, 128)),
+    (True, 200, None)])
+def test_row_statistics_are_the_logsumexp_of_the_dense_scores(
+        causal, window, blocks):
+    """What the forward keeps for the backward kernels: B * H * T floats,
+    one row a head, equal to `logsumexp` over the keys each query sees;
+    the output is the one the forward-only program gives."""
+    from mxnet_tpu.ops import flash_attention as fa
+    T, D = 256, 32
+    q, k, v, _g = _qkvg(13, 4, 2, T, D, "float32")
+    blk_q, blk_k = blocks or (None, None)
+    o, lse = fa._fa_call(q, k, v, D ** -0.5, causal, blk_q, blk_k, window,
+                         True)
+    assert lse.shape == (4, 1, T) and lse.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(
+        fa._flash_attention(q, k, v, D ** -0.5, causal, blk_q, blk_k,
+                            window)))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, 2, axis=1),
+                   precision="highest") * D ** -0.5
+    if causal:
+        ahead = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
+        seen = ahead >= 0 if window is None else \
+            (ahead >= 0) & (ahead < window)
+        s = jnp.where(seen, s, -jnp.inf)
+    assert_almost_equal(np.asarray(lse.reshape(1, 4, T)),
+                        np.asarray(jax.nn.logsumexp(s, axis=-1)),
+                        rtol=1e-5, atol=1e-5)
+
+
+def test_forward_only_program_keeps_no_statistics():
+    """Outside `record()` the forward program is the one it was: one
+    output, and the kernel computes no log."""
+    from mxnet_tpu.ops import flash_attention as fa
+    x = jnp.ones((1, 2, 256, 64), jnp.bfloat16)
+    for f, outs in ((lambda a: fa._flash_attention(a, a, a, 0.125, True), 1),
+                    (lambda a: fa._fa_fwd(a, a, a, 0.125, True, None, None,
+                                          None)[0], 2)):
+        eqns = list(_kernel_eqns(jax.make_jaxpr(f)(x).jaxpr))
+        (call,) = [e for e in eqns if e.primitive.name == "pallas_call"]
+        assert len(call.outvars) == outs
+        assert any(e.primitive.name == "log" for e in eqns) is (outs == 2)
+
+
+def test_backward_kernels_take_operands_as_given():
+    """The forward kernel's rule in both backward kernels: q, k, v and dO
+    reach the products in bfloat16, P and dS are cast to it for the second
+    products, every product gives float32, and no block is widened."""
+    from mxnet_tpu.ops import flash_attention as fa
+    x = jnp.ones((1, 2, 256, 64), jnp.bfloat16)
+    lse = jnp.ones((2, 1, 256), jnp.float32)
+    eqns = list(_kernel_eqns(jax.make_jaxpr(lambda a, l: fa._fa_bwd(
+        0.125, True, 128, 128, None, (a, a, a, a, l), a))(x, lse).jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 2
+    inside = [e for c in calls for e in _kernel_eqns(c.params["jaxpr"])]
+    dots = [e for e in inside if e.primitive.name == "dot_general"]
+    assert len(dots) >= 7        # four in the dK/dV kernel, three in dQ's
+    for e in dots:
+        assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16] * 2, e
+        assert e.outvars[0].aval.dtype == jnp.float32
+    assert not [e for e in inside
+                if e.primitive.name == "convert_element_type"
+                and e.invars[0].aval.dtype == jnp.bfloat16
+                and e.params["new_dtype"] == jnp.float32]
+
+
+def test_backward_tile_rule_at_the_cells_shapes():
+    """The backward's tiles at the three token cells' shapes: divisors,
+    whole sub-tiles in the walked block, inside the backward's budget; a
+    smaller budget gives smaller tiles, none gives none."""
+    from mxnet_tpu.ops import flash_attention as fa
+    for T, D in ((2048, 64), (2048, 256), (8192, 128)):
+        blk, major, sub = fa._fa_bwd_tiles(T, T, D, jnp.bfloat16)
+        assert T % blk == 0 and T % major == 0 and major % sub == 0
+        assert blk % 128 == 0 and sub % 128 == 0
+        assert fa._fa_bwd_vmem_bytes(blk, major, sub, D, 2) <= \
+            fa.BWD_VMEM_BUDGET < fa.BWD_VMEM_LIMIT
+        small = fa._fa_bwd_tiles(T, T, D, jnp.bfloat16, budget=2 ** 20)
+        assert small is not None and small[0] * small[2] < blk * sub
+    assert fa._fa_bwd_tiles(2048, 2048, 64, jnp.bfloat16, budget=1024) \
+        is None
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip():
+    """A described, not attached, v5e chip: the TPU's compiler is installed
+    here, so the kernels compile for it on a CPU-only box (nothing runs)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("H, Hkv, T, D, window", [
+    (32, 32, 2048, 64, None), (20, 20, 2048, 256, None),
+    (28, 4, 8192, 128, None), (28, 4, 8192, 128, 4096)],
+    ids=["opt1.3b", "glm4.7flash", "smallthinker21b_global",
+         "smallthinker21b_window"])
+def test_kernels_compile_for_the_v5e_at_the_cells_shapes(
+        H, Hkv, T, D, window, one_v5e_chip, monkeypatch):
+    """Mosaic takes all three kernels at the token cells' exact shapes (the
+    interpreter proves nothing about tiling, layouts or VMEM): the forward
+    with its statistics and both backward kernels, as three custom calls
+    whose backward names no forward reader matches, and the statistics
+    compact in HBM."""
+    from mxnet_tpu.ops import flash_attention as fa
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()  # a `jit` of the interpreter's lowering would answer
+
+    def step(q, k, v, g):
+        o, pull = jax.vjp(lambda a, b, c: fa._flash_attention(
+            a, b, c, D ** -0.5, True, None, None, window), q, k, v)
+        return o, pull(g)
+
+    args = [jax.ShapeDtypeStruct((2, h, T, D), jnp.bfloat16,
+                                 sharding=one_v5e_chip)
+            for h in (H, Hkv, Hkv, H)]
+    try:
+        text = jax.jit(step).lower(*args).compile().as_text()
+    finally:
+        jax.clear_caches()
+    calls = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3, calls
+    assert sorted(c.lstrip("%").split(".")[0] for c in calls
+                  if "flash_bwd" in c) == ["flash_bwd_dkv", "flash_bwd_dq"]
+    assert not any("attention" in c for c in calls if "flash_bwd" in c)
+    assert f"f32[{2 * H},1,{T}]" "{2,1,0:T(1,128)" in text    # B*H*T floats
 
 
 @pytest.mark.parametrize("BH, D", [(64, 64), (40, 256)])

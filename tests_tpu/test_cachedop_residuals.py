@@ -2,7 +2,8 @@
 transformer cells' own sizes: each cell's whole run through the benchmark's
 entry (`chipbench/run.py run_cell`, a traced window of 3 s), from which come
 the forward-kernel events a step (one a layer: the backward program runs
-the kernel no more), the gradients against the cell's dense float32
+the kernel no more), the backward kernels' events under their own names
+(two a layer, and no `while` of plain XLA), the gradients against the cell's dense float32
 reference (`grad_norm_gap` under the cell's limit: chipbench/checks/
 train_steps.py), the peak of `memory_stats()` and the residual counters.
 
@@ -69,6 +70,16 @@ def test_cell_runs_its_forward_once(cell, monkeypatch):
     events = sum(is_fwd(name) for dev in devices
                  for name, _s, _e in dev["ops"])
     assert events == layers * steps, events
+    # the attention backward is two kernels a layer, under names of their
+    # own that no forward reader takes for a forward event
+    for scope in ("flash_bwd_dkv", "flash_bwd_dq"):
+        names = [trace_reduce.op_short_name(name) for dev in devices
+                 for name, _s, _e in dev["ops"]
+                 if scope in name and "tpu_custom_call" in name]
+        assert len(names) == layers * steps, (scope, len(names))
+        assert not any("attention" in n for n in names), names[:3]
+    assert not [name for dev in devices for name, _s, _e in dev["ops"]
+                if trace_reduce.op_short_name(name).startswith("while")]
     by_program = {}  # device ms a step, for PERF.md section 5
     for dev in devices:
         for name, s, e in dev["modules"]:

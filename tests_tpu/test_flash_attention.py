@@ -1,9 +1,11 @@
-"""The forward flash kernel on the chip at the benchmark cells' call
+"""The flash kernels on the chip at the benchmark cells' call
 shapes (opt1.3b_train_gluon: 2 x 32 heads of 64; glm4.7flash_train_gluon:
 2 x 20 heads of 256; T 2048, bfloat16, causal; smallthinker21b_train_gluon:
 2 x 28 query heads over 4 key/value heads of 128, T 8192, with a window of
 4,096 and without) against the dense reference in float32 at "highest",
-forward and gradients, and the counters the tile choice sets."""
+forward and gradients, and the counters the tile choice sets; the two
+backward kernels on the forward's own residuals against the plain float32
+pass `_bwd_banded`."""
 import numpy as np
 import pytest
 
@@ -84,7 +86,7 @@ def _blocked_reference(q, k, v, scale, window, block=1024):
 @pytest.mark.parametrize("window", [None, 4096], ids=["global", "window"])
 def test_flash_kernel_at_the_grouped_query_cells_shape(window):
     """2 x 28 query heads over 4 key/value heads of 128 at T 8,192: the
-    whole call through the kernel and its banded backward; the last batch
+    whole call through the kernel and the backward kernels; the last batch
     entry's last group (7 query heads, 1 key/value head) against the
     blocked dense reference, whose gradients are that group's alone since
     the loss is a sum over heads.  On the CPU (the harness's self-test)
@@ -134,3 +136,56 @@ def test_flash_kernel_at_the_grouped_query_cells_shape(window):
         assert np.isfinite(got).all(), name
         err = float(np.max(np.abs(got - want_)) / np.max(np.abs(want_)))
         assert err <= 2e-2, (name, err)
+
+
+CALLS = {  # B, H, Hkv, T, D, window: one call of each kind the cells send
+    "opt1.3b": (2, 32, 32, 2048, 64, None),
+    "glm4.7flash": (2, 20, 20, 2048, 256, None),
+    "smallthinker21b_global": (2, 28, 4, 8192, 128, None),
+    "smallthinker21b_window": (2, 28, 4, 8192, 128, 4096),
+}
+
+
+@pytest.mark.parametrize("call", sorted(CALLS))
+def test_backward_kernels_against_the_plain_pass(call):
+    """dQ, dK and dV of the two backward kernels at the cells' exact
+    shapes, from the forward kernel's own output and row statistics,
+    against `_bwd_banded` on the same residuals; the calls carry the
+    `flash_bwd_*` names no forward reader matches; the kernels visit the
+    tiles the mask needs and no other.  On the CPU (the harness's
+    self-test) an eighth of the length."""
+    on_chip = jax.default_backend() == "tpu"
+    B, H, Hkv, T, D, window = CALLS[call]
+    if not on_chip:
+        B, T, window = 1, T // 8, window and window // 8
+    rng = np.random.default_rng(13)
+    q, g = (jnp.asarray(rng.standard_normal((B, H, T, D), dtype=np.float32)
+                        ).astype(jnp.bfloat16) for _ in range(2))
+    k, v = (jnp.asarray(rng.standard_normal((B, Hkv, T, D),
+                                            dtype=np.float32)
+                        ).astype(jnp.bfloat16) for _ in range(2))
+    scale = D ** -0.5
+    o, lse = jax.jit(lambda a, b, c: fa._fa_call(
+        a, b, c, scale, True, None, None, window, True))(q, k, v)
+    assert lse.shape == (B * H, 1, T) and lse.dtype == jnp.float32
+    metrics.FLASH_BWD.reset()
+    metrics.FLASH_BWD_TILES.reset()
+    kernels = jax.jit(lambda res, g_: fa._fa_bwd(
+        scale, True, None, None, window, res, g_))
+    got = kernels((q, k, v, o, lse), g)
+    assert metrics.FLASH_BWD.get(path="kernel") == 1
+    assert metrics.FLASH_BWD.get(path="reference") == 0
+    assert metrics.FLASH_BWD_TILES.get(kind="visited") == \
+        metrics.FLASH_BWD_TILES.get(kind="needed") > 0
+    text = kernels.lower((q, k, v, o, lse), g).as_text(debug_info=True)
+    assert "flash_bwd_dkv" in text and "flash_bwd_dq" in text
+    if on_chip:
+        assert text.count("tpu_custom_call") == 2
+    want = jax.jit(lambda res, g_: fa._bwd_banded(
+        scale, True, window, res, g_))((q, k, v, o), g)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert a.dtype == jnp.bfloat16 and a.shape == b.shape, name
+        a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))
+        assert np.isfinite(a).all(), name
+        err = float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+        assert err <= 1e-2, (name, err)
