@@ -25,10 +25,26 @@ input, the tensor its attention reads, and not the feed-forward's.  With
 expert-parallel layer without its exchange: a chosen expert that is absent
 adds nothing (ops/decoder.py).
 
+`DecoderBlock(sandwich=True)` normalises each sub-layer's output as well
+as its input:
+
+    x + n1post(attn(n1(x)));  x + n2post(ffn(n2(x)))
+
+`LoopedLM` is a looped (weight-tied) decoder: ONE stack of such blocks
+applied `loop_steps` times, `norm_f` closing every step, the head and an
+exit gate (`ExitGate`) reading every step's output.  The loop is a node of
+the graph (`F.contrib.foreach`, run as `lax.scan`), so the hybridized
+program holds the stack once and a tied parameter's gradient is the sum
+over its applications.  `LoopedLMLoss` is its training objective: the
+cross-entropy of every exit weighted by the gates' exit distribution
+(`ExitDistribution`), less `beta` times that distribution's entropy, one
+exit's float32 logits alive at a time.
+
 The whole stack is one HybridBlock, so a step is one CachedOp forward and
 one backward, as `TransformerLM`'s is.  There is no decode path yet
 (ROADMAP R1 / R6: a latent leaf, a window's ring and a global layer's
-table in the decode cache).
+table in the decode cache; for a looped model a cache leaf a loop step and
+layer, and exits that leave a batch ragged in depth).
 """
 from __future__ import annotations
 
@@ -189,27 +205,36 @@ class MoEFeedForward(HybridBlock):
 
 class DecoderBlock(HybridBlock):
     """x + attn(n1(x)), then x + ffn(n2(x)).  router_reads: 'ffn_input', or
-    'attention_input' for an expert layer whose router scores n1(x)."""
+    'attention_input' for an expert layer whose router scores n1(x).
+    sandwich: each sub-layer's output is normalised too before it joins
+    the residual stream, x + n1post(attn(n1(x))), then x +
+    n2post(ffn(n2(x))): two more `RMSNorm`s a layer."""
 
     def __init__(self, attn, ffn, dim, epsilon=1e-5,
-                 router_reads="ffn_input", **kw):
+                 router_reads="ffn_input", sandwich=False, **kw):
         super().__init__(**kw)
         if router_reads not in ("ffn_input", "attention_input"):
             raise ValueError(f"DecoderBlock router_reads={router_reads!r}: "
                              "choose 'ffn_input' or 'attention_input'")
         self._early_router = router_reads == "attention_input"
+        self.n1_post = self.n2_post = None
         with self.name_scope():
             self.n1 = RMSNorm(dim, epsilon, prefix="n1_")
             self.attn = attn(prefix="attn_")
+            if sandwich:
+                self.n1_post = RMSNorm(dim, epsilon, prefix="n1post_")
             self.n2 = RMSNorm(dim, epsilon, prefix="n2_")
             self.ffn = ffn(prefix="ffn_")
+            if sandwich:
+                self.n2_post = RMSNorm(dim, epsilon, prefix="n2post_")
 
     def hybrid_forward(self, F, x):
         h = self.n1(x)
-        x = x + self.attn(h)
-        if self._early_router:
-            return x + self.ffn(self.n2(x), h)
-        return x + self.ffn(self.n2(x))
+        a = self.attn(h)
+        x = x + (a if self.n1_post is None else self.n1_post(a))
+        f = self.ffn(self.n2(x), h) if self._early_router else \
+            self.ffn(self.n2(x))
+        return x + (f if self.n2_post is None else self.n2_post(f))
 
 
 class DecoderLM(HybridBlock):
@@ -269,3 +294,148 @@ class DecoderLM(HybridBlock):
             "ROADMAP R1 / R6")
 
     _kv_forward = generate
+
+
+class ExitGate(HybridBlock):
+    """A looped model's exit gate: (B, T, D) -> the logit (B, T) of
+    leaving after this loop step, w . h + b in float32 whatever the net is
+    cast to (an element-wise product and a sum: nothing of it is rounded
+    to the activations' dtype)."""
+
+    def __init__(self, dim, **kw):
+        super().__init__(**kw)
+        self.weight = self.params.get("weight", shape=(1, dim))
+        self.bias = self.params.get("bias", shape=(1,), init="zeros")
+
+    def hybrid_forward(self, F, h, weight, bias):
+        w = F.reshape(F.cast(weight, "float32"), (1, 1, -1))
+        return F.broadcast_add(
+            F.sum(F.broadcast_mul(F.cast(h, "float32"), w), axis=-1),
+            F.reshape(F.cast(bias, "float32"), (1, 1)))
+
+
+class ExitDistribution(HybridBlock):
+    """Gate logits (R, B, T) -> (p, log p), each (R, B, T) float32: where
+    the gates let a token leave (op `exit_distribution`).  Auxiliary
+    (`grad_req="null"`, float32): `mass` (R + 1,), to which a forward pass
+    adds p summed over its tokens and, last, their number (read by
+    `observability.metrics.refresh_loop`)."""
+
+    def __init__(self, loop_steps, **kw):
+        super().__init__(**kw)
+        self.mass = self.params.get(
+            "mass", shape=(loop_steps + 1,), init="zeros", grad_req="null",
+            differentiable=False)
+        _metrics.watch_loop_exits(self)
+
+    def cast(self, dtype):
+        super().cast(dtype)
+        self.mass.cast("float32")
+
+    def hybrid_forward(self, F, gate, mass):
+        return F.exit_distribution(gate, mass)
+
+
+class LoopedLM(HybridBlock):
+    """A looped (weight-tied) decoder LM: token ids (B, T) -> the LAST
+    exit's logits (B, T, vocab).
+
+        h_0 = tok(x);  h_t = norm_f(blocks(h_{t-1})),  t = 1..loop_steps
+        exit t: logits head(h_t), gate logit gate(h_t)
+
+    ONE stack of `num_layers` sandwich-norm `DecoderBlock`s (`attention`:
+    a factory called with `prefix=`, a dense gated feed-forward of
+    `ffn_dim`) is applied `loop_steps` times, `norm_f` closing every step
+    (its output feeds the exit and the next step), so a parameter of the
+    stack is read `loop_steps` times a pass and its gradient is the sum
+    over them.  The loop is a node of the graph (`F.contrib.foreach`): the
+    hybridized program holds the stack once.  Untied head, no biases but
+    the gate's.  `LoopedLMLoss` trains all exits; inference with an exit
+    threshold of 1 runs every step and answers from the last, as this
+    block's plain call does."""
+
+    def __init__(self, vocab, dim, num_layers, loop_steps, attention,
+                 ffn_dim, epsilon=1e-6, **kw):
+        super().__init__(**kw)
+        self._loop_steps, self._num_layers = loop_steps, num_layers
+        self._stacks_traced = 0
+
+        def ffn(prefix):
+            return GatedFeedForward(dim, ffn_dim, prefix=prefix)
+
+        with self.name_scope():
+            self.tok = nn.Embedding(vocab, dim, prefix="tok_")
+            self.blocks = nn.HybridSequential(prefix="blocks_")
+            for i in range(num_layers):
+                self.blocks.add(DecoderBlock(attention, ffn, dim, epsilon,
+                                             sandwich=True, prefix=f"l{i}_"))
+            self.norm_f = RMSNorm(dim, epsilon, prefix="normf_")
+            self.head = _dense(vocab, dim, "head_")
+            self.gate = ExitGate(dim, prefix="gate_")
+            self.exits = ExitDistribution(loop_steps, prefix="exits_")
+
+    def step(self, h):
+        """One pass through the stack: h_{t-1} -> h_t."""
+        self._stacks_traced += 1
+        return self.norm_f(self.blocks(h))
+
+    def loop(self, F, tokens, exit_fn=None):
+        """Embed `tokens` and run the loop.  `exit_fn(h_t)` -> a list of
+        per-exit values; returns (those stacked over the steps, h_R)."""
+        self._stacks_traced = 0
+
+        def body(_step, states):
+            h = self.step(states[0])
+            return (exit_fn(h) if exit_fn else []), [h]
+
+        outs, (h,) = F.contrib.foreach(
+            body, F.arange(0, self._loop_steps), [self.tok(tokens)])
+        _metrics.LOOP_APPLICATIONS.set(self._num_layers * self._loop_steps)
+        _metrics.LOOP_STACK_COPIES.set(self._stacks_traced)
+        return outs, h
+
+    def hybrid_forward(self, F, tokens):
+        return self.head(self.loop(F, tokens)[1])
+
+    def generate(self, *args, **kwargs):
+        raise NotImplementedError(
+            "LoopedLM has no decode path yet: a cache leaf a loop step and "
+            "layer, and exits that leave a batch ragged in depth, are "
+            "ROADMAP R1 / R6")
+
+    _kv_forward = generate
+
+
+class LoopedLMLoss(HybridBlock):
+    """(tokens, next tokens), both (B, T) -> a looped LM's training
+    objective, per sequence (B,):
+
+        mean over tokens of  sum_t p_t CE(z_t, y)  -  beta H(p)
+
+    z_t the logits of exit t, p the exit distribution of the gates
+    (`ExitDistribution`), H its entropy: every exit is trained, weighted by
+    the chance of leaving there, and `beta` keeps the gates from
+    collapsing onto one exit.  Net and loss are one graph; an exit's
+    float32 logits and log-softmax live INSIDE the loop body, so one
+    exit's are alive at a time and only the cross-entropies and gate
+    logits (R, B, T) leave the loop.  Everything from the logits' cast on
+    is float32."""
+
+    def __init__(self, net, beta=0.1, **kw):
+        super().__init__(**kw)
+        self._beta = beta
+        with self.name_scope():
+            self.net = net
+
+    def hybrid_forward(self, F, tokens, labels):
+        net = self.net
+
+        def exit_fn(h):
+            logp = F.log_softmax(F.cast(net.head(h), "float32"), axis=-1)
+            return [-F.pick(logp, labels, axis=-1), net.gate(h)]
+
+        (ce, gate), _ = net.loop(F, tokens, exit_fn)
+        p, logp = net.exits(gate)
+        per_token = F.sum(p * ce, axis=0) + self._beta * F.sum(p * logp,
+                                                               axis=0)
+        return F.mean(per_token, axis=1)

@@ -22,6 +22,7 @@ for _name in dir(_gen):
 from . import random
 from . import linalg
 from . import sparse
+from . import contrib
 from .sparse import CSRNDArray, RowSparseNDArray
 
 # storage-class-aware forms shadow the value-level generated ops

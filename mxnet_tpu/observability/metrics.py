@@ -771,23 +771,31 @@ def watch_moe_layer(block) -> None:
     _moe_layers[block] = None
 
 
+def _read_states(blocks, attr):
+    """[(block, its float32 state `attr` as a float64 row)] of the blocks
+    whose state is initialized, all read in ONE transfer."""
+    import numpy as np
+    found = [(b, data._data.reshape(-1)) for b, data in
+             ((b, getattr(getattr(b, attr), "_data", None)) for b in blocks)
+             if data is not None]
+    if not found:
+        return []
+    import jax.numpy as jnp
+    flat = np.asarray(jnp.concatenate([a for _b, a in found]),
+                      dtype=np.float64)
+    rows = np.split(flat, np.cumsum([a.shape[0] for _b, a in found])[:-1])
+    return [(b, row) for (b, _a), row in zip(found, rows)]
+
+
 def refresh_moe() -> None:
     """Pull the load counters of every live expert layer (one stacked
     device read) into MOE_ASSIGNMENTS and MOE_LOAD_MAX_OVER_MEAN."""
     import numpy as np
-    blocks, arrays = [], []
-    for block in list(_moe_layers):
-        data = getattr(block.load, "_data", None)
-        if data is not None:  # initialized
-            blocks.append(block)
-            arrays.append(data._data.reshape(-1))
-    if not blocks:
+    states = _read_states(list(_moe_layers), "load")
+    if not states:
         return
-    import jax.numpy as jnp
-    flat = np.asarray(jnp.concatenate(arrays), dtype=np.float64)
-    rows = np.split(flat, np.cumsum([a.shape[0] for a in arrays])[:-1])
     held_all = []
-    for block, row in zip(blocks, rows):
+    for block, row in states:
         last = _moe_layers.get(block)
         delta = row - last if last is not None and (row >= last).all() \
             else row
@@ -798,6 +806,57 @@ def refresh_moe() -> None:
     held_all = np.concatenate(held_all)
     if held_all.sum() > 0:
         MOE_LOAD_MAX_OVER_MEAN.set(float(held_all.max() / held_all.mean()))
+
+
+# -- looped models -------------------------------------------------------------
+# A looped model (gluon.model_zoo.decoder.LoopedLM) applies one stack of layers
+# several times a step.  The first two gauges are set from shapes when the
+# model's graph is traced, as MOE_ROWS is; the third is filled as the expert
+# layers' load is: the model's `ExitDistribution` keeps one stacked float32
+# state on its device, the forward pass adds to it through the auxiliary
+# path, and `refresh_loop()` reads it in ONE transfer at export.
+_loop_exits = weakref.WeakKeyDictionary()  # ExitDistribution blocks alive
+_LOOP_STEP_LABELS = tuple(str(t) for t in range(1, 65))  # a bounded set
+
+LOOP_APPLICATIONS = Gauge(
+    "mxnet_loop_applications",
+    "Layer applications of one forward pass of a looped model: layers of "
+    "the stack x loop steps.  Set from shapes when the model is traced")
+LOOP_STACK_COPIES = Gauge(
+    "mxnet_loop_stack_copies",
+    "Copies of a looped model's stack of layers in the text of the program "
+    "traced last: 1 where the loop is a node of the graph "
+    "(contrib.foreach), the number of loop steps where it is unrolled.  "
+    "Counted while the model is traced: the times the stack was traced")
+LOOP_EXIT_MASS = Gauge(
+    "mxnet_loop_exit_mass",
+    "Mean over the tokens seen since the model was built of the "
+    "probability that a token leaves a looped model at loop step `step` "
+    "(1..R; they sum to 1).  Filled from the model's device-side state at "
+    "export (one device read), never in the step")
+
+
+def watch_loop_exits(block) -> None:
+    """Register a block whose `mass` parameter `refresh_loop` reads."""
+    _loop_exits[block] = None
+
+
+def refresh_loop() -> None:
+    """Pull the exit mass of the live looped models (one stacked device
+    read) into LOOP_EXIT_MASS."""
+    import numpy as np
+    rows = [row for _b, row in _read_states(list(_loop_exits), "mass")]
+    if not rows:
+        return
+    steps = min(max(len(r) for r in rows) - 1, len(_LOOP_STEP_LABELS))
+    mass, tokens = np.zeros(steps), 0.0
+    for row in rows:
+        mass[:len(row) - 1] += row[:-1][:steps]
+        tokens += row[-1]
+    if tokens > 0:
+        for t in range(steps):
+            LOOP_EXIT_MASS.set(float(mass[t] / tokens),
+                               step=_LOOP_STEP_LABELS[t])
 
 
 FUSED_DTYPE_RECOMPILES = Counter(
@@ -1231,10 +1290,11 @@ def _refresh_export_gauges() -> None:
         _mem.refresh_gauge()
     except Exception:  # noqa: BLE001
         pass
-    try:
-        refresh_moe()
-    except Exception:  # noqa: BLE001
-        pass
+    for refresh in (refresh_moe, refresh_loop):
+        try:
+            refresh()
+        except Exception:  # noqa: BLE001
+            pass
 
 
 def render_prometheus() -> str:

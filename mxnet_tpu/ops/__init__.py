@@ -20,5 +20,6 @@ from . import vision
 from . import contrib
 from . import flash_attention
 from . import decoder
+from . import control_flow
 from . import custom
 from . import sparse_ops

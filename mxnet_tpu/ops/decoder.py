@@ -387,3 +387,32 @@ def _moe_ffn_routed_by(p, x, routed_by, router_w, bias, gate_w, up_w, down_w,
     input here and the feed-forward's to `data`.  Everything else as
     `moe_ffn`."""
     return _moe(p, x, routed_by, router_w, bias, gate_w, up_w, down_w, load)
+
+
+@register("_contrib_exit_distribution", input_names=("gate", "mass"),
+          aliases=("exit_distribution",), num_outputs=2, aux_inputs=[1],
+          f32_inputs=(1,))
+def _exit_distribution(p, gate, mass):
+    """Where a looped model's exit gates let a token leave.
+
+    gate (R, ...): the gate's logit after each of the R loop steps; mass
+    (R + 1,), auxiliary float32.  With lam_t = sigmoid(gate_t), a token
+    leaves at step t with probability p_1 = lam_1, p_t = lam_t *
+    prod_{j<t} (1 - lam_j) for 1 < t < R, and p_R = prod_{j<R} (1 -
+    lam_j): the last step takes what is left and reads no gate of its own.
+    Returns (p, log p), both (R, ...) float32, computed from log-sigmoids so
+    that a saturated gate gives no NaN; p sums to 1 over axis 0.  The
+    forward pass adds the sum of p over the tokens to mass[:R] and the
+    number of tokens to mass[R] (read by
+    `observability.metrics.refresh_loop`)."""
+    g = gate.astype(jnp.float32)
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-g), axis=0)       # log prod (1 - lam)
+    before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]], axis=0)
+    leave = jnp.concatenate([jax.nn.log_sigmoid(g[:-1]),
+                             jnp.zeros_like(g[:1])], axis=0)
+    logp = before + leave
+    prob = jnp.exp(logp)
+    seen = jnp.concatenate([
+        jnp.sum(prob.reshape(prob.shape[0], -1), axis=1),
+        jnp.full((1,), prob[0].size, jnp.float32)])
+    return prob, logp, lax.stop_gradient(mass + seen.astype(mass.dtype))

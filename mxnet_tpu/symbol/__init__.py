@@ -15,3 +15,4 @@ from .symbol import pow, maximum, minimum, hypot  # noqa: E402
 
 from . import graph
 from .graph import GraphPlan
+from . import contrib

@@ -496,6 +496,70 @@ class GraphPlan:
         return outputs, aux
 
 
+class LoopBody:
+    """The body of a `contrib.foreach` loop: a sub-graph traced once, and
+    how a `_foreach` node runs it.
+
+    `symbol` groups the body's outputs and then its new states; `in_names`
+    are the sub-graph's variables in the order of the node's inputs: the
+    data slices, the states, then everything the body closes over (values
+    of the outer graph, then free variables: the blocks' parameters).
+
+    `scan` runs the loop as ONE `lax.scan`: the compiled program holds the
+    body once.  The closed-over inputs are constants of the scan, not
+    scanned and not carried, so the gradient of a parameter is the sum over
+    the steps, as jax transposes a constant.  The body runs under its own
+    `jax.checkpoint`: under a recorded CachedOp call the caller's policy
+    reaches into it (gluon/block.py `_RESIDUAL_POLICY`, as it reaches into
+    `ops/decoder.py moe_ffn`), so the forward scan stacks the outputs of
+    the body's products and kernels along the loop axis, and the backward
+    scan recomputes one step's element-wise work at a time; anywhere else
+    (an executor's backward) a step is recomputed whole from its carry.
+
+    A body may hold no auxiliary state (BatchNorm's moving statistics, an
+    expert layer's load counter) and no operator that draws random numbers
+    (Dropout): neither a state written inside a step nor a key for each
+    step is carried through the loop.  `sym.contrib.foreach` raises
+    MXNetError for both when it builds the node.
+    """
+
+    def __init__(self, symbol: Symbol, in_names):
+        self.symbol = symbol
+        self.in_names = tuple(in_names)
+        self._plan = None
+
+    def __repr__(self):
+        return f"LoopBody({len(self.symbol._topo())} nodes)"
+
+    @property
+    def plan(self) -> "GraphPlan":
+        if self._plan is None:
+            self._plan = GraphPlan(self.symbol)
+        return self._plan
+
+    def scan(self, ins, n_data, n_states, n_out, is_train):
+        from jax import lax
+        names = self.in_names
+        if len(ins) != len(names):
+            raise MXNetError(f"_foreach: {len(ins)} inputs for a body of "
+                             f"{len(names)} variables")
+        cut = n_data + n_states
+        closed = dict(zip(names[cut:], ins[cut:]))
+
+        def step(carry, xs):
+            args = dict(closed)
+            args.update(zip(names[:n_data], xs))
+            args.update(zip(names[n_data:cut], carry))
+            outs, _ = self.plan.run(args, {}, None, is_train)
+            return tuple(outs[n_out:]), tuple(outs[:n_out])
+
+        # within a scan nothing can be merged with the recomputation
+        final, stacked = lax.scan(jax.checkpoint(step, prevent_cse=False),
+                                  tuple(ins[n_data:cut]),
+                                  tuple(ins[:n_data]))
+        return tuple(stacked) + tuple(final)
+
+
 def _canon_params(op, node, n_inputs):
     p = {}
     for k, v in node.params.items():

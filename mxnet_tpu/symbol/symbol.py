@@ -23,10 +23,13 @@ from ..ops import registry as _reg
 
 
 class _Node:
-    __slots__ = ("op", "name", "params", "inputs", "attrs")
+    __slots__ = ("op", "name", "params", "inputs", "attrs", "serial")
+    made = 0  # nodes made so far: a node's `serial` says when it was made
 
     def __init__(self, op: Optional[str], name: str, params=None, inputs=None,
                  attrs=None):
+        _Node.made += 1
+        self.serial = _Node.made  # contrib.foreach: made before the body?
         self.op = op              # None for variables
         self.name = name
         self.params = dict(params or {})
@@ -46,6 +49,9 @@ class _Node:
         if self.op == "Custom":
             from ..ops.custom import custom_num_outputs
             return custom_num_outputs(dict(self.params))
+        if self.op == "_foreach":
+            return int(self.params["num_out_data"]) \
+                + int(self.params["num_states"])
         if op.name == "RNN":
             return 3 if _truthy(self.params.get("state_outputs")) else 1
         if op.name in ("BatchNorm", "LayerNorm"):
@@ -341,6 +347,10 @@ class Symbol:
         nid = {id(n): i for i, n in enumerate(nodes)}
         jnodes = []
         for n in nodes:
+            if n.op == "_foreach":
+                raise MXNetError(
+                    f"tojson: node '{n.name}' is a contrib.foreach loop, "
+                    "whose body is a sub-graph this format does not hold")
             jnodes.append({
                 "op": "null" if n.is_var else n.op,
                 "name": n.name,
