@@ -20,7 +20,8 @@ op bulking) + `python/mxnet/executor.py`.  TPU-native realization:
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+import operator
+from typing import Any, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +45,14 @@ def _device_of(a):
         return next(iter(devs)) if len(devs) == 1 else None
     except Exception:
         return None
+
+
+class _HeldLaunch(NamedTuple):
+    """A fused launch issued ahead of its step (`Executor.launch_ahead`)."""
+    batch: Any      # the caller's batch object it was issued for
+    reads: list     # every array bound when it was issued
+    snapshot: tuple  # (arg_vals, aux_vals, key), as forward() keeps them
+    results: Any    # what the program returned, or the error it raised
 
 
 class Executor:
@@ -76,6 +85,11 @@ class Executor:
         self._outputs_cache: Optional[List[NDArray]] = None
         self._snapshot = None  # (arg_vals, aux_vals, key) of last forward
         self._pending_grads = None  # grads held by a train-mode forward()
+        # a fused launch issued ahead of its step, nothing deposited yet
+        self._held: Optional[_HeldLaunch] = None
+        # the key of a held launch that was dropped: the launch that
+        # replaces it runs with it, so the random stream does not shift
+        self._held_key = None
         # lazy train-mode forward (VERDICT r3 #6): until this executor's
         # backward() is seen once, forward(is_train=True) runs ONLY the
         # forward program — Monitor taps / MC eval never pay the vjp.
@@ -258,9 +272,82 @@ class Executor:
             aux_vals = {k: _memory.register(jax.device_put(v, repl),
                                             tag="executor")
                         for k, v in aux_vals.items()}
-        return arg_vals, aux_vals, _random.next_key()
+        key, self._held_key = self._held_key, None
+        return arg_vals, aux_vals, _random.next_key() if key is None else key
+
+    # -- a launch held for its step -----------------------------------------
+    def _bound_data(self):
+        """Every array a launch reads, as bound right now."""
+        return [v._data for v in self.arg_dict.values()] + \
+            [v._data for v in self.aux_dict.values()]
+
+    def launch_ahead(self, batch=None) -> None:
+        """The first half of `forward_backward()`, issued before its step
+        (Module.prepare, for the `batch` it has just loaded): gather, key
+        and the launch of the fused program.
+        What comes back is HELD, nothing is deposited: outputs, auxiliary
+        states and gradients stay the last step's until `forward_backward()`
+        or `forward(is_train=True)` takes the slot.  A launch that raises is
+        held too and raises from the step that takes it, where a supervised
+        fit can retry it."""
+        self.drop_held()
+        reads = self._bound_data()
+        snapshot = self._gather({})
+        try:
+            results = self._launch_fused(*snapshot, None)
+        except Exception as exc:  # noqa: BLE001 - re-raised by the taker
+            results = exc
+        self._held = _HeldLaunch(batch, reads, snapshot, results)
+
+    def holds_launch(self, batch=None) -> bool:
+        """Whether a held launch is still good: it was issued for this very
+        `batch` object, and every array it read is the one bound now
+        (identity of the immutable buffers, so any write to a parameter, an
+        input or an auxiliary state shows).  Another one is dropped here."""
+        if self._held is None:
+            return False
+        reads, now = self._held.reads, self._bound_data()
+        if self._held.batch is batch and len(reads) == len(now) \
+                and all(map(operator.is_, reads, now)):
+            return True
+        self.drop_held()
+        return False
+
+    def drop_held(self) -> None:
+        """Forget a held launch and keep its key for the next one."""
+        if self._held is not None:
+            self._held_key, self._held = self._held.snapshot[2], None
+            if _metrics.ENABLED:
+                _metrics.HELD_LAUNCHES.inc(result="dropped")
+
+    def _take_held(self, same_launch: bool):
+        """What a good held launch returned, with the slot emptied and its
+        inputs and key as the snapshot; None where there is none.  A call
+        that asks for another launch than the held one (`same_launch`
+        False: an inference forward, head gradients, arguments) drops it."""
+        if not same_launch:
+            self.drop_held()
+        held = self._held
+        if held is None or not self.holds_launch(held.batch):
+            return None
+        self._held = None
+        if _metrics.ENABLED:
+            _metrics.HELD_LAUNCHES.inc(result="taken")
+        if isinstance(held.results, Exception):
+            raise held.results
+        self._snapshot = held.snapshot
+        return held.results
 
     def forward(self, is_train: bool = False, **kwargs) -> List[NDArray]:
+        held = self._take_held(is_train and not kwargs)
+        if held is not None:
+            # the fused program has run for these very arrays: the outputs
+            # now, the gradients when backward() asks
+            outs, new_aux, grads, rsp_grads = held
+            with span("mx.executor.deposit", cat="executor"):
+                self._set_results(outs, new_aux)
+            self._pending_grads = (grads, rsp_grads)
+            return self._outputs_cache
         arg_vals, aux_vals, key = self._gather(kwargs)
         self._snapshot = (arg_vals, aux_vals, key)
         self._pending_grads = None
@@ -333,14 +420,26 @@ class Executor:
     def forward_backward(self, out_grads=None, **kwargs) -> List[NDArray]:
         """Fused training step: outputs + grads + aux in ONE compiled call
         (the Module.fit hot path)."""
+        held = self._take_held(out_grads is None and not kwargs)
+        self._pending_grads = None
+        if held is not None:
+            self._finish_fused(held)
+            return self._outputs_cache
         arg_vals, aux_vals, key = self._gather(kwargs)
         self._snapshot = (arg_vals, aux_vals, key)
-        self._pending_grads = None
         self._run_fused(arg_vals, aux_vals, key, out_grads)
         return self._outputs_cache
 
     def _run_fused(self, arg_vals, aux_vals, key, out_grads,
                    set_results=True):
+        self._finish_fused(
+            self._launch_fused(arg_vals, aux_vals, key, out_grads),
+            set_results)
+
+    def _launch_fused(self, arg_vals, aux_vals, key, out_grads):
+        """First half of the fused step: the launch.  Returns what the
+        program returns (outputs, new auxiliary states, gradients,
+        row-sparse gradients) and writes nothing."""
         if out_grads is None:
             ograds = [None] * len(self._plan.out_refs)
         elif isinstance(out_grads, NDArray):
@@ -357,13 +456,17 @@ class Executor:
         with span("mx.executor.launch", cat="executor"), \
                 _memory.oom_guard("executor.forward_backward"):
             _fi_fire("memory.oom", at="executor")
-            outs, new_aux, grads, rsp_grads = fwd_bwd(
-                arg_vals, aux_vals, key, ograds)
+            results = fwd_bwd(arg_vals, aux_vals, key, ograds)
         nk = ("fwd_bwd", self._plan_key)
         if _introspect.ENABLED and nk not in self._noted:
             self._noted.add(nk)
             _introspect.note_jit("executor:fwd_bwd", fwd_bwd,
                                  arg_vals, aux_vals, key, ograds)
+        return results
+
+    def _finish_fused(self, results, set_results=True):
+        """Second half of the fused step: the deposit."""
+        outs, new_aux, grads, rsp_grads = results
         with span("mx.executor.deposit", cat="executor"):
             if set_results:
                 self._set_results(outs, new_aux)
@@ -550,10 +653,16 @@ class Executor:
                 nd.zeros(shp, ctx=self._ctx, dtype=cur.dtype)
         grads = {n: nd.zeros(new_args[n].shape, ctx=self._ctx)
                  for n in self._grad_names}
-        return Executor(self._symbol, self._ctx, new_args, grads, self.grad_req,
-                        new_aux, group2ctx=self.group2ctx, shared_exec=self)
+        new = Executor(self._symbol, self._ctx, new_args, grads, self.grad_req,
+                       new_aux, group2ctx=self.group2ctx, shared_exec=self)
+        # a launch held for the old shapes goes; its key goes along
+        self.drop_held()
+        new._held_key, self._held_key = self._held_key, None
+        return new
 
     def set_monitor_callback(self, callback, monitor_all=False) -> None:
+        # a monitored step launches inside the step, after the monitor's tic()
+        self.drop_held()
         self._monitor = callback
         self._monitor_all = bool(monitor_all)
 

@@ -232,6 +232,7 @@ class BaseModule:
             data_iter = iter(train_data)
             end_of_batch = False
             next_data_batch = self._fetch(data_iter, global_step)
+            ahead = {}  # what prepare() dispatched for the step to come
             while not end_of_batch:
                 data_batch = next_data_batch
                 if monitor is not None:
@@ -258,14 +259,23 @@ class BaseModule:
                     if obs_on:
                         # the span's deltas ride its ring record
                         deltas.update(_obs.step_deltas(c0))
+                        # what prepare() issued for this step is this step's
+                        for k, v in ahead.items():
+                            deltas[k] += v
                         _obs.FIT_STEP_DISPATCHES.set(
                             deltas["launches"] + deltas["device_puts"])
                 step = global_step
                 global_step += 1
+                ahead = {}
                 try:
                     next_data_batch = self._fetch(data_iter, step)
-                    with span("mx.module.prepare", cat="io", step=step):
+                    # prepare() works for the step to come: its span, and
+                    # what it dispatches, carry that step's id
+                    with span("mx.module.prepare", cat="io",
+                              step=global_step):
+                        before = _obs.step_counts()
                         self.prepare(next_data_batch)
+                        ahead = _obs.step_deltas(before)
                 except StopIteration:
                     end_of_batch = True
                 with span("mx.module.update_metric", cat="metric",

@@ -72,6 +72,10 @@ class Module(BaseModule):
         self._data_shapes = None
         self._label_shapes = None
         self._grad_req = "write"
+        # "computed" after a training forward-backward on the standard
+        # path, "applied" once update() has applied it: prepare() launches
+        # ahead only behind an applied step
+        self._last_step = None
 
     @staticmethod
     def load(prefix, epoch, load_optimizer_states=False, **kwargs):
@@ -223,6 +227,7 @@ class Module(BaseModule):
              inputs_need_grad=False, force_rebind=False, shared_module=None,
              grad_req="write"):
         if force_rebind:
+            self._drop_prepared()
             self._exec = None
             self.binded = False
         if self.binded:
@@ -439,16 +444,54 @@ class Module(BaseModule):
                     continue
                 self._load_arg(arr, self._exec.arg_dict[name])
 
+    def prepare(self, data_batch):
+        """Launch `data_batch`'s forward-backward now and HOLD its results.
+
+        `fit` calls this with the next batch before it reads this step's
+        metric, so the device starts the next step while the host reads,
+        runs callbacks and comes back.  Until `forward_backward(data_batch)`
+        (or `forward(data_batch, is_train=True)`) takes the held launch,
+        everything a caller can read is the last step's: outputs,
+        parameters, auxiliary states, gradients.  It is taken only for the
+        very batch object prepared and only while every array the program
+        read is still the one bound; otherwise it is dropped and the step
+        runs as if prepare() had not been called, with the same key.
+
+        It launches only behind a training step of the standard path whose
+        update() was applied, with no monitor installed and no group2ctx
+        placement: a caller who scores between steps, or uses prepare()
+        for something else, never pays for a program nobody takes."""
+        ex = self._exec
+        if self._last_step != "applied" or ex._monitor is not None \
+                or ex.group2ctx or not ex._grad_names:
+            return
+        self._set_batch(data_batch, True)
+        ex.launch_ahead(data_batch)
+
+    def _drop_prepared(self):
+        self._last_step = None
+        if self._exec is not None:
+            self._exec.drop_held()
+
+    def _load_batch(self, data_batch, is_train):
+        """The batch into the executor's arguments, unless a launch held
+        for this very batch has read them already."""
+        self._last_step = None
+        if not (is_train and self._exec.holds_launch(data_batch)):
+            self._exec.drop_held()
+            self._set_batch(data_batch, is_train or bool(data_batch.label))
+
     def forward(self, data_batch, is_train=None):
         assert self.binded and self.params_initialized
         if is_train is None:
             is_train = self.for_training
-        self._set_batch(data_batch, is_train or bool(data_batch.label))
+        self._load_batch(data_batch, is_train)
         self._exec.forward(is_train=is_train)
 
     def backward(self, out_grads=None):
         assert self.binded and self.params_initialized
         self._exec.backward(out_grads=out_grads)
+        self._last_step = "computed"
 
     def forward_backward(self, data_batch):
         """Fused single-compiled-call training step (TPU hot path)."""
@@ -458,9 +501,11 @@ class Module(BaseModule):
         self.__dict__.pop("_fused_stepped", None)
         with span("mx.module.forward_backward", cat="executor"):
             if self._maybe_fused_train_step(data_batch):
+                self._drop_prepared()  # the one-program step prepares none
                 return
-            self._set_batch(data_batch, True)
+            self._load_batch(data_batch, True)
             self._exec.forward_backward()
+            self._last_step = "computed"
 
     # -- single-program train step (MXNET_FUSED_STEP=1) ---------------------
     def _fused_step_updater(self):
@@ -625,6 +670,8 @@ class Module(BaseModule):
         if self.__dict__.pop("_fused_stepped", False):
             return  # the fused train step already applied the update
         self._params_dirty = True
+        if self._last_step == "computed":
+            self._last_step = "applied"
         with span("mx.module.update", cat="optimizer"):
             live = [(i, n) for i, n in enumerate(self._param_names)
                     if n in self._exec.grad_dict]

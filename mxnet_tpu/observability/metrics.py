@@ -374,6 +374,11 @@ XLA_LAUNCHES = Counter(
     "mxnet_xla_launches_total",
     "Compiled XLA program launches by kind (fwd, bwd, fwd_bwd, fused_step, "
     "kvstore_merge, allreduce, optimizer, data)")
+HELD_LAUNCHES = Counter(
+    "mxnet_module_held_launch_total",
+    "Forward-backward launches Module.prepare issued ahead of their step, "
+    "by result: taken by the step, or dropped (another batch, a written "
+    "array, a reshape, a monitor) and run again with the same key")
 DEVICE_PUTS = Counter(
     "mxnet_device_put_total",
     "Explicit jax.device_put host->device / device->device transfers")
@@ -1199,6 +1204,8 @@ def snapshot() -> dict:
         "engine_wait_seconds": ENGINE_WAIT_SECONDS.value,
         "jit_cache": {"hits": JIT_CACHE_HITS.value,
                       "misses": JIT_CACHE_MISSES.value},
+        "held_launches": {"taken": HELD_LAUNCHES.get(result="taken"),
+                          "dropped": HELD_LAUNCHES.get(result="dropped")},
         "host_sync_reads": HOST_SYNC_READS.value,
         "program_loads": {"compile": PROGRAM_LOADS.get(how="compile"),
                           "cache": PROGRAM_LOADS.get(how="cache"),
