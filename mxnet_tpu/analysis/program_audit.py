@@ -32,8 +32,9 @@ from a real incident class:
 Contracts are declared at the compile chokepoints
 (``note_program(..., contracts={...})`` — wholestep, FusedUpdater) and
 verified here from the opt-in captured HLO text
-(``MXNET_INTROSPECT_HLO=1`` / ``introspect.configure(hlo=True)`` must
-be on before the program compiles).  Programs without a contract are
+(``MXNET_INTROSPECT_HLO=1`` / ``introspect.configure(hlo=True)``: the
+text is read when ``introspect.programs()`` is first asked for it, so the
+flag must be on by then and the program's jitted function alive).  Programs without a contract are
 skipped, programs with a contract but no HLO are reported as
 ``skipped`` (or fail under ``strict=True`` — the CI self-audit mode).
 
@@ -251,7 +252,7 @@ def audit_program(rec: dict) -> List[dict]:
                  "detail": "contract declared but no HLO captured — "
                            "set MXNET_INTROSPECT_HLO=1 (or "
                            "introspect.configure(hlo=True)) before the "
-                           "program compiles"}]
+                           "records are read"}]
     if rec.get("hlo_truncated"):
         # half a program proves nothing: its alias table, casts and
         # collectives may all lie past the cut, and reading the stub
@@ -260,7 +261,7 @@ def audit_program(rec: dict) -> List[dict]:
                  "skipped": True,
                  "detail": "captured HLO was cut at the size cap — raise "
                            "introspect.configure(hlo_cap_bytes=...) "
-                           "before the program compiles"}]
+                           "before the records are read"}]
     issues: List[dict] = []
 
     leaves = contracts.get("donated_leaves")

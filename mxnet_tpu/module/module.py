@@ -21,6 +21,7 @@ from ..context import Context, cpu
 from ..initializer import InitDesc, Uniform
 from .. import ndarray as nd
 from ..io import DataDesc
+from ..observability import introspect as _introspect
 from ..observability import memory as _memory
 from ..observability import metrics as _metrics
 from ..observability.tracing import span
@@ -601,16 +602,21 @@ class Module(BaseModule):
                         jax.tree_util.tree_map(jnp.zeros_like, new_aux))
                 (grads,) = vjp(cots)
                 new_p, new_s = dict(params), dict(states)
-                for k, n in enumerate(sorted(gset)):
-                    nw, ns = opt_._fused_step_mp(
-                        ukeys[n], params[n], grads[n], states[n],
-                        lrs[k], wds[k], ts[k])
-                    new_p[n] = (nw if nw.dtype == params[n].dtype
-                                else nw.astype(params[n].dtype))
-                    new_s[n] = jax.tree_util.tree_map(
-                        lambda a, b: a if a.dtype == b.dtype
-                        else a.astype(b.dtype), ns, states[n])
-                return outs, new_aux, new_p, new_s, ts + 1
+                # the update's instructions land under "optimizer", as
+                # FusedUpdater's own program's do: the one program holds
+                # three passes and only the scopes tell them apart
+                with _introspect.layer_scope("optimizer"):
+                    for k, n in enumerate(sorted(gset)):
+                        nw, ns = opt_._fused_step_mp(
+                            ukeys[n], params[n], grads[n], states[n],
+                            lrs[k], wds[k], ts[k])
+                        new_p[n] = (nw if nw.dtype == params[n].dtype
+                                    else nw.astype(params[n].dtype))
+                        new_s[n] = jax.tree_util.tree_map(
+                            lambda a, b: a if a.dtype == b.dtype
+                            else a.astype(b.dtype), ns, states[n])
+                    new_ts = ts + 1
+                return outs, new_aux, new_p, new_s, new_ts
 
             # hold the plan ref: id() keys must not be recycled
             fs = {"key": fkey, "plan": plan,
@@ -631,6 +637,12 @@ class Module(BaseModule):
             _metrics.XLA_LAUNCHES.inc(kind="fused_step")
             _metrics.OPTIMIZER_STEPS.inc()
         key = _random.next_key()
+        if _introspect.ENABLED and not fs.get("noted"):
+            # once per compiled step, BEFORE the call (the donated
+            # buffers are still live): a retrace, no XLA compile
+            fs["noted"] = True
+            _introspect.note_jit("module:fused_step", fs["fn"], params,
+                                 states, aux_vals, xs, key, lrs, wds, ts)
         with span("mx.executor.launch", cat="executor"):
             outs, new_aux, new_p, new_s, nts = fs["fn"](
                 params, states, aux_vals, xs, key, lrs, wds, ts)

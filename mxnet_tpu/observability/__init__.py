@@ -42,8 +42,13 @@ package makes that visibility a product API:
     `note_program()` surface (`snapshot()["programs"]`,
     `introspect.report()`); `jax.named_scope` layer names thread
     through the graph interpreter so `per_layer()` attributes the
-    donated whole-step program's flops to named blocks
-    (`MXNET_INTROSPECT_HLO=1` captures the HLO it parses); MFU /
+    donated whole-step program's flops to named blocks and reports
+    measured `device_ms` per layer when handed a trace's seconds;
+    `op_scopes(jit_name)` names every instruction of a compiled
+    program by graph node, registered operator and pass, the join
+    between a device trace and the model (programs keep their
+    `Lowered`; `MXNET_INTROSPECT_HLO=1` captures the HLO text at
+    the first read of `programs()`, not at a program's first call); MFU /
     roofline gauges (`mxnet_mfu`, `MXNET_PEAK_FLOPS` override) and a
     persisted perf-regression sentinel (`MXNET_PERF_BASELINE_DIR`)
     compare the warmed step-time EWMA against a per-(model, platform)

@@ -12,8 +12,11 @@ TF's per-op attribution + utilization telemetry, arxiv 1605.08695):
     ``note_jit(name, fn, *args)`` capture each compiled program's
     ``cost_analysis()`` (analytical flops, bytes accessed), its
     ``CompiledMemoryStats`` (via ``memory.compiled_stats_dict`` — ONE
-    uniform shape across jax versions), and — opt-in — its optimized
-    HLO text.  Wired at every compile chokepoint: Executor
+    uniform shape across jax versions), and what it takes to read its
+    optimized HLO text LATER (the ``Lowered`` of a jit-called program,
+    tied to the life of the jitted function): the text is rendered at
+    the first read, never at the program's first call.  Wired at every
+    compile chokepoint: Executor
     (fwd/fwd_bwd + ``memory_analysis``), ``CachedOp`` (gluon fwd/bwd),
     ``FusedUpdater.update_all``, ``WholeStepCompiler``, and the serving
     bucket precompile.  Surfaces: ``snapshot()["programs"]``,
@@ -26,8 +29,16 @@ TF's per-op attribution + utilization telemetry, arxiv 1605.08695):
     ``per_layer()`` parses the captured HLO with a small per-opcode
     flops model (dot/conv exact from shapes, elementwise ≈ 1/elem) and
     groups by innermost known scope — the per-layer flops table for
-    the one-dispatch whole-step program.  The same scopes show up in
-    profiler/Perfetto device traces for measured per-layer *time*.
+    the one-dispatch whole-step program; handed the measured seconds
+    of a device trace by instruction it reports ``device_ms`` per layer.
+  * **device-time names** — ``op_scopes(jit_name)`` maps every
+    instruction of a compiled program (entry, ``while`` bodies, called
+    computations) to ``{node, op_type, pass, scope}``: the graph node
+    and its registered operator (``layer_scope(name, op_type=...)``),
+    ``fwd`` / ``bwd`` / ``recompute`` / ``update``, the raw path.  A
+    profiler trace names device time by instruction and launch
+    (``jit_mx_cachedop_bwd``); this is the join
+    (``chipbench/scope_reduce.py`` reads it).
   * **MFU / roofline** — analytical flops-per-step ÷ the flight
     recorder's warmed step-time EWMA → ``mxnet_mfu``,
     ``mxnet_step_flops_per_s``, ``mxnet_step_bytes_per_s``, and
@@ -53,11 +64,13 @@ Overhead contract (the ``MXNET_METRICS_ENABLED`` discipline):
 ``MXNET_INTROSPECT=0`` reduces every hook — named scopes, program
 notes, sentinel ticks — to ONE module-global boolean test.  Enabled,
 the steady-state per-step cost is one counter increment (captures are
-once-per-program retraces at build time, never per step); HLO text is
-captured only under ``MXNET_INTROSPECT_HLO=1`` (size-capped; dumps go
+once-per-program retraces at build time, never per step); an untraced
+step path calls neither ``compile()`` nor ``as_text()``.  Records carry
+HLO text only under ``MXNET_INTROSPECT_HLO=1`` (size-capped; dumps go
 through ``base.atomic_write`` + ``base.unique_path`` like flight
-dumps) because it forces an extra ``lower().compile()`` on jit-called
-programs.
+dumps), rendered when ``programs()`` is first read: a jit-called
+program's ``lower().compile()`` then finds the executable the step
+already runs (in-process, else the persistent compile cache).
 """
 from __future__ import annotations
 
@@ -67,6 +80,7 @@ import logging
 import os
 import re
 import time
+import weakref
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -79,7 +93,7 @@ log = logging.getLogger(__name__)
 
 __all__ = ["ENABLED", "enabled", "enable", "disable", "layer_scope",
            "known_scopes", "note_program", "note_jit", "programs",
-           "per_layer", "attributed_pct", "step_flops", "mfu",
+           "op_scopes", "program_sources", "per_layer", "attributed_pct", "step_flops", "mfu",
            "peak_flops", "phase_flops_map", "dump_hlo", "report",
            "snapshot_summary", "sentinel_tick", "refresh_baseline",
            "baseline_dir", "baseline_path", "sentinel_armed",
@@ -89,9 +103,10 @@ __all__ = ["ENABLED", "enabled", "enable", "disable", "layer_scope",
 # Hooks across symbol/executor/gluon/optimizer/serving read this module
 # global directly: `if introspect.ENABLED: ...`.
 ENABLED: bool = getenv("MXNET_INTROSPECT", True)
-#: opt-in optimized-HLO text capture (per_layer()'s input).  Default
-#: OFF for steady state: on jit-called programs it forces one extra
-#: lower().compile() per program (persistent-compile-cache assisted).
+#: opt-in optimized-HLO text in the program records (per_layer()'s and
+#: the audit's input), rendered at the first read of programs().
+#: Default OFF for steady state: the text of a large program is tens of
+#: MB and its first read may compile.
 HLO: bool = getenv("MXNET_INTROSPECT_HLO", False)
 #: size cap on captured HLO text per program (truncated past it — the
 #: flops parser still sees the leading instructions; configure() tunes)
@@ -125,13 +140,26 @@ FUSED_STEP_PROGRAMS = ("gluon:fwd", "gluon:bwd", "fused_update")
 #: flops/time quotient stays a true device rate.
 FULL_STEP_PHASES = frozenset({"whole_step", "superstep"})
 
+#: scopes opened straight by ``jax.named_scope`` around a kernel call
+#: (ops/flash_attention.py: the kernels' trace events are named after
+#: them), known here so that op_scopes() can fall back on them
+KERNEL_SCOPES = ("flash_attention_bwd", "flash_bwd_dkv", "flash_bwd_dq")
+
 _lock = _san.make_lock("introspect.programs")
 _programs: Dict[str, dict] = {}
-#: every name ever passed through layer_scope() — the known-scope set
-#: per_layer() matches HLO metadata components against.  Bounded by
-#: the graphs traced in-process (one entry per distinct node name),
-#: the same boundedness contract as flight phase names.
-_scopes: set = set()
+#: every compiled program that can still name its own instructions,
+#: oldest first; a program leaves with its jitted function
+_sources: List["_Source"] = []
+#: registry name -> the newest source noted under it (programs() reads
+#: its text under MXNET_INTROSPECT_HLO=1)
+_latest: Dict[str, "_Source"] = {}
+#: every name ever passed through layer_scope() — the known scopes
+#: per_layer() and op_scopes() match HLO metadata components against —
+#: with the registered operator of a graph node (None for a literal
+#: scope such as "optimizer").  Bounded by the graphs traced in-process
+#: (one entry per distinct node name), the same boundedness contract as
+#: flight phase names.
+_scopes: Dict[str, Optional[str]] = {}
 
 
 def enabled() -> bool:
@@ -150,19 +178,22 @@ def disable() -> None:
 
 # -- named scopes ------------------------------------------------------------
 @contextlib.contextmanager
-def layer_scope(name: str):
+def layer_scope(name: str, op_type: Optional[str] = None):
     """Wrap a traced region in ``jax.named_scope(name)`` and register
     ``name`` as a known layer scope.  ``GraphPlan.run`` calls this per
-    graph step with the node name (so HLO metadata carries layer names
-    through fwd AND the vjp), the fused optimizer math with literal
-    ``"optimizer"``/``"allreduce"`` scopes.  Names must come from a
-    bounded set (graph node names / literals) — the metrics-hygiene
-    graft-lint rule rejects call-site string building.  One boolean
-    test when introspection is off."""
+    graph step with the node name and the node's registered operator
+    (so HLO metadata carries layer names through fwd AND the vjp, and
+    op_scopes() can say which operator a node is), the fused optimizer
+    math and the program's own glue with literal scopes
+    (``"optimizer"``, ``"allreduce"``; no ``op_type``).  Names must
+    come from a bounded set (graph node names / literals) — the
+    metrics-hygiene graft-lint rule rejects call-site string building.
+    One boolean test when introspection is off."""
     if not ENABLED:
         yield
         return
-    _scopes.add(name)
+    if op_type is not None or name not in _scopes:
+        _scopes[name] = op_type
     try:
         ctx = jax.named_scope(name)
     except Exception:  # noqa: BLE001 — a bad name must never kill a trace
@@ -173,9 +204,17 @@ def layer_scope(name: str):
 
 
 def known_scopes() -> frozenset:
-    # list() snapshots the set in one GIL-atomic C call: a trace on
+    # list() snapshots the keys in one GIL-atomic C call: a trace on
     # another thread may be registering scopes concurrently
     return frozenset(list(_scopes))
+
+
+def _scope_types() -> Dict[str, Optional[str]]:
+    """{scope: registered operator of a graph node | None for a literal},
+    the kernels' own scopes among the literals."""
+    types: Dict[str, Optional[str]] = dict.fromkeys(KERNEL_SCOPES)
+    types.update(list(_scopes.items()))
+    return types
 
 
 # -- program capture ---------------------------------------------------------
@@ -213,26 +252,7 @@ def _memory_of(compiled) -> dict:
         return {}
 
 
-def _hlo_of(compiled, lowered) -> Tuple[Optional[str], bool]:
-    """Optimized HLO text, size-capped.  Lazy by flag: nothing is ever
-    rendered unless MXNET_INTROSPECT_HLO=1 — and only then does a
-    jit-called program pay the extra lowered.compile() (which the
-    persistent compile cache absorbs when JAX_COMPILATION_CACHE_DIR is
-    set)."""
-    if not HLO:
-        return None, False
-    src = compiled
-    if src is None and lowered is not None:
-        try:
-            src = lowered.compile()
-        except Exception:  # noqa: BLE001
-            return None, False
-    if src is None:
-        return None, False
-    try:
-        txt = src.as_text()
-    except Exception:  # noqa: BLE001
-        return None, False
+def _cap(txt) -> Tuple[Optional[str], bool]:
     if not isinstance(txt, str) or not txt:
         return None, False
     if len(txt) > HLO_CAP_BYTES:
@@ -240,9 +260,57 @@ def _hlo_of(compiled, lowered) -> Tuple[Optional[str], bool]:
     return txt, False
 
 
+class _Source:
+    """What ONE compiled program keeps so that its optimized HLO can be
+    read later: the ``Lowered`` of a jit-called program (its
+    ``compile()`` finds the executable the step already runs: jax keeps
+    it on the lowering it cached, else the persistent compile cache has
+    it), or a weak reference to a ``Compiled`` the caller holds.
+    Nothing is compiled or rendered until ``text()`` is asked, and the
+    instruction records parsed from it (``ops``) replace the text."""
+
+    __slots__ = ("name", "jit_name", "lowered", "compiled", "ops",
+                 "text_bytes", "read_s", "__weakref__")
+
+    def __init__(self, name, jit_name, lowered=None, compiled=None):
+        self.name, self.jit_name = name, jit_name
+        self.lowered = lowered
+        self.compiled = weakref.ref(compiled) if compiled is not None \
+            else None
+        self.ops: Optional[Dict[str, dict]] = None
+        self.text_bytes = 0
+        self.read_s = 0.0
+
+    def text(self) -> Optional[str]:
+        t0 = time.perf_counter()
+        src = self.compiled() if self.compiled is not None else None
+        try:
+            if src is None and self.lowered is not None:
+                src = self.lowered.compile()
+            txt = src.as_text() if src is not None else None
+        except Exception as e:  # noqa: BLE001 — introspection never raises
+            log.debug("introspect: reading the HLO of %s failed: %s",
+                      self.name, e)
+            return None
+        if not isinstance(txt, str) or not txt:
+            return None
+        self.text_bytes = len(txt)
+        self.read_s += time.perf_counter() - t0
+        return txt
+
+
+def _drop_source(src: "_Source") -> None:
+    with _lock:
+        if src in _sources:
+            _sources.remove(src)
+        for name, latest in list(_latest.items()):
+            if latest is src:
+                del _latest[name]
+
+
 def note_program(name: str, compiled=None, lowered=None, label=None,
                  signature=None, memory_stats=None,
-                 contracts=None) -> dict:
+                 contracts=None, jit_name=None, owner=None) -> dict:
     """File one compiled program's stats under ``name`` — THE shared
     surface every compile chokepoint routes through (Executor bind /
     memory_analysis, CachedOp, FusedUpdater, WholeStepCompiler, serving
@@ -262,7 +330,14 @@ def note_program(name: str, compiled=None, lowered=None, label=None,
     ``analysis.audit_programs()`` verifies against the captured HLO
     (donation really became input-output aliasing, AMP left no f32
     dots, no host callbacks, collective count matches the bucketer's
-    plan).  Returns the record (``{}`` when introspection is off)."""
+    plan).
+
+    ``jit_name`` is the name a device trace shows for the program's
+    launches (``jit_`` + the jitted function's name) and files the
+    program for ``op_scopes()``; ``owner`` (the jitted function) ties
+    what is kept to its life, so a program that is freed keeps no
+    executable loaded through this registry.  Returns the record
+    (``{}`` when introspection is off)."""
     if not ENABLED:
         return {}
     from . import goodput as _goodput
@@ -277,8 +352,28 @@ def note_program(name: str, compiled=None, lowered=None, label=None,
     if mem:
         from . import memory as _memory
         _memory.note_compiled(full, mem)
-    hlo, truncated = _hlo_of(compiled, lowered)
+    # a Compiled in hand has paid its compile: with the flag on its
+    # text is read now (the caller may drop it); a Lowered is kept and
+    # compiled at the first read
+    src = None
+    hlo, truncated = None, False
+    if compiled is not None or lowered is not None:
+        src = _Source(full, jit_name, lowered=lowered, compiled=compiled)
+        if HLO and compiled is not None:
+            hlo, truncated = _cap(src.text())
+        # a source leaves with the jitted function, or with the Compiled
+        # it points at weakly: the registry does not grow with programs
+        # that are gone
+        owner = owner if owner is not None else compiled
+        if owner is not None:
+            try:
+                weakref.finalize(owner, _drop_source, src)
+            except TypeError:  # not weak-referenceable: kept for good
+                pass
     with _lock:
+        if src is not None:
+            _sources.append(src)
+            _latest[full] = src
         prev = _programs.get(full)
         rec = {
             "name": full,
@@ -287,8 +382,9 @@ def note_program(name: str, compiled=None, lowered=None, label=None,
             "memory": dict(mem) if mem else {},
             "signature": signature if signature is not None
             else (prev or {}).get("signature"),
-            "hlo": hlo if hlo is not None else (prev or {}).get("hlo"),
-            "hlo_truncated": truncated if hlo is not None
+            # a new program under the name: the old text goes with it
+            "hlo": hlo if src is not None else (prev or {}).get("hlo"),
+            "hlo_truncated": truncated if src is not None
             else bool((prev or {}).get("hlo_truncated")),
             "contracts": dict(contracts) if contracts is not None
             else (prev or {}).get("contracts"),
@@ -301,10 +397,12 @@ def note_program(name: str, compiled=None, lowered=None, label=None,
 def note_jit(name: str, fn, *args, label=None, signature=None,
              contracts=None, **kwargs) -> dict:
     """Capture a jit-called program via ``fn.lower(*args)`` — a retrace
-    (NO XLA compile unless MXNET_INTROSPECT_HLO=1 forces one for the
-    text).  Call sites guard to once per program/cache key; a capture
-    failure is logged and swallowed — introspection must never break
-    the step it observes."""
+    served by jax's own lowering cache, NO XLA compile.  The ``Lowered``
+    is kept for as long as ``fn`` lives (host memory only: jax's cache
+    holds the same lowering) under the name its launches carry in a
+    device trace, ``jit_`` + ``fn.__name__``.  Call sites guard to once
+    per program/cache key; a capture failure is logged and swallowed —
+    introspection must never break the step it observes."""
     if not ENABLED:
         return {}
     try:
@@ -312,13 +410,52 @@ def note_jit(name: str, fn, *args, label=None, signature=None,
     except Exception as e:  # noqa: BLE001
         log.debug("introspect: lowering %s for capture failed: %s", name, e)
         return {}
+    fn_name = getattr(fn, "__name__", None)
     return note_program(name, lowered=lowered, label=label,
-                        signature=signature, contracts=contracts)
+                        signature=signature, contracts=contracts,
+                        jit_name="jit_" + fn_name if fn_name else None,
+                        owner=fn)
 
 
 def programs() -> Dict[str, dict]:
+    """The program records.  Under ``MXNET_INTROSPECT_HLO=1`` a record
+    whose text has not been read yet reads it here (size-capped), the
+    first time it is asked for."""
+    if HLO:
+        with _lock:
+            unread = [(k, _latest[k]) for k, v in _programs.items()
+                      if v.get("hlo") is None and k in _latest]
+        for full, src in unread:
+            hlo, truncated = _cap(src.text())
+            with _lock:
+                rec = _programs.get(full)
+                if hlo is not None and rec is not None \
+                        and _latest.get(full) is src:
+                    rec["hlo"], rec["hlo_truncated"] = hlo, truncated
     with _lock:
         return {k: dict(v) for k, v in _programs.items()}
+
+
+def program_sources(sizes: bool = False) -> List[dict]:
+    """What the registry holds to read programs' text from, and what
+    reading has cost so far: ``[{name, jit_name, kept, text_bytes,
+    read_s, instructions}]``, oldest first (the builder's report).
+    ``sizes`` adds ``lowered_bytes``, the length of each kept
+    lowering's StableHLO text (it renders every one: seconds for a
+    large program), the measure of the host memory the registry pins
+    beside jax's own lowering cache, which holds the same modules."""
+    with _lock:
+        held = list(_sources)
+    out = [{"name": s.name, "jit_name": s.jit_name,
+            "kept": "lowered" if s.lowered is not None else "compiled",
+            "text_bytes": s.text_bytes, "read_s": s.read_s,
+            "instructions": len(s.ops) if s.ops is not None else None}
+           for s in held]
+    if sizes:
+        for row, s in zip(out, held):
+            row["lowered_bytes"] = len(s.lowered.as_text()) \
+                if s.lowered is not None else None
+    return out
 
 
 def dump_hlo(name: str, directory: Optional[str] = None) -> str:
@@ -329,7 +466,7 @@ def dump_hlo(name: str, directory: Optional[str] = None) -> str:
     if rec is None or not rec.get("hlo"):
         raise MXNetError(
             f"no HLO captured for program {name!r} — set "
-            f"MXNET_INTROSPECT_HLO=1 before the program compiles "
+            f"MXNET_INTROSPECT_HLO=1 before the program is noted "
             f"(captured: {sorted(programs())})")
     d = directory or flight_dir()
     os.makedirs(d, exist_ok=True)
@@ -353,7 +490,8 @@ _ZERO_FLOP_OPS = frozenset({
     "send-done", "recv-done", "all-gather", "optimization-barrier",
 })
 
-_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%[\w.\-]+\s*=\s*(.+?)\s+"
+#: an instruction line: (ROOT?, name, result type, opcode)
+_INSTR_RE = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s*(.+?)\s+"
                        r"([\w\-]+)\(")
 _DIMS_RE = re.compile(r"\[([0-9,]*)\]")
 _META_RE = re.compile(r'metadata=\{[^}]*op_name="([^"]+)"')
@@ -442,12 +580,7 @@ def _scope_of(op_name: str, known: frozenset) -> Optional[str]:
     test."""
     best = None
     for comp in op_name.split("/"):
-        t = comp
-        while True:
-            m = _WRAP_RE.match(t)
-            if m is None:
-                break
-            t = m.group(1)
+        t = _unwrap(comp)
         if t in known:
             best = t
     return best
@@ -473,7 +606,7 @@ def parse_hlo_flops(text: str,
         m = _INSTR_RE.match(line)
         if m is None:
             continue
-        type_str, opcode = m.group(1), m.group(2)
+        type_str, opcode = m.group(3), m.group(4)
         if opcode in _ZERO_FLOP_OPS:
             continue
         flops = _instr_flops(line, type_str, opcode)
@@ -486,17 +619,213 @@ def parse_hlo_flops(text: str,
     return out
 
 
+# -- device-time names: instruction -> node, operator, pass -------------------
+_HEAD_RE = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
+_CALLEE_RE = re.compile(r"\b(calls|to_apply)=%?([\w.\-]+)")
+#: the optimizer's literal scope: what runs under it is the update pass
+UPDATE_SCOPE = "optimizer"
+
+
+def _lines(text: str):
+    """The lines of a text that may be hundreds of MB, one at a time."""
+    for m in re.finditer(r"[^\n]+", text):
+        yield m.group(0)
+
+
+def _unwrap(component: str) -> str:
+    """``transpose(jvp(dense0_fwd))`` -> ``dense0_fwd``."""
+    while True:
+        m = _WRAP_RE.match(component)
+        if m is None:
+            return component
+        component = m.group(1)
+
+
+def scope_record(op_name: Optional[str], opcode: str,
+                 types: Dict[str, Optional[str]], by: str = "self") -> dict:
+    """One instruction's ``{node, op_type, pass, scope, opcode, by}`` from
+    the ``op_name`` path of its metadata.  ``node``: the innermost graph
+    node of the path, else its innermost literal scope, else
+    ``UNATTRIBUTED``.  ``pass``: ``update`` under the optimizer's scope,
+    ``recompute`` under a checkpoint's ``rematted_computation``, ``bwd``
+    under a ``transpose(``, else ``fwd``; None where the path says
+    nothing (no scope and neither marker) or is not the instruction's
+    to speak for (``by == "inner"``: a convolution XLA rewrote loses its
+    path and keeps, fused into it, the forward activation it reads: the
+    node names the place, the pass is the reader's to settle).  ``by`` says whose path it
+    is: the instruction's own (``self``), or for a fusion that XLA left
+    without one its root's (``root``) or the commonest of its fused
+    instructions' (``inner``)."""
+    node = literal = None
+    parts = [_unwrap(c) for c in op_name.split("/")] if op_name else ()
+    for t in parts:
+        if t in types:
+            if types[t] is None:
+                literal = t
+            else:
+                node = t
+    if UPDATE_SCOPE in parts:
+        pass_ = "update"
+    elif op_name and "rematted_computation" in op_name:
+        pass_ = "recompute"
+    elif op_name and "transpose(" in op_name:
+        pass_ = "bwd"
+    else:
+        pass_ = "fwd" if (node or literal) else None
+    if by == "inner":
+        pass_ = None
+    return {"node": node or literal or UNATTRIBUTED,
+            "op_type": types[node] if node else literal,
+            "pass": pass_, "scope": op_name, "opcode": opcode,
+            "by": by if op_name else None}
+
+
+def parse_op_scopes(text: str,
+                    types: Optional[Dict[str, Optional[str]]] = None
+                    ) -> Dict[str, dict]:
+    """``{instruction name: record}`` for every instruction of every
+    computation of an optimized HLO module that can show in a device
+    trace: the entry, ``while`` bodies and conditions, branches, called
+    computations.  A fused computation gives only its fusion instruction
+    (named by the fusion's own ``op_name``; XLA leaves some without one,
+    a convolution it rewrote, a multi-output root: those take their
+    root's, else the commonest path of the instructions fused into
+    them), a reduction's ``to_apply`` region nothing.  What is left
+    without a path (copies, layout changes, broadcasts XLA made) is named
+    after the instruction that reads it, else the one it reads.
+    Instructions of one path and opcode share one record; the text is
+    walked twice line by line and never split, so no size cap applies."""
+    types = types if types is not None else _scope_types()
+    inner = set()     # computations no trace event is named after
+    for line in _lines(text):
+        if "calls=" in line or "to_apply=" in line:
+            m = _INSTR_RE.match(line)
+            if m is None or m.group(4) == "call":
+                continue
+            inner.update(c for kind, c in _CALLEE_RE.findall(line)
+                         if kind == "to_apply" or m.group(4) == "fusion")
+    out: Dict[str, dict] = {}
+    shared: Dict[tuple, dict] = {}
+    paths: Dict[str, str] = {}      # one string a distinct path
+    fused: Dict[str, list] = {}     # fused computation -> [root's, tally]
+    flows: Dict[str, tuple] = {}    # pathless instruction -> (reads, readers)
+    comp = None
+    for line in _lines(text):
+        m = _INSTR_RE.match(line)
+        if m is None:
+            h = _HEAD_RE.match(line)
+            if h is not None:
+                comp = h.group(1)
+            continue
+        is_root, name, opcode = m.group(1), m.group(2), m.group(4)
+        meta = _META_RE.search(line)
+        path = meta.group(1) if meta else None
+        if path is not None:
+            path = paths.setdefault(path, path)
+        if comp in inner:
+            if path and "/" in path:
+                seen = fused.setdefault(comp, [None, {}])
+                if is_root:
+                    seen[0] = path
+                seen[1][path] = seen[1].get(path, 0) + 1
+            continue
+        by = "self"
+        if path is None and opcode == "fusion":
+            c = _CALLEE_RE.search(line)
+            seen = fused.pop(c.group(2), None) if c else None
+            if seen is not None:
+                path, by = (seen[0], "root") if seen[0] else \
+                    (max(seen[1], key=seen[1].get), "inner")
+        key = (path, opcode, by)
+        rec = shared.get(key)
+        if rec is None:
+            rec = shared[key] = scope_record(path, opcode, types, by)
+        out[name] = rec
+        # what XLA made itself (a layout change, a copy, a broadcast of
+        # zeros) carries no path: remember who reads it and what it reads
+        reads = _OPERAND_RE.findall(line[m.end():].split(")", 1)[0])
+        for o in reads:
+            if o in flows:
+                flows[o][1].append(name)
+        if rec["node"] == UNATTRIBUTED and opcode not in _NO_WORK_OPS:
+            flows[name] = (reads, [])
+    _name_by_neighbours(out, flows, shared)
+    return out
+
+
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+_NO_WORK_OPS = frozenset({"parameter", "constant"})
+
+
+def _name_by_neighbours(out: Dict[str, dict], flows: Dict[str, tuple],
+                        shared: Dict[tuple, dict]) -> None:
+    """Give an instruction without a path the names of the instruction
+    that reads it (``by: "user"``: a layout change is made for its
+    reader), else of the first one it reads (``"operand"``).  Readers
+    come later in the text, so walking backwards names a chain of such
+    instructions from its named end; a second walk forwards serves what
+    only its operands can name."""
+    def take(name, neighbours, by):
+        for n in neighbours:
+            rec = out.get(n)
+            if rec is not None and rec["node"] != UNATTRIBUTED:
+                key = (id(rec), out[name]["opcode"], by)
+                new = shared.get(key)
+                if new is None:
+                    new = shared[key] = dict(rec, by=by,
+                                             opcode=out[name]["opcode"])
+                out[name] = new
+                return True
+        return False
+
+    order = list(flows)
+    for name in reversed(order):
+        take(name, flows[name][1], "user")
+    for name in order:
+        if out[name]["node"] == UNATTRIBUTED:
+            take(name, flows[name][0], "operand")
+
+
+def op_scopes(jit_name: str) -> Optional[List[Dict[str, dict]]]:
+    """Name the instructions of the compiled programs whose launches a
+    device trace shows as ``jit_name`` (``jit_mx_cachedop_bwd``): one
+    ``{instruction name: {node, op_type, pass, scope, opcode}}`` a
+    program, oldest first (two CachedOps share a name and no
+    instruction set: a reader matches a launch to the map that knows
+    all of its instructions).  The first call reads the program's
+    optimized HLO — ``compile()`` of the kept lowering: the executable
+    in use, else a read of the persistent cache — parses it and keeps
+    the records in its place; never on an untraced step path.  None
+    with introspection off, or where no live program has that name."""
+    if not ENABLED:
+        return None
+    with _lock:
+        held = [s for s in _sources if s.jit_name == jit_name]
+    maps = []
+    for src in held:
+        if src.ops is None:
+            text = src.text()
+            if text is None:
+                continue
+            t0 = time.perf_counter()
+            src.ops = parse_op_scopes(text)
+            src.read_s += time.perf_counter() - t0
+        maps.append(src.ops)
+    return maps or None
+
+
 def per_layer(program: str = "whole_step", top: Optional[int] = None,
-              step_time_s: Optional[float] = None,
-              phase: Optional[str] = None) -> List[dict]:
+              measured: Optional[Dict[str, float]] = None) -> List[dict]:
     """The per-layer cost table for a captured program: ``[{layer,
-    flops, pct, est_ms}]`` sorted by flops (the ``_unattributed``
-    remainder is a row, never hidden).  ``est_ms`` distributes the
-    phase's warmed step-time EWMA (or ``step_time_s``) proportionally
-    to flops — the cheap always-available time estimate; for MEASURED
-    per-layer time, take a profiler/Perfetto device trace: its op
-    metadata carries the same named scopes.  Requires HLO capture
-    (``MXNET_INTROSPECT_HLO=1`` before the program compiles)."""
+    flops, pct, device_ms}]`` sorted by flops (the ``_unattributed``
+    remainder is a row, never hidden).  ``flops`` is the parser's
+    estimate; ``device_ms`` is MEASURED: hand in the seconds a device
+    trace gives by instruction name (``{"fusion.58": 0.0021}``: leaf
+    operations of this program's launches; chipbench/scope_reduce.py
+    makes them) and each lands on the layer ``op_scopes`` names for it;
+    None without ``measured``.  Requires HLO text in the record
+    (``MXNET_INTROSPECT_HLO=1``: read when the record is first asked
+    for)."""
     rec = programs().get(program)
     if rec is None:
         raise MXNetError(
@@ -505,23 +834,23 @@ def per_layer(program: str = "whole_step", top: Optional[int] = None,
     if not rec.get("hlo"):
         raise MXNetError(
             f"no HLO text captured for {program!r}: set "
-            f"MXNET_INTROSPECT_HLO=1 (or configure(hlo=True)) before "
-            f"the program compiles — capture is opt-in because it "
-            f"forces an extra lower().compile() per program")
+            f"MXNET_INTROSPECT_HLO=1 (or configure(hlo=True)) — the "
+            f"text is opt-in because a large program's is tens of MB "
+            f"and its first read may compile")
     by_layer = parse_hlo_flops(rec["hlo"])
     total = sum(by_layer.values()) or 1.0
-    if step_time_s is None:
-        from . import flight as _flight
-        for ph in ([phase] if phase else
-                   [p for p, pr in PHASE_PROGRAM.items() if pr == program] +
-                   [program]):
-            step_time_s = _flight.watch_ewma(ph)
-            if step_time_s is not None:
-                break
+    ms: Dict[str, float] = {}
+    if measured is not None:
+        names = parse_op_scopes(rec["hlo"])
+        for instr, seconds in measured.items():
+            node = names.get(instr, {}).get("node", UNATTRIBUTED)
+            layer = _layer_of(node)
+            ms[layer] = ms.get(layer, 0.0) + seconds * 1e3
+            by_layer.setdefault(layer, 0.0)
     rows = [{"layer": k, "flops": v,
              "pct": round(100.0 * v / total, 2),
-             "est_ms": round(step_time_s * 1e3 * v / total, 4)
-             if step_time_s else None}
+             "device_ms": round(ms.get(k, 0.0), 4)
+             if measured is not None else None}
             for k, v in sorted(by_layer.items(), key=lambda kv: -kv[1])]
     return rows[:top] if top else rows
 
@@ -948,6 +1277,8 @@ def reset() -> None:
     refresh_baseline() to change them."""
     with _lock:
         _programs.clear()
+        del _sources[:]
+        _latest.clear()
     _scopes.clear()
     _sent_counts.clear()
     _sentinel.clear()

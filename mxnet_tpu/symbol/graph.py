@@ -382,10 +382,11 @@ class GraphPlan:
             # layer attribution (ISSUE 13): each step traces under a
             # jax.named_scope of its node name, so HLO instruction
             # metadata carries layer names through forward AND the vjp
-            # (introspect.per_layer parses them back out).  Trace-time
+            # and the node's operator beside its name (introspect.per_layer
+            # and op_scopes parse them back out).  Trace-time
             # only — compiled programs pay nothing per execution; one
             # boolean when MXNET_INTROSPECT=0
-            with _introspect.layer_scope(step.node.name):
+            with _introspect.layer_scope(step.node.name, step.op.name):
                 if step_overrides and si in step_overrides:
                     out = step_overrides[si](p, ins)
                 else:
@@ -470,7 +471,8 @@ class GraphPlan:
                         p["__is_train__"] = is_train
                     if step.op.needs_rng:
                         ins.append(jax.random.fold_in(key_, si))
-                    with _introspect.layer_scope(step.node.name):
+                    with _introspect.layer_scope(step.node.name,
+                                                 step.op.name):
                         out = step.op.fn(p, *ins)
                     out = out if isinstance(out, tuple) else (out,)
                     n_vis = len(out) - len(step.op.aux_inputs)

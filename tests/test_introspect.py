@@ -271,12 +271,30 @@ def test_per_layer_attributes_90pct_on_pinned_net(monkeypatch):
     assert 99.0 <= total_pct <= 101.0
 
 
-def test_per_layer_est_ms_uses_step_time(monkeypatch):
+def test_per_layer_device_ms_is_the_measured_time(monkeypatch):
+    """per_layer() reports MEASURED time: the seconds handed in by
+    instruction land on the layer op_scopes names for the instruction,
+    none is lost, and without a measurement the column reads None (the
+    FLOPs share of a host-clock step time, `est_ms`, is gone)."""
     st = _wholestep(monkeypatch, hlo=True)
     assert st.active
-    rows = introspect.per_layer("whole_step", step_time_s=1.0)
-    total_ms = sum(r["est_ms"] for r in rows)
-    assert abs(total_ms - 1000.0) < 1.0  # distributes the full second
+    assert all(r["device_ms"] is None and "est_ms" not in r
+               for r in introspect.per_layer("whole_step"))
+    (names,) = introspect.op_scopes("jit_ftrain")
+    dense0 = st.net._children[0].name
+    of_dense0 = [n for n, r in names.items()
+                 if r["node"] == dense0 + "_fwd"]
+    assert of_dense0
+    measured = {n: 0.001 for n in names}          # 1 ms an instruction
+    rows = introspect.per_layer("whole_step", measured=measured)
+    by_layer = {r["layer"]: r for r in rows}
+    assert abs(by_layer[dense0]["device_ms"] - len(of_dense0)) < 1e-6
+    assert abs(sum(r["device_ms"] for r in rows) - len(names)) < 1e-3
+    assert by_layer["optimizer"]["device_ms"] > 0
+    # an instruction the text does not hold is shown, not dropped
+    rows = introspect.per_layer("whole_step", measured={"nope.1": 0.5})
+    assert {r["layer"]: r["device_ms"] for r in rows}[
+        introspect.UNATTRIBUTED] == 500.0
 
 
 def test_per_layer_requires_hlo(monkeypatch):
@@ -310,7 +328,8 @@ def test_dump_hlo_atomic_unique(tmp_path):
 def test_parse_hlo_flops_dot_model():
     """The per-instruction flops model: a dot is 2*M*N*K attributed to
     the innermost known scope (decorations unwrapped)."""
-    introspect._scopes.update({"dense0_fwd", "optimizer"})
+    introspect._scopes.update({"dense0_fwd": "FullyConnected",
+                               "optimizer": None})
     text = textwrap.dedent("""\
       %dot.1 = f32[8,4]{1,0} dot(f32[8,16]{1,0} %a, f32[16,4]{1,0} %b), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="jit(f)/transpose(jvp(dense0_fwd))/dot_general"}
       %add.1 = f32[8,4]{1,0} add(f32[8,4]{1,0} %x, f32[8,4]{1,0} %y), metadata={op_name="jit(f)/optimizer/add"}
