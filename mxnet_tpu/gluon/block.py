@@ -273,6 +273,26 @@ def _is_input_ref(x):
     return isinstance(x, _InputRef)
 
 
+def _committed(arrays: Dict[str, NDArray]) -> dict:
+    """{name: buffer}, each buffer committed to the device it is on.  `jit`
+    keys a call on committed-ness: parameters fresh from an initializer,
+    `cast` or `set_data` are uncommitted and the update's outputs that
+    replace them are committed, so a first step with the former lowered,
+    compiled and cached every recorded program a second time at the second
+    step (PERF.md Open questions 13).  The committed buffer replaces the
+    NDArray's, so an array is placed once, at its first call."""
+    out = {}
+    for k, v in arrays.items():
+        x = v._data
+        if not getattr(x, "_committed", True):
+            # device_put to the sharding it has reuses the buffer
+            # (tests/test_moe_buffer.py); `_data` and not `_set_data`, as
+            # the value is the same and `_version` keys the autograd tape
+            x = v._data = jax.device_put(x, x.sharding)  # graft-lint: disable=memory-hygiene
+        out[k] = x
+    return out
+
+
 class CachedOp:
     """Compiled graph closure (parity: Imperative::CachedOp,
     src/imperative/cached_op.cc).
@@ -379,8 +399,8 @@ class CachedOp:
     def _call(self, arg_arrays, aux_arrays, ctx, input_names):
         from .. import random as _random
         is_train = autograd.is_training()
-        arg_vals = {k: v._data for k, v in arg_arrays.items()}
-        aux_vals = {k: v._data for k, v in aux_arrays.items()}
+        arg_vals = _committed(arg_arrays)
+        aux_vals = _committed(aux_arrays)
         key = _random.next_key()
         if _metrics.ENABLED:
             # the gluon analog of the executor's fwd/fwd_bwd accounting:

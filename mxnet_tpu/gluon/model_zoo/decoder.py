@@ -153,9 +153,10 @@ class MoEFeedForward(HybridBlock):
     up (held, D, F) and down (held, F, D) matrices of the held experts.
     Auxiliary (`grad_req="null"`, float32 whatever the net is cast to, as a
     bfloat16 counter stops counting at 256): `select_bias` (num_experts,),
-    added to the scores for the selection only, and `load` (held + 1,), to
-    which the forward pass adds the assignments of each held expert and,
-    last, of absent ones (read by `observability.metrics.refresh_moe`).
+    added to the scores for the selection only, and `load` (held + 2,), to
+    which the forward pass adds the assignments of each held expert, then of
+    absent ones, and last the rows of the buffer the grouped products ran
+    over (read by `observability.metrics.refresh_moe`).
     router, activation: as `moe_ffn` has them.  Called with a second
     input, the router scores that and the experts read the first."""
 
@@ -181,7 +182,7 @@ class MoEFeedForward(HybridBlock):
             self.down_weight = self.params.get(
                 "down_weight", shape=(held, ffn_dim, dim))
             self.load = self.params.get(
-                "load", shape=(held + 1,), init="zeros", grad_req="null",
+                "load", shape=(held + 2,), init="zeros", grad_req="null",
                 differentiable=False)
             self.shared = GatedFeedForward(
                 dim, ffn_dim * shared_experts, prefix="shared_") \

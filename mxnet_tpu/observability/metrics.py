@@ -701,6 +701,16 @@ MOE_LOAD_MAX_OVER_MEAN = Gauge(
     "the busiest expert sets a grouped product's critical path and, across "
     "devices, the straggler.  Refreshed with "
     "mxnet_moe_assignments_total")
+MOE_BUFFER_ROWS = Counter(
+    "mxnet_moe_buffer_rows_total",
+    "Rows of the expert layers' buffers, by kind: live = the (token, choice) "
+    "pairs routed to a held expert (mxnet_moe_assignments_total{where=held}), "
+    "processed = the rows the buffers around the grouped products were as "
+    "long as (gathered, masked, cast and added back), the whole T x top_k "
+    "where a layer keeps its products, else the rung of ops/decoder.py "
+    "buffer_rungs the device chose.  processed over live is what the row "
+    "plumbing does for each row that counts.  Filled as "
+    "mxnet_moe_assignments_total is")
 MOE_ROWS = Gauge(
     "mxnet_moe_rows",
     "Rows of one expert layer's grouped products, set from shapes when the "
@@ -794,7 +804,9 @@ def _read_states(blocks, attr):
 
 def refresh_moe() -> None:
     """Pull the load counters of every live expert layer (one stacked
-    device read) into MOE_ASSIGNMENTS and MOE_LOAD_MAX_OVER_MEAN."""
+    device read; a row is each held expert's assignments, absent ones',
+    the buffer's rows) into MOE_ASSIGNMENTS, MOE_BUFFER_ROWS and
+    MOE_LOAD_MAX_OVER_MEAN."""
     import numpy as np
     states = _read_states(list(_moe_layers), "load")
     if not states:
@@ -805,9 +817,12 @@ def refresh_moe() -> None:
         delta = row - last if last is not None and (row >= last).all() \
             else row
         _moe_layers[block] = row
-        MOE_ASSIGNMENTS.inc(float(delta[:-1].sum()), where="held")
-        MOE_ASSIGNMENTS.inc(float(delta[-1]), where="absent")
-        held_all.append(row[:-1])
+        live = float(delta[:-2].sum())
+        MOE_ASSIGNMENTS.inc(live, where="held")
+        MOE_ASSIGNMENTS.inc(float(delta[-2]), where="absent")
+        MOE_BUFFER_ROWS.inc(live, kind="live")
+        MOE_BUFFER_ROWS.inc(float(delta[-1]), kind="processed")
+        held_all.append(row[:-2])
     held_all = np.concatenate(held_all)
     if held_all.sum() > 0:
         MOE_LOAD_MAX_OVER_MEAN.set(float(held_all.max() / held_all.mean()))

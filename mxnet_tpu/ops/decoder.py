@@ -15,12 +15,20 @@ The expert op is what an expert-parallel step wraps its exchange around:
 it routes over ALL `num_experts`, and computes the part of the result that
 the experts `[first, first + held)` give.  A chosen expert that is absent
 adds nothing.  No capacity, no dropped token: the (token, choice) pairs
-are sorted by expert into a buffer of `T * top_k` rows, the most a
-dropless layer can need, and the three products of the gated feed-forward
-run as grouped products over that ragged split (`lax.ragged_dot`; on the
-TPU XLA lowers it to a Mosaic grouped-matmul call that visits only the row
-tiles its group sizes cover, so the tail of the buffer that no held expert
-owns costs no MXU time).
+are sorted by expert, the held experts' first, and the three products of
+the gated feed-forward run as grouped products over that ragged split
+(`lax.ragged_dot`; on the TPU XLA lowers it to a Mosaic grouped-matmul call
+that visits only the row tiles its group sizes cover, so the tail of the
+buffer that no held expert owns costs no MXU time).  Where the products'
+outputs are kept for the backward pass the buffer has `T * top_k` rows, the
+most a dropless layer can need.  Where they are run again in the backward
+pass (`KEEP_BYTES_MAX`) the buffer is the shorter of two lengths fixed by
+the shapes (`buffer_rungs`) that holds the rows the held experts got,
+chosen on the device; the longer is `T * top_k`.  Either
+way the rows are gathered straight from the tokens and each token reads
+its results back from the buffer, so the gathers, masks and casts around
+the products cost what the buffer costs, and nothing is as long as
+`T * top_k` rows of width D unless the buffer is.
 """
 from __future__ import annotations
 
@@ -172,18 +180,6 @@ def _grouped_query_attention(p, q, k, v):
     return out.transpose(0, 2, 1, 3).reshape(B, T, H * D)
 
 
-@jax.custom_vjp
-def _permute_rows(x, perm, inv):
-    """x[perm] for a permutation: the backward pass is a gather through
-    the inverse, not the scatter-add a plain gather transposes to."""
-    return x.at[perm].get(mode="promise_in_bounds", unique_indices=True)
-
-
-_permute_rows.defvjp(
-    lambda x, perm, inv: (_permute_rows(x, perm, inv), (perm, inv)),
-    lambda res, g: (_permute_rows(g, res[1], res[0]), None, None))
-
-
 ROUTERS = ("sigmoid", "softmax_topk")
 ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
@@ -219,35 +215,132 @@ def route(h, router_w, bias, top_k, scale, norm_topk, router="sigmoid"):
     return idx.astype(jnp.int32), w * scale
 
 
-def _expert_rows(h, key, w, gate_w, up_w, down_w, act=jax.nn.silu):
-    """The held experts' part: rows sorted by `key` (held experts 0..held-1,
-    absent ones `held`, which sort last), three grouped products over the
-    ragged split (`act` on the gate's), unsorted and summed over a token's
-    choices."""
-    held = gate_w.shape[0]
-    rows, top_k = key.shape[0], w.shape[1]
+def _sorted_rows(key, held):
+    """(order, sizes): the (token, choice) rows sorted by `key` (held
+    experts 0..held-1, absent ones `held`, which sort last, so the live rows
+    are a prefix), and how many rows each key got."""
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    inv = jnp.zeros_like(order).at[order].set(
-        jnp.arange(rows, dtype=jnp.int32), unique_indices=True)
     sizes = jnp.sum(key[:, None] == jnp.arange(held + 1, dtype=jnp.int32),
                     axis=0, dtype=jnp.int32)
-    groups = sizes[:held]
-    # The tail of the buffer belongs to absent experts.  The TPU's grouped
+    return order, sizes
+
+
+def _grouped_ffn(xs, groups, gate_w, up_w, down_w, act):
+    """The three grouped products (`act` on the gate's) over the ragged
+    split of the buffer `xs` whose first sum(groups) rows are live."""
+    # The tail of the buffer belongs to no held expert.  The TPU's grouped
     # product leaves rows it does not visit as they were in memory (read on
     # the chip: values up to 5 in the tail), on the way back too, so the
     # tail is cut off on both sides of the products: what comes out of them
     # there is not a result, and what flows back into them is not a gradient.
-    live = (jnp.arange(rows, dtype=jnp.int32) < jnp.sum(groups))[:, None]
-    # every choice's copy of its token, sorted by expert
-    xs = _permute_rows(jnp.repeat(h, top_k, axis=0), order, inv)
+    live = (jnp.arange(xs.shape[0], dtype=jnp.int32)
+            < jnp.sum(groups))[:, None]
     xs = jnp.where(live, xs, jnp.zeros((), xs.dtype))
     mid = act(lax.ragged_dot(xs, gate_w, groups)) \
         * lax.ragged_dot(xs, up_w, groups)
-    ys = lax.ragged_dot(mid, down_w, groups)                # (rows, D)
-    ys = jnp.where(live, ys, jnp.zeros((), ys.dtype))
-    y = _permute_rows(ys, inv, order).reshape(-1, top_k, ys.shape[-1])
-    y = jnp.sum(y.astype(jnp.float32) * w[:, :, None], axis=1)
-    return y.astype(h.dtype), sizes
+    ys = lax.ragged_dot(mid, down_w, groups)
+    return jnp.where(live, ys, jnp.zeros((), ys.dtype))
+
+
+def buffer_rungs(rows, held, num_experts):
+    """The lengths the expert buffer may take, shortest first: the shortest
+    power-of-two fraction of `rows` (T * top_k, the dropless bound) that
+    holds twice the rows the held experts get under even routing, rounded
+    up to whole tiles of the grouped product, and `rows` itself, so that no
+    token is ever dropped.  Two lengths, not one a power of two: every
+    length carries its own grouped products and gathers in both programs,
+    and a middle one (49,152 rows in `smallthinker21b_train_gluon`) won 1.3%
+    of `tokens_per_s` for 14 s more compile and half as much again of the
+    op's code (PR 36, PERF.md section 6)."""
+    floor = 2 * rows * held / num_experts
+    k = 0
+    while rows / 2 ** (k + 1) >= floor:
+        k += 1
+    c = -(-rows // 2 ** k)
+    return tuple(sorted(
+        {rows, min(rows, -(-c // GROUPED_TILE_ROWS) * GROUPED_TILE_ROWS)}))
+
+
+def _pick(table, slot, live, weight=None):
+    """(T, D) float32: for each token the sum over its choices j of
+    table[slot[t, j]] (times weight[t, j]) where slot[t, j] < live: the
+    rows of a buffer of len(table) rows whose first `live` rows hold
+    results, read back into the tokens that sent them.  One choice at a
+    time, so each gather is T rows and fuses into the sum; no scatter (a
+    scatter of float32 rows of width 2,560 took 6-8 MB of program code
+    each, and the cell's programs outgrew the compile cache: PR 36)."""
+    out = jnp.zeros((slot.shape[0], table.shape[1]), jnp.float32)
+    for j in range(slot.shape[1]):
+        rows = table.at[jnp.minimum(slot[:, j], table.shape[0] - 1)].get(
+            mode="promise_in_bounds").astype(jnp.float32)
+        if weight is not None:
+            rows = rows * weight[:, j, None]
+        out = out + jnp.where((slot[:, j] < live)[:, None], rows, 0.0)
+    return out
+
+
+@jax.custom_vjp
+def _token_rows(x, order, slot, live):
+    """x[order // top_k]: the buffer's rows gathered straight from the
+    tokens.  The backward pass reads each token's rows back (`_pick`) and
+    adds them in float32."""
+    return x.at[order // slot.shape[1]].get(mode="promise_in_bounds")
+
+
+_token_rows.defvjp(
+    lambda x, order, slot, live: (_token_rows(x, order, slot, live),
+                                  (slot, live)),
+    lambda res, g: (_pick(g, *res).astype(g.dtype), None, None, None))
+
+
+@jax.custom_vjp
+def _combine(ys, w, order, slot, live):
+    """Each token's results weighted by its choices' weights `w` (T,
+    top_k) and added up in float32, in ys' dtype (`_pick`).  The backward
+    pass gathers the cotangent in its own dtype, a row a row of `ys`."""
+    return _pick(ys, slot, live, w).astype(ys.dtype)
+
+
+def _combine_bwd(res, g):
+    ys, w, order, slot, live = res
+    top_k = slot.shape[1]
+    g = g.at[order // top_k].get(mode="promise_in_bounds").astype(
+        jnp.float32)
+    wt = w.reshape(-1).at[order].get(mode="promise_in_bounds")
+    dwt = jnp.sum(g * ys.astype(jnp.float32), axis=1)
+    dw = jnp.where(slot < live, dwt.at[jnp.minimum(slot, ys.shape[0] - 1)]
+                   .get(mode="promise_in_bounds"), 0.0)
+    return ((g * wt[:, None]).astype(ys.dtype), dw.astype(w.dtype), None,
+            None, None)
+
+
+_combine.defvjp(
+    lambda ys, w, order, slot, live: (_combine(ys, w, order, slot, live),
+                                      (ys, w, order, slot, live)),
+    _combine_bwd)
+
+
+def _expert_rows(h, key, w, gate_w, up_w, down_w, act=jax.nn.silu, *,
+                 buffer):
+    """The held experts' part over a buffer of `buffer` rows, which must
+    hold the live ones: the (token, choice) rows sorted by `key`, the first
+    `buffer` of them gathered straight from `h` by token, the grouped
+    products, and each token's results read back from the buffer by the
+    place its choices sorted to, weighted and summed over its choices.
+    Nothing is as long as `T * top_k` rows of width D unless `buffer` is.
+    Returns (y, the rows of each held expert, of absent ones, and
+    `buffer`)."""
+    held, top_k = gate_w.shape[0], w.shape[1]
+    rows = key.shape[0]
+    order, sizes = _sorted_rows(key, held)
+    slot = jnp.zeros_like(order).at[order].set(
+        jnp.arange(rows, dtype=jnp.int32), unique_indices=True
+    ).reshape(-1, top_k)
+    live = jnp.sum(sizes[:held])
+    order = order[:buffer]
+    ys = _grouped_ffn(_token_rows(h, order, slot, live), sizes[:held],
+                      gate_w, up_w, down_w, act)
+    return _combine(ys, w, order, slot, live), jnp.append(sizes, buffer)
 
 
 # The outputs of one layer's three grouped products are rows x (2 F + D)
@@ -255,24 +348,42 @@ def _expert_rows(h, key, w, gate_w, up_w, down_w, act=jax.nn.silu):
 # (gluon/block.py _RESIDUAL_POLICY reaches into this op's own
 # `jax.checkpoint`).  Past this many bytes a layer they are not kept: the
 # backward pass runs the layer's grouped products again from the op's
-# inputs.  At 2 x 2,048 tokens, 4 a token, F 1,536, D 2,048 they are 168 MB
-# a layer and that program stays as it was; at 2 x 8,192 tokens, 6 a token,
-# F 768, D 2,560 they would be 805 MB a layer, 3.2 GB of four layers'
-# residuals, seven eighths of it rows that no held expert owns, and the step
-# does not fit the chip beside them (17.4 of 16.9 GB: v5e compiles, PR 31).
-# PROVISIONAL (PR 31): the bound lies between those two readings and no cell
-# has been measured on the other side of it.  ROADMAP S14's first step runs
-# the SiLU expert cell under `_run_again_in_backward` and keeps one mode.
+# inputs, over a buffer only as long as the rows the held experts got
+# (`buffer_rungs`).  At 2 x 2,048 tokens, 4 a token, F 1,536, D 2,048 they
+# are 168 MB a layer over the whole buffer of T x top_k rows and are kept;
+# at 2 x 8,192 tokens, 6 a token, F 768, D 2,560
+# they would be 805 MB a layer, 3.2 GB of four layers' residuals, most of it
+# rows that no held expert owns, and the step does not fit the chip beside
+# them (17.4 of 16.9 GB: v5e compiles, PR 31).  PROVISIONAL (PR 31): the
+# bound lies between those two readings and no cell has been measured on
+# the other side of it.  ROADMAP D10: run the SiLU expert cell under
+# `_run_again_in_backward` and keep one mode; a kept buffer cut to a rung
+# would keep every rung's products (a conditional that is differentiated
+# keeps the union of its branches' residuals).
 KEEP_BYTES_MAX = 2 ** 29
 
 
-def _run_again_in_backward(experts):
-    """`experts(h, key, w, gate_w, up_w, down_w) -> (y, sizes)` whose
-    backward pass starts from its inputs and nothing else, whatever an
-    enclosing `jax.checkpoint`'s policy would keep of its products."""
+def _run_again_in_backward(experts, rungs):
+    """`experts(h, key, w, gate_w, up_w, down_w, buffer=C) -> (y, sizes)`
+    on the shortest of `rungs` that holds the rows the held experts got
+    (chosen on the device from `key`, the same way forward and backward),
+    whose backward pass starts from its inputs and nothing else, whatever
+    an enclosing `jax.checkpoint`'s policy would keep of its products.  The
+    choice is made around the whole backward of a rung, never inside what
+    is differentiated: a conditional that is differentiated keeps the
+    union of every branch's residuals."""
+    def on_rung(key, held, run):
+        if len(rungs) == 1:
+            return run(rungs[0])
+        live = jnp.sum(key < held, dtype=jnp.int32)
+        i = jnp.sum(live > jnp.asarray(rungs[:-1], jnp.int32),
+                    dtype=jnp.int32)
+        return lax.switch(i, [functools.partial(run, c) for c in rungs])
+
     @jax.custom_vjp
     def call(h, key, w, *weights):
-        return experts(h, key, w, *weights)
+        return on_rung(key, weights[0].shape[0], lambda c: experts(
+            h, key, w, *weights, buffer=c))
 
     def fwd(*args):
         return call(*args), args
@@ -283,8 +394,13 @@ def _run_again_in_backward(experts):
         # of the backward program and hold them all until their turn (12.6
         # GB of temporaries against 6.9: v5e compiles, PR 31)
         (h, key, *rest), dy = lax.optimization_barrier((args, cots[0]))
-        dh, *drest = jax.vjp(
-            lambda h_, *rest_: experts(h_, key, *rest_)[0], h, *rest)[1](dy)
+
+        def grads(c):
+            return tuple(jax.vjp(
+                lambda h_, *rest_: experts(h_, key, *rest_, buffer=c)[0],
+                h, *rest)[1](dy))
+
+        dh, *drest = on_rung(key, rest[1].shape[0], grads)
         return (dh, None, *drest)  # the rows' keys are integers
 
     call.defvjp(fwd, bwd)
@@ -308,6 +424,10 @@ def _moe(p, x, routed_by, router_w, bias, gate_w, up_w, down_w, load):
     if p["activation"] not in ACTIVATIONS:
         raise ValueError(f"moe_ffn activation={p['activation']!r}: choose "
                          f"one of {sorted(ACTIVATIONS)}")
+    if load.shape != (held + 2,):
+        raise ValueError(f"moe_ffn: load must be ({held + 2},): each held "
+                         f"expert's rows, absent ones', the buffer's; got "
+                         f"{load.shape}")
     if routed_by.shape[:-1] != x.shape[:-1]:
         raise ValueError(f"moe_ffn: the router's input {routed_by.shape} "
                          f"and the experts' {x.shape} differ in tokens")
@@ -326,17 +446,17 @@ def _moe(p, x, routed_by, router_w, bias, gate_w, up_w, down_w, load):
     # split rounded out to whole tiles, never more than the buffer
     _metrics.MOE_ROWS.set(
         min(rows, required + held * GROUPED_TILE_ROWS), kind="multiplied")
-    # the function itself where it can be: `jax.checkpoint` of a `partial`
-    # traces to another program text, and the SiLU cells keep theirs
-    experts = _expert_rows if p["activation"] == "silu" else \
-        functools.partial(_expert_rows, act=ACTIVATIONS[p["activation"]])
     kept = rows * (gate_w.shape[2] + up_w.shape[2] + down_w.shape[2]) \
         * x.dtype.itemsize
     if kept > KEEP_BYTES_MAX:
-        y, sizes = _run_again_in_backward(experts)(
-            h, key, w, gate_w, up_w, down_w)
+        y, sizes = _run_again_in_backward(
+            functools.partial(_expert_rows,
+                              act=ACTIVATIONS[p["activation"]]),
+            buffer_rungs(rows, held, E))(h, key, w, gate_w, up_w, down_w)
     else:
-        y, sizes = jax.checkpoint(experts)(h, key, w, gate_w, up_w, down_w)
+        y, sizes = jax.checkpoint(functools.partial(
+            _expert_rows, act=ACTIVATIONS[p["activation"]], buffer=rows))(
+                h, key, w, gate_w, up_w, down_w)
     new_load = load + sizes.astype(load.dtype)
     return (y.reshape(x.shape), lax.stop_gradient(bias),
             lax.stop_gradient(new_load))
@@ -354,8 +474,9 @@ def _moe_ffn(p, x, router_w, bias, gate_w, up_w, down_w, load):
     (num_experts,), auxiliary, added to the scores for the selection only;
     gate_weight, up_weight (held, D, F) and down_weight (held, F, D), the
     stacked matrices of experts `first .. first + held - 1`; load
-    (held + 1,), auxiliary float32: the forward pass adds the assignments
-    each held expert got and, last, those that fell on absent experts.
+    (held + 2,), auxiliary float32: the forward pass adds the assignments
+    each held expert got, then those that fell on absent experts, and last
+    the rows of the buffer its grouped products ran over.
 
     Routes every token over all `num_experts`, `top_k` a token (`router`:
     'sigmoid' scores normalised over the chosen, or 'softmax_topk', a
@@ -363,13 +484,15 @@ def _moe_ffn(p, x, router_w, bias, gate_w, up_w, down_w, load):
     sum_i w_i E_i(x) over the chosen experts that are held, E_i(x) =
     down_i(act(gate_i x) * up_i x) with `activation` 'silu' or 'relu': a
     partial result where held < num_experts.  Dropless: the buffer has
-    `T * top_k` rows, so every token sent to one held expert still equals
-    the reference.  The feed-forward's intermediates are recomputed in the
-    backward pass (`jax.checkpoint`), as jobs that fill the chip do; under
-    a caller's own `jax.checkpoint` (a recorded CachedOp call) the caller's
-    policy decides instead, and keeps the grouped products, unless their
-    outputs pass `KEEP_BYTES_MAX` a layer: then the backward pass runs them
-    again from the op's inputs whoever calls.
+    room for `T * top_k` rows, so every token sent to one held expert still
+    equals the reference.  The feed-forward's intermediates are recomputed
+    in the backward pass (`jax.checkpoint`), as jobs that fill the chip do;
+    under a caller's own `jax.checkpoint` (a recorded CachedOp call) the
+    caller's policy decides instead, and keeps the grouped products, unless
+    their outputs pass `KEEP_BYTES_MAX` a layer: then the backward pass runs
+    them again from the op's inputs whoever calls, over a buffer only as
+    long as the shortest of `buffer_rungs` that holds the rows the held
+    experts got.
     """
     return _moe(p, x, x, router_w, bias, gate_w, up_w, down_w, load)
 

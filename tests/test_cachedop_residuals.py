@@ -267,3 +267,49 @@ def test_forward_program_writes_no_copy_of_an_input(case, fixed_key):
     size = compiled.memory_analysis().output_size_in_bytes
     payload = sum(a.nbytes for a in written)
     assert payload <= size <= payload + 8 * len(written), (size, payload)
+
+
+@pytest.mark.parametrize("which", NETS)
+def test_a_call_places_its_arrays_once_and_compiles_once(monkeypatch, which):
+    """Arrays made on the host (`jnp.asarray`: an initializer's, `cast`'s,
+    `set_data`'s) are not committed to a device, the update's outputs that
+    replace the parameters are, and `jit` keys a call on it.  A CachedOp
+    call commits what it reads where it lies (the same buffer) and keeps
+    what it committed, so the next call places nothing and each recorded
+    program is compiled once over the steps (PERF.md Open questions 13)."""
+    net, x = _build(which)
+    op = net._cached_op
+    params = list(net.collect_params().values())
+    for p in params:  # as they come from an initializer
+        p.data()._data = jnp.asarray(np.asarray(p.data()._data))
+    x2 = mx.nd.NDArray(jnp.asarray(x.asnumpy()), x.context)
+    arrays = [x2] + [p.data() for p in params]
+    assert not any(a._data._committed for a in arrays)
+    where = [a._data.unsafe_buffer_pointer() for a in arrays]
+    trainer = mx.gluon.Trainer(net.collect_params(), "sgd",
+                               {"learning_rate": 0.01})
+    sizes, placed = [], []
+    put = jax.device_put
+
+    def counted(*a, **k):
+        placed[-1] += 1
+        return put(*a, **k)
+
+    monkeypatch.setattr(jax, "device_put", counted)
+    for step in range(3):
+        placed.append(0)
+        with autograd.record():
+            out = net(x2)
+        if step == 0:
+            assert all(a._data._committed for a in arrays)
+            # the auxiliary states are the call's new ones
+            assert [a._data.unsafe_buffer_pointer() for a, p in
+                    zip(arrays, [None] + params)
+                    if p is None or p.grad_req != "null"] == \
+                [w for w, p in zip(where, [None] + params)
+                 if p is None or p.grad_req != "null"]
+        out.backward()
+        trainer.step(1)
+        sizes.append((op._fwd._cache_size(), op._bwd._cache_size()))
+    assert placed[0] == len(arrays) and placed[1:] == [0, 0], placed
+    assert sizes[0] == sizes[1] == sizes[2], sizes

@@ -417,7 +417,7 @@ def _expert_inputs(seed, tokens=48, held=4, bias=None, kit=KITS["sigmoid_silu"])
         gate=_rand(rs, held, d, f, scale=0.2),
         up=_rand(rs, held, d, f, scale=0.2),
         down=_rand(rs, held, f, d, scale=0.2),
-        load=np.zeros(held + 1, "float32"))
+        load=np.zeros(held + 2, "float32"))
 
 
 def _moe(a, first=0, held=4, record=False, kit=KITS["sigmoid_silu"]):
@@ -497,7 +497,8 @@ def test_every_token_on_one_held_expert_loses_none(kit):
     bias[[1, 2]] = 50.0
     a = _expert_inputs(8, tokens=64, bias=bias, kit=kit)
     y, load = _moe(a, kit=kit)
-    np.testing.assert_array_equal(load, [0, 64, 64, 0, 0])
+    # and the buffer held every row: this layer keeps its products
+    np.testing.assert_array_equal(load, [0, 64, 64, 0, 0, 64 * kit.k])
     if kit.shared:  # the other reference has no selection bias to give
         _close(y, _ref_routed(a, kit=kit), rtol=1e-3, atol=1e-4)
     assert (np.abs(y).max(axis=1) > 0).all()  # no token came back empty
@@ -519,7 +520,7 @@ def test_shares_add_up_to_the_uncut_layer(kit, held):
     total = np.zeros_like(full["h"])
     loads = []
     for first in range(0, kit.e, held):
-        share = dict(full, load=np.zeros(held + 1, "float32"),
+        share = dict(full, load=np.zeros(held + 2, "float32"),
                      **{k: full[k][first:first + held]
                         for k in ("gate", "up", "down")})
         y, load = _moe(share, first=first, held=held, kit=kit)
@@ -552,10 +553,11 @@ def test_load_counter_splits_held_and_absent_as_the_routing_does(kit):
     _y, load = _moe(a, first=2, kit=kit)
     idx, _w = kit.routing(jnp.asarray(a["h"]), jnp.asarray(a["router"]))
     idx = np.asarray(idx)
-    assert load.sum() == 48 * kit.k
+    assert load[:5].sum() == 48 * kit.k
     want = [(idx == e).sum() for e in range(2, 6)]
     np.testing.assert_array_equal(load[:4], want)
     assert load[4] == 48 * kit.k - sum(want)
+    assert load[5] == 48 * kit.k  # the buffer's rows: the whole T x top_k
 
 
 # -- the second router, its own input, the other gate -------------------------
@@ -636,7 +638,7 @@ def test_relu_gate_is_not_the_silu_gate():
     # by hand, one expert for every token: down(relu(gate x) * up x)
     # all logits equal: top_k picks experts 0..k-1, each weighs 1 / k
     one = dict(a, router=np.zeros_like(a["router"]),
-               load=np.zeros(2, "float32"),
+               load=np.zeros(3, "float32"),
                **{k: a[k][:1] for k in ("gate", "up", "down")})
     y, _ = _moe(one, held=1, kit=kit)
     x = a["h"]
@@ -717,6 +719,7 @@ def test_block_counters_ride_the_auxiliary_path_and_stay_float32(monkeypatch):
     states; the registry reads them when asked."""
     monkeypatch.setattr(metrics, "_moe_layers", weakref.WeakKeyDictionary())
     metrics.MOE_ASSIGNMENTS.reset()
+    metrics.MOE_BUFFER_ROWS.reset()
     blk = decoder.MoEFeedForward(D, F, E, K, held_experts=4, first_expert=0,
                                  routed_scale=SCALE)
     blk.initialize(mx.init.Normal(0.3))
@@ -733,10 +736,14 @@ def test_block_counters_ride_the_auxiliary_path_and_stay_float32(monkeypatch):
         y.backward()
     load = blk.load.data().asnumpy()
     assert load.dtype == np.float32
-    assert load.sum() == 3 * 200 * K and load[:4].sum() > 256
+    assert load.shape == (6,)
+    assert load[:5].sum() == 3 * 200 * K and load[:4].sum() > 256
+    assert load[5] == 3 * 200 * K  # a layer that keeps its products
     metrics.refresh_moe()
     assert metrics.MOE_ASSIGNMENTS.get(where="held") == load[:4].sum()
     assert metrics.MOE_ASSIGNMENTS.get(where="absent") == load[4]
+    assert metrics.MOE_BUFFER_ROWS.get(kind="live") == load[:4].sum()
+    assert metrics.MOE_BUFFER_ROWS.get(kind="processed") == load[5]
     assert metrics.MOE_ROWS.get(kind="required") == 200 * K * 4 / E
     assert metrics.MOE_ROWS.get(kind="multiplied") <= 200 * K
     metrics.refresh_moe()  # a second read adds what came since: nothing
@@ -944,7 +951,8 @@ def test_expert_load_reader(monkeypatch):
     monkeypatch.setattr(metrics, "_moe_layers", weakref.WeakKeyDictionary())
     blk = decoder.MoEFeedForward(D, F, E, K, held_experts=4)
     blk.initialize()
-    blk.load.set_data(nd.array(np.array([10., 30., 10., 10., 99.], "f")))
+    blk.load.set_data(nd.array(np.array([10., 30., 10., 10., 99., 480.],
+                                        "f")))
     assert rd.read({}) == pytest.approx(2.0)
     del blk
     gc.collect()
