@@ -40,6 +40,13 @@ cross-entropy of every exit weighted by the gates' exit distribution
 (`ExitDistribution`), less `beta` times that distribution's entropy, one
 exit's float32 logits alive at a time.
 
+`ZayaLM` stacks `ZayaBlock`s: compressed convolutional attention
+(`CompressedConvAttention`), an MLP router (`MLPRouter`) whose state each
+layer hands to the next through the graph, top-k experts beside a skip
+choice that no device holds, learned scales on the residual and on each
+sub-layer's output, and a head tied to the embedding (`TiedHead`) whose
+loss walks the vocabulary in blocks.
+
 The whole stack is one HybridBlock, so a step is one CachedOp forward and
 one backward, as `TransformerLM`'s is.  There is no decode path yet
 (ROADMAP R1 / R6: a latent leaf, a window's ring and a global layer's
@@ -47,6 +54,8 @@ table in the decode cache; for a looped model a cache leaf a loop step and
 layer, and exits that leave a batch ragged in depth).
 """
 from __future__ import annotations
+
+import functools
 
 from ...observability import metrics as _metrics
 from .. import nn
@@ -158,20 +167,34 @@ class MoEFeedForward(HybridBlock):
     absent ones, and last the rows of the buffer the grouped products ran
     over (read by `observability.metrics.refresh_moe`).
     router, activation: as `moe_ffn` has them.  Called with a second
-    input, the router scores that and the experts read the first."""
+    input, the router scores that and the experts read the first.
+
+    scored: the block has no router weight; its second input is the
+    router's logits (..., num_experts), computed by the caller (an MLP
+    router), and `moe_ffn_scored` routes by them.  skip: the last of the
+    `num_experts` choices is a skip that no device holds; `load` is then
+    (held + 3,), a slot for the skipped tokens before the buffer's."""
 
     def __init__(self, dim, ffn_dim, num_experts, top_k, held_experts=None,
                  first_expert=0, shared_experts=1, routed_scale=1.0,
-                 norm_topk=True, router="sigmoid", activation="silu", **kw):
+                 norm_topk=True, router="sigmoid", activation="silu",
+                 scored=False, skip=False, **kw):
         super().__init__(**kw)
         held = num_experts if held_experts is None else held_experts
+        self._scored, self.skip_choice = scored, skip
         self._moe = dict(num_experts=num_experts, top_k=top_k,
                          first=first_expert, held=held, scale=routed_scale,
                          norm_topk=norm_topk, router=router,
                          activation=activation)
+        if scored:
+            self._moe["skip"] = skip
+        elif skip:
+            raise ValueError("MoEFeedForward: a skip choice needs the "
+                             "router's scores (scored=True)")
         with self.name_scope():
-            self.router_weight = self.params.get(
-                "router_weight", shape=(num_experts, dim))
+            if not scored:
+                self.router_weight = self.params.get(
+                    "router_weight", shape=(num_experts, dim))
             self.select_bias = self.params.get(
                 "select_bias", shape=(num_experts,), init="zeros",
                 grad_req="null", differentiable=False)
@@ -182,8 +205,8 @@ class MoEFeedForward(HybridBlock):
             self.down_weight = self.params.get(
                 "down_weight", shape=(held, ffn_dim, dim))
             self.load = self.params.get(
-                "load", shape=(held + 2,), init="zeros", grad_req="null",
-                differentiable=False)
+                "load", shape=(held + 2 + skip,), init="zeros",
+                grad_req="null", differentiable=False)
             self.shared = GatedFeedForward(
                 dim, ffn_dim * shared_experts, prefix="shared_") \
                 if shared_experts else None
@@ -197,10 +220,14 @@ class MoEFeedForward(HybridBlock):
     def hybrid_forward(self, F, x, routed_by=None, router_weight=None,
                        select_bias=None, gate_weight=None, up_weight=None,
                        down_weight=None, load=None):
-        rest = (router_weight, select_bias, gate_weight, up_weight,
-                down_weight, load)
-        y = F.moe_ffn(x, *rest, **self._moe) if routed_by is None else \
-            F.moe_ffn_routed_by(x, routed_by, *rest, **self._moe)
+        experts = (select_bias, gate_weight, up_weight, down_weight, load)
+        if self._scored:
+            y = F.moe_ffn_scored(x, routed_by, *experts, **self._moe)
+        elif routed_by is None:
+            y = F.moe_ffn(x, router_weight, *experts, **self._moe)
+        else:
+            y = F.moe_ffn_routed_by(x, routed_by, router_weight, *experts,
+                                    **self._moe)
         return y if self.shared is None else y + self.shared(x)
 
 
@@ -440,3 +467,205 @@ class LoopedLMLoss(HybridBlock):
         per_token = F.sum(p * ce, axis=0) + self._beta * F.sum(p * logp,
                                                                axis=0)
         return F.mean(per_token, axis=1)
+
+
+class CompressedConvAttention(HybridBlock):
+    """Compressed convolutional attention (arXiv:2510.04476, section 3)
+    over (B, T, D): `num_heads` query and `num_kv_heads` key/value heads of
+    `head_dim`, no biases on the projections.
+
+    Queries and keys are projected, then mixed (op `cca_mix`): a causal
+    convolution over time, one filter a channel, of `conv_taps[0]` taps; a
+    causal convolution over time and the channels of each head, of
+    `conv_taps[1]` taps; the pre-mix mean of a group's query heads and its
+    key head added back; L2 norms scaled to sqrt(head_dim), the keys' by a
+    learned `temperature` a key/value head; rotary positions over the first
+    `rope_dims` of every head.  The values are two projections of half the
+    key/value width, the second read one token later.  Attention is
+    `grouped_query_attention` without positions of its own (attn_type
+    'dense' | 'flash': the Pallas kernels), then one projection back up."""
+
+    def __init__(self, dim, num_heads, num_kv_heads, head_dim, rope_dims,
+                 rope_base=10000.0, conv_taps=(2, 2), attn_type="dense",
+                 **kw):
+        super().__init__(**kw)
+        if num_kv_heads % 2:
+            raise ValueError("CompressedConvAttention: half the value heads "
+                             "read the token before, so the key/value heads "
+                             f"must be even, got {num_kv_heads}")
+        width = (num_heads + num_kv_heads) * head_dim
+        self._mix = dict(num_heads=num_heads, num_kv_heads=num_kv_heads,
+                         rope_dims=rope_dims, rope_base=rope_base)
+        self._attn = dict(num_heads=num_heads, num_kv_heads=num_kv_heads,
+                          rope=False, impl=attn_type)
+        half = num_kv_heads * head_dim // 2
+        with self.name_scope():
+            self.conv0_weight = self.params.get(
+                "conv0_weight", shape=(conv_taps[0], width))
+            self.conv0_bias = self.params.get("conv0_bias", shape=(width,),
+                                              init="zeros")
+            self.conv1_weight = self.params.get(
+                "conv1_weight", shape=(num_heads + num_kv_heads,
+                                       conv_taps[1], head_dim, head_dim))
+            self.conv1_bias = self.params.get("conv1_bias", shape=(width,),
+                                              init="zeros")
+            self.temperature = self.params.get(
+                "temperature", shape=(num_kv_heads,), init="ones")
+            self.q = _dense(num_heads * head_dim, dim, "q_")
+            self.k = _dense(num_kv_heads * head_dim, dim, "k_")
+            self.va = _dense(half, dim, "va_")
+            self.vb = _dense(half, dim, "vb_")
+            self.proj = _dense(dim, num_heads * head_dim, "proj_")
+
+    def hybrid_forward(self, F, x, conv0_weight, conv0_bias, conv1_weight,
+                       conv1_bias, temperature):
+        q, k, v = F.cca_mix(self.q(x), self.k(x), self.va(x), self.vb(x),
+                            conv0_weight, conv0_bias, conv1_weight,
+                            conv1_bias, temperature, **self._mix)
+        return self.proj(F.grouped_query_attention(q, k, v, **self._attn))
+
+
+class MLPRouter(HybridBlock):
+    """A router that is an MLP (ops `mlp_router`, `mlp_router_carried`, in
+    float32 whatever the net is cast to): x (..., D) -> (logits (..., E),
+    state (..., R)).  `carried`: the block takes the previous layer's state
+    as a second input and adds it, times a learned `state_scale` (R,), to
+    its own (exponential depth averaging); the first layer's router has
+    neither."""
+
+    def __init__(self, dim, router_dim, num_outputs, carried, epsilon=1e-5,
+                 **kw):
+        super().__init__(**kw)
+        self._carried, self._eps = carried, epsilon
+        with self.name_scope():
+            self.down_weight = self.params.get("down_weight",
+                                               shape=(router_dim, dim))
+            self.down_bias = self.params.get("down_bias", shape=(router_dim,),
+                                             init="zeros")
+            if carried:
+                self.state_scale = self.params.get(
+                    "state_scale", shape=(router_dim,), init="ones")
+            self.norm_gamma = self.params.get(
+                "norm_gamma", shape=(router_dim,), init="ones")
+            self.w1 = self.params.get("w1", shape=(router_dim, router_dim))
+            self.w2 = self.params.get("w2", shape=(router_dim, router_dim))
+            self.w3 = self.params.get("w3", shape=(num_outputs, router_dim))
+
+    def hybrid_forward(self, F, x, state=None, down_weight=None,
+                       down_bias=None, state_scale=None, norm_gamma=None,
+                       w1=None, w2=None, w3=None):
+        if self._carried:
+            return F.mlp_router_carried(x, state, down_weight, down_bias,
+                                        state_scale, norm_gamma, w1, w2, w3,
+                                        eps=self._eps)
+        return F.mlp_router(x, down_weight, down_bias, norm_gamma, w1, w2,
+                            w3, eps=self._eps)
+
+
+class ZayaBlock(HybridBlock):
+    """A layer of attention and experts whose router carries a state from
+    layer to layer, with learned scales on the residual and on each
+    sub-layer's output:
+
+        x' = a1 * x + b1 * attn(n1(x))
+        w = n2(x');  (logits, state) = router(w, previous state)
+        out = a2 * x' + b2 * ffn(w, logits)
+
+    a1, b1, a2, b2 (D,), ones at first.  `ffn` is a scored
+    `MoEFeedForward`.  (x, state) -> (out, state); the first layer is
+    called with x alone."""
+
+    def __init__(self, attn, router, ffn, dim, epsilon=1e-5, **kw):
+        super().__init__(**kw)
+        with self.name_scope():
+            for name in ("attn_in_scale", "attn_out_scale", "ffn_in_scale",
+                         "ffn_out_scale"):
+                setattr(self, name, self.params.get(name, shape=(dim,),
+                                                    init="ones"))
+            self.n1 = RMSNorm(dim, epsilon, prefix="n1_")
+            self.attn = attn(prefix="attn_")
+            self.n2 = RMSNorm(dim, epsilon, prefix="n2_")
+            self.router = router(prefix="router_")
+            self.ffn = ffn(prefix="ffn_")
+
+    def hybrid_forward(self, F, x, state=None, attn_in_scale=None,
+                       attn_out_scale=None, ffn_in_scale=None,
+                       ffn_out_scale=None):
+        x = F.broadcast_mul(x, attn_in_scale) + F.broadcast_mul(
+            self.attn(self.n1(x)), attn_out_scale)
+        w = self.n2(x)
+        logits, state = self.router(w) if state is None else \
+            self.router(w, state)
+        return F.broadcast_mul(x, ffn_in_scale) + F.broadcast_mul(
+            self.ffn(w, logits), ffn_out_scale), state
+
+
+class TiedHead(HybridBlock):
+    """An output head whose matrix is an embedding's (build it with
+    `params=embedding.params`): h (..., D) -> logits (..., V); called with
+    labels too, each token's cross-entropy (op `blocked_cross_entropy`,
+    float32, no logits held)."""
+
+    def __init__(self, vocab, dim, **kw):
+        super().__init__(**kw)
+        self._vocab = vocab
+        self.weight = self.params.get("weight", shape=(vocab, dim))
+
+    def hybrid_forward(self, F, h, labels=None, weight=None):
+        if labels is None:
+            return F.FullyConnected(h, weight, no_bias=True,
+                                    num_hidden=self._vocab, flatten=False)
+        return F.blocked_cross_entropy(h, weight, labels)
+
+
+class ZayaLM(HybridBlock):
+    """Token ids (B, T) -> logits (B, T, vocab): `ZayaBlock` layers of
+    compressed convolutional attention (`attention`: a factory called with
+    `prefix=`) and top-`top_k` experts with a skip choice, routed by MLP
+    routers (width `router_dim`) that hand their state from layer to layer
+    through the graph; the head is the embedding (`TiedHead`), no
+    embedding scale, no biases.  Of the `num_experts` routed experts and
+    the skip, this device holds `held_experts` from `first_expert` on
+    (ops/decoder.py `moe_ffn_scored`).  `hidden(F, tokens)` is the final
+    norm's output; `head(h, labels)` the per-token loss."""
+
+    def __init__(self, vocab, dim, num_layers, attention, expert_ffn_dim,
+                 num_experts, held_experts=None, first_expert=0, top_k=1,
+                 router_dim=256, epsilon=1e-5, **kw):
+        super().__init__(**kw)
+
+        def router(carried):
+            return functools.partial(MLPRouter, dim, router_dim,
+                                     num_experts + 1, carried, epsilon)
+
+        def ffn(prefix):
+            return MoEFeedForward(
+                dim, expert_ffn_dim, num_experts + 1, top_k, held_experts,
+                first_expert, shared_experts=0, norm_topk=False,
+                router="softmax_topk", scored=True, skip=True, prefix=prefix)
+
+        with self.name_scope():
+            self.tok = nn.Embedding(vocab, dim, prefix="tok_")
+            self.blocks = nn.HybridSequential(prefix="blocks_")
+            for i in range(num_layers):
+                self.blocks.add(ZayaBlock(attention, router(i > 0), ffn, dim,
+                                          epsilon, prefix=f"l{i}_"))
+            self.norm_f = RMSNorm(dim, epsilon, prefix="normf_")
+            self.head = TiedHead(vocab, dim, params=self.tok.params)
+
+    def hidden(self, F, tokens):
+        x, state = self.tok(tokens), None
+        for blk in self.blocks:
+            x, state = blk(x) if state is None else blk(x, state)
+        return self.norm_f(x)
+
+    def hybrid_forward(self, F, tokens):
+        return self.head(self.hidden(F, tokens))
+
+    def generate(self, *args, **kwargs):
+        raise NotImplementedError(
+            "ZayaLM has no decode path yet: its cache would hold, beside "
+            "K and V, the convolutions' last tokens and the shifted value "
+            "heads' previous token (ROADMAP R1 / R6)")
+
+    _kv_forward = generate

@@ -142,8 +142,11 @@ FULL_STEP_PHASES = frozenset({"whole_step", "superstep"})
 
 #: scopes opened straight by ``jax.named_scope`` around a kernel call
 #: (ops/flash_attention.py: the kernels' trace events are named after
-#: them), known here so that op_scopes() can fall back on them
-KERNEL_SCOPES = ("flash_attention_bwd", "flash_bwd_dkv", "flash_bwd_dq")
+#: them) or an op's body (ops/decoder.py ``cca_mix``,
+#: ``blocked_cross_entropy``), known here so that op_scopes() can fall
+#: back on them
+KERNEL_SCOPES = ("flash_attention_bwd", "flash_bwd_dkv", "flash_bwd_dq",
+                 "cca_mix", "blocked_cross_entropy")
 
 _lock = _san.make_lock("introspect.programs")
 _programs: Dict[str, dict] = {}

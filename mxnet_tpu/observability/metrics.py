@@ -691,7 +691,9 @@ MOE_ASSIGNMENTS = Counter(
     "(token, choice) pairs the routers of this process's expert layers "
     "assigned, by where the chosen expert lives (held = one of the experts "
     "this device holds, absent = an expert of another device, whose part "
-    "of the result is not computed here).  Filled from the layers' "
+    "of the result is not computed here, skipped = a layer's skip choice, "
+    "which no device holds and which adds nothing; neither of the last two "
+    "enters mxnet_moe_expert_load_max_over_mean).  Filled from the layers' "
     "device-side load counters at export (one device read), never in the "
     "step")
 MOE_LOAD_MAX_OVER_MEAN = Gauge(
@@ -805,7 +807,8 @@ def _read_states(blocks, attr):
 def refresh_moe() -> None:
     """Pull the load counters of every live expert layer (one stacked
     device read; a row is each held expert's assignments, absent ones',
-    the buffer's rows) into MOE_ASSIGNMENTS, MOE_BUFFER_ROWS and
+    skipped ones' where the layer has a skip choice, the buffer's rows)
+    into MOE_ASSIGNMENTS, MOE_BUFFER_ROWS and
     MOE_LOAD_MAX_OVER_MEAN."""
     import numpy as np
     states = _read_states(list(_moe_layers), "load")
@@ -817,12 +820,16 @@ def refresh_moe() -> None:
         delta = row - last if last is not None and (row >= last).all() \
             else row
         _moe_layers[block] = row
-        live = float(delta[:-2].sum())
+        # a layer with a skip choice has one slot more, before the buffer's
+        held = row.shape[0] - 2 - bool(getattr(block, "skip_choice", False))
+        live = float(delta[:held].sum())
         MOE_ASSIGNMENTS.inc(live, where="held")
-        MOE_ASSIGNMENTS.inc(float(delta[-2]), where="absent")
+        MOE_ASSIGNMENTS.inc(float(delta[held]), where="absent")
+        if held + 2 < row.shape[0]:
+            MOE_ASSIGNMENTS.inc(float(delta[held + 1]), where="skipped")
         MOE_BUFFER_ROWS.inc(live, kind="live")
         MOE_BUFFER_ROWS.inc(float(delta[-1]), kind="processed")
-        held_all.append(row[:-2])
+        held_all.append(row[:held])
     held_all = np.concatenate(held_all)
     if held_all.sum() > 0:
         MOE_LOAD_MAX_OVER_MEAN.set(float(held_all.max() / held_all.mean()))

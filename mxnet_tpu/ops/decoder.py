@@ -1,10 +1,14 @@
 """Operators of a current decoder block: RMS norm, rotary positions, two
 attention assemblies (latent keys and values at one head count;
 grouped-query heads with rotary positions or none and a sliding window or
-none), and a mixture-of-experts feed-forward that is told which experts it
-holds and how its model routes (sigmoid scores with a selection bias, or a
-softmax over the chosen logits; a SiLU or a ReLU gate; the router reading
-the experts' input or a tensor of its own).
+none), the mixing of compressed convolutional attention (`cca_mix`), an
+MLP router that carries a state from layer to layer, a mixture-of-experts
+feed-forward that is told which experts it holds and how its model routes
+(sigmoid scores with a selection bias, or a softmax over the chosen logits;
+a SiLU or a ReLU gate; the router reading the experts' input, a tensor of
+its own, or scores the block computed, with a skip choice or none), and a
+cross-entropy over an output matrix that walks the vocabulary in blocks
+(`blocked_cross_entropy`).
 
 Registered as ops (not python in the gluon blocks) for the reason
 `_contrib_multihead_attention` is: the head splits, the rotation tables and
@@ -37,11 +41,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..base import Arg
 from ..observability import metrics as _metrics
 from .flash_attention import _dense_reference, _flash_attention
-from .registry import register
+from .registry import RESIDUAL_NAME, register
 
 # rows of one tile of the TPU's grouped product (the v5e compile of
 # `lax.ragged_dot` carries metadata of m / 512 + groups - 1 tile visits)
@@ -180,13 +185,109 @@ def _grouped_query_attention(p, q, k, v):
     return out.transpose(0, 2, 1, 3).reshape(B, T, H * D)
 
 
+def _shift(x, steps=1):
+    """`x` moved `steps` positions later along axis 1 (time): position t
+    holds t - steps, the first `steps` positions zeros (causal: nothing
+    before t = 0)."""
+    if steps == 0:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[1] = (steps, 0)
+    return lax.slice_in_dim(jnp.pad(x, pad), 0, x.shape[1], axis=1)
+
+
+# under the square root of a query's or key's L2 norm, so that a head of
+# zeros gives zeros and not a NaN
+L2_EPS = 1e-12
+
+
+@register("_contrib_cca_mix",
+          input_names=("q", "k", "va", "vb", "conv0_weight", "conv0_bias",
+                       "conv1_weight", "conv1_bias", "temperature"),
+          aliases=("cca_mix",), num_outputs=3,
+          args=[Arg("num_heads", int, required=True),
+                Arg("num_kv_heads", int, required=True),
+                Arg("rope_dims", int, 0), Arg("rope_base", float, 10000.0)])
+def _cca_mix(p, q, k, va, vb, w0, b0, w1, b1, temp):
+    """The mixing of compressed convolutional attention (arXiv:2510.04476,
+    section 3): from the projected queries and keys to the ones attention
+    reads.
+
+    q: (B, T, H * d); k: (B, T, G * d); va, vb: (B, T, G * d / 2), the two
+    halves of the values; conv0_weight (K0, (H + G) d), conv0_bias
+    ((H + G) d,): a causal convolution over time, one filter a channel, tap
+    j reading position t - j; conv1_weight (H + G, K1, d, d) [head, tap,
+    out, in], conv1_bias ((H + G) d,): a causal convolution over time and
+    the channels of one head; temperature (G,).  With c = [q | k]:
+
+        c1 = sum_j w0[j] c_{t-j} + b0;  c2^h = sum_j w1[h, j] c1^h_{t-j} + b1^h
+        m_g = (mean of the query heads of group g + key head g) / 2
+        q_j = c2^j + m_g(j),  k_g = c2^(H+g) + m_g
+        q <- sqrt(d) q / |q|,  k <- sqrt(d) temperature_g k / |k|
+
+    then rotary positions (`rope_base`, split halves) over the first
+    `rope_dims` of every head, the rest untouched.  v = [va | vb one
+    position later]: the first G/2 value heads read the token, the last
+    G/2 the one before it.  Returns (q, k, v) in q's dtype; the
+    convolution over channels multiplies in that dtype with float32 sums,
+    everything else is float32."""
+    B, T, _ = q.shape
+    H, G, r = p["num_heads"], p["num_kv_heads"], p["rope_dims"]
+    d = q.shape[-1] // H
+    if H % G or k.shape[-1] != G * d or w1.shape[2:] != (d, d) \
+            or va.shape[-1] + vb.shape[-1] != G * d or r > d or r % 2:
+        raise ValueError(
+            f"cca_mix: {H} query heads of {d} over {G} key/value heads, "
+            f"rope_dims {r}: got k {k.shape}, va {va.shape}, vb {vb.shape}, "
+            f"conv1_weight {w1.shape}")
+    f32, dt = jnp.float32, q.dtype
+    with jax.named_scope("cca_mix"):
+        c = jnp.concatenate([q, k], axis=-1).astype(f32)
+        c1 = sum(w0[j].astype(f32) * _shift(c, j)
+                 for j in range(w0.shape[0])) + b0.astype(f32)
+        c1 = c1.astype(dt).reshape(B, T, H + G, d)
+        c2 = sum(jnp.einsum("bthi,hoi->btho", _shift(c1, j), w1[:, j],
+                            preferred_element_type=f32)
+                 for j in range(w1.shape[1]))
+        c2 = c2 + b1.astype(f32).reshape(H + G, d)
+        q0 = q.astype(f32).reshape(B, T, G, H // G, d)
+        k0 = k.astype(f32).reshape(B, T, G, d)
+        m = 0.5 * (jnp.mean(q0, axis=3) + k0)
+        qh = c2[:, :, :H].reshape(B, T, G, H // G, d) + m[:, :, :, None]
+        kh = c2[:, :, H:] + m
+
+        def unit(x):
+            return x * lax.rsqrt(jnp.sum(jnp.square(x), axis=-1,
+                                         keepdims=True) + L2_EPS)
+
+        qh = unit(qh) * d ** 0.5
+        kh = unit(kh) * (d ** 0.5 * temp.astype(f32)[:, None])
+        if r:
+            qh = jnp.concatenate([_rope(qh[..., :r], p["rope_base"]),
+                                  qh[..., r:]], axis=-1)
+            kh = jnp.concatenate([_rope(kh[..., :r], p["rope_base"]),
+                                  kh[..., r:]], axis=-1)
+        v = jnp.concatenate([va, _shift(vb)], axis=-1)
+    return (qh.reshape(B, T, H * d).astype(dt),
+            kh.reshape(B, T, G * d).astype(dt), v)
+
+
 ROUTERS = ("sigmoid", "softmax_topk")
 ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def route(h, router_w, bias, top_k, scale, norm_topk, router="sigmoid"):
-    """(chosen experts (T, k) int32, their weights (T, k) float32), the
-    router's product and everything after it in float32.
+    """`select` over the logits of a linear router: `h` (T, D) scored by
+    `router_w` (E, D), the product in float32."""
+    s = jnp.einsum(
+        "td,ed->te", h.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST)
+    return select(s, bias, top_k, scale, norm_topk, router)
+
+
+def select(s, bias, top_k, scale, norm_topk, router="sigmoid"):
+    """(chosen experts (T, k) int32, their weights (T, k) float32) from the
+    router's logits `s` (T, E), everything in float32.
 
     'sigmoid': sigmoid scores; the `top_k` experts of largest score +
     bias; weights from the scores WITHOUT the bias, normalised over the
@@ -195,9 +296,6 @@ def route(h, router_w, bias, top_k, scale, norm_topk, router="sigmoid"):
     (`norm_topk`; the same numbers as a softmax over all experts
     renormalised over the chosen) or, without `norm_topk`, the chosen
     experts' part of a softmax over all; times `scale`."""
-    s = jnp.einsum(
-        "td,ed->te", h.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST)
     if router == "sigmoid":
         s = jax.nn.sigmoid(s)
     elif router != "softmax_topk":
@@ -414,28 +512,40 @@ _MOE_ARGS = [Arg("num_experts", int, required=True),
              Arg("router", str, "sigmoid"), Arg("activation", str, "silu")]
 
 
-def _moe(p, x, routed_by, router_w, bias, gate_w, up_w, down_w, load):
-    """`moe_ffn`'s body: the experts read `x`, the router `routed_by`."""
+def _moe(p, x, routed_by, router_w, bias, gate_w, up_w, down_w, load,
+         skip=False):
+    """The expert ops' body: the experts read `x`, the router `routed_by`
+    (scored by `router_w`, or, where that is None, the router's logits
+    (..., num_experts) themselves).  `skip`: the last of the `num_experts`
+    choices is no expert at all, held nowhere: a token that takes it adds
+    nothing, and `load` has a slot for it."""
     E, k, first, held = (p["num_experts"], p["top_k"], p["first"],
                          p["held"])
-    if gate_w.shape[0] != held or not 0 <= first <= E - held:
+    if gate_w.shape[0] != held or not 0 <= first <= E - skip - held:
         raise ValueError(f"moe_ffn: held={held} first={first} of {E} "
                          f"experts against {gate_w.shape[0]} stacked")
     if p["activation"] not in ACTIVATIONS:
         raise ValueError(f"moe_ffn activation={p['activation']!r}: choose "
                          f"one of {sorted(ACTIVATIONS)}")
-    if load.shape != (held + 2,):
-        raise ValueError(f"moe_ffn: load must be ({held + 2},): each held "
-                         f"expert's rows, absent ones', the buffer's; got "
-                         f"{load.shape}")
+    if load.shape != (held + 2 + skip,):
+        raise ValueError(
+            f"moe_ffn: load must be ({held + 2 + skip},): each held "
+            f"expert's rows, absent ones', {'skipped ones, ' * skip}the "
+            f"buffer's; got {load.shape}")
     if routed_by.shape[:-1] != x.shape[:-1]:
         raise ValueError(f"moe_ffn: the router's input {routed_by.shape} "
                          f"and the experts' {x.shape} differ in tokens")
     h = x.reshape(-1, x.shape[-1])
     hr = h if routed_by is x else \
         routed_by.reshape(-1, routed_by.shape[-1])
-    idx, w = route(hr, router_w, bias, k, p["scale"], p["norm_topk"],
-                   p["router"])
+    if router_w is None:
+        if hr.shape[1] != E:
+            raise ValueError(f"moe_ffn: {hr.shape[1]} scores a token for "
+                             f"{E} choices")
+        idx, w = select(hr, bias, k, p["scale"], p["norm_topk"], p["router"])
+    else:
+        idx, w = route(hr, router_w, bias, k, p["scale"], p["norm_topk"],
+                       p["router"])
     local = idx - first
     key = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
     rows = key.shape[0]
@@ -457,6 +567,10 @@ def _moe(p, x, routed_by, router_w, bias, gate_w, up_w, down_w, load):
         y, sizes = jax.checkpoint(functools.partial(
             _expert_rows, act=ACTIVATIONS[p["activation"]], buffer=rows))(
                 h, key, w, gate_w, up_w, down_w)
+    if skip:  # of the rows held nowhere, those that chose no expert
+        skipped = jnp.sum(idx == E - 1, dtype=sizes.dtype)
+        sizes = jnp.concatenate([sizes[:held], sizes[held:held + 1] - skipped,
+                                 skipped[None], sizes[held + 1:]])
     new_load = load + sizes.astype(load.dtype)
     return (y.reshape(x.shape), lax.stop_gradient(bias),
             lax.stop_gradient(new_load))
@@ -510,6 +624,198 @@ def _moe_ffn_routed_by(p, x, routed_by, router_w, bias, gate_w, up_w, down_w,
     input here and the feed-forward's to `data`.  Everything else as
     `moe_ffn`."""
     return _moe(p, x, routed_by, router_w, bias, gate_w, up_w, down_w, load)
+
+
+@register("_contrib_moe_ffn_scored",
+          input_names=("data", "scores", "select_bias", "gate_weight",
+                       "up_weight", "down_weight", "load"),
+          aliases=("moe_ffn_scored",), aux_inputs=[2, 6], f32_inputs=(2, 6),
+          args=_MOE_ARGS + [Arg("skip", bool, False)])
+def _moe_ffn_scored(p, x, scores, bias, gate_w, up_w, down_w, load):
+    """`moe_ffn` for a layer whose router is not one matrix: `scores`
+    (..., num_experts), as many tokens as `data`, are the router's logits
+    as the block computed them (an MLP router), and the op routes by them
+    as `moe_ffn` routes by its own product ('softmax_topk' without
+    `norm_topk`: the chosen experts' softmax probability over all choices,
+    not renormalised).  `skip`: the last of the `num_experts` choices is a
+    skip, held on no chip, which adds nothing; `load` is then (held + 3,):
+    each held expert's rows, absent experts', skipped tokens', the
+    buffer's.  Everything else as `moe_ffn`."""
+    return _moe(p, x, scores, None, bias, gate_w, up_w, down_w, load,
+                skip=p["skip"])
+
+
+def _mlp_router(p, x, state, down_w, down_b, scale, gamma, w1, w2, w3):
+    """(logits (..., E), the layer's router state (..., R)), float32."""
+    f32 = jnp.float32
+
+    def mm(a, w):
+        return jnp.einsum("...i,oi->...o", a, w.astype(f32),
+                          precision=lax.Precision.HIGHEST)
+
+    rho = mm(x.astype(f32), down_w) + down_b.astype(f32)
+    if state is not None:
+        rho = rho + scale.astype(f32) * state.astype(f32)
+    hid = rho * lax.rsqrt(jnp.mean(jnp.square(rho), axis=-1, keepdims=True)
+                          + p["eps"]) * gamma.astype(f32)
+    hid = jax.nn.gelu(mm(hid, w1), approximate=False)
+    hid = jax.nn.gelu(mm(hid, w2), approximate=False)
+    return mm(hid, w3), rho
+
+
+_ROUTER_ARGS = [Arg("eps", float, 1e-5)]
+
+
+@register("_contrib_mlp_router",
+          input_names=("data", "down_weight", "down_bias", "norm_gamma",
+                       "w1", "w2", "w3"),
+          aliases=("mlp_router",), num_outputs=2, args=_ROUTER_ARGS)
+def _mlp_router_first(p, x, down_w, down_b, gamma, w1, w2, w3):
+    """An MLP router, everything in float32 at "highest" precision:
+
+        rho = x W_down + b_down            (the layer's router state, R wide)
+        logits = W3 gelu(W2 gelu(W1 rmsnorm(rho)))      (gelu: the erf form)
+
+    data (..., D); down_weight (R, D); down_bias, norm_gamma (R,); w1, w2
+    (R, R); w3 (E, R).  Returns (logits (..., E), rho (..., R)), the state
+    the next layer's router (`mlp_router_carried`) adds to its own."""
+    return _mlp_router(p, x, None, down_w, down_b, None, gamma, w1, w2, w3)
+
+
+@register("_contrib_mlp_router_carried",
+          input_names=("data", "state", "down_weight", "down_bias",
+                       "state_scale", "norm_gamma", "w1", "w2", "w3"),
+          aliases=("mlp_router_carried",), num_outputs=2, args=_ROUTER_ARGS)
+def _mlp_router_carried(p, x, state, down_w, down_b, scale, gamma, w1, w2,
+                        w3):
+    """`mlp_router` of a layer after the first: the state it hands on is
+    its own plus `state_scale` (R,) times the previous layer's (`state`,
+    (..., R), before that layer's norm):
+
+        rho = x W_down + b_down + state_scale * state"""
+    return _mlp_router(p, x, state, down_w, down_b, scale, gamma, w1, w2, w3)
+
+
+# rows of the output matrix one block of the blocked cross-entropy
+# multiplies: a block's float32 logits at 4,096 tokens are 134 MB
+CE_BLOCK = 8192
+
+
+def _ce_blocks(vocab, block):
+    """(blocks, start of block i): the last block is moved back to end at
+    `vocab`, so every block is `block` rows; its columns an earlier block
+    took are masked."""
+    n = -(-vocab // block)
+    return n, lambda i: jnp.minimum(i * block, vocab - block)
+
+
+def _ce_logits(h, w, start, block):
+    """(N, block) float32 logits of rows start.. of `w`, the product in the
+    operands' dtype with float32 sums."""
+    wb = lax.dynamic_slice_in_dim(w, start, block, axis=0)
+    return wb, lax.dot_general(h, wb, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _ce_forward(h, w, label, block):
+    """(loss (N,), log-sum-exp (N,)), float32, one block of logits alive."""
+    n, start_of = _ce_blocks(w.shape[0], block)
+
+    def body(i, carry):
+        top, total, picked = carry
+        start = start_of(i)
+        _wb, z = _ce_logits(h, w, start, block)
+        col = start + jnp.arange(block, dtype=jnp.int32)
+        fresh = (col >= i * block)[None, :]
+        z = jnp.where(fresh, z, -jnp.inf)
+        new_top = jnp.maximum(top, jnp.max(z, axis=1))
+        total = total * jnp.exp(top - new_top) + jnp.sum(
+            jnp.exp(z - new_top[:, None]), axis=1)
+        picked = picked + jnp.sum(
+            jnp.where(fresh & (col[None, :] == label[:, None]), z, 0.0),
+            axis=1)
+        return new_top, total, picked
+
+    rows = h.shape[0]
+    top, total, picked = lax.fori_loop(
+        0, n, body, (jnp.full((rows,), -jnp.inf, jnp.float32),
+                     jnp.zeros((rows,), jnp.float32),
+                     jnp.zeros((rows,), jnp.float32)))
+    lse = top + jnp.log(total)
+    return lse - picked, lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _blocked_ce(h, w, label, block):
+    return _ce_forward(h, w, label, block)[0]
+
+
+def _blocked_ce_fwd(h, w, label, block):
+    loss, lse = _ce_forward(h, w, label, block)
+    # kept for the backward program (ops/registry.py RESIDUAL_NAME): the
+    # log-sum-exp, so that it does not walk the vocabulary twice, and the
+    # hidden states it was taken over.  Made again there, h can differ in
+    # its last bit (another fusion of the norm before it), and where the
+    # logits are large that shifts exp(z - lse) far from a probability
+    return loss, (checkpoint_name(h, RESIDUAL_NAME), w, label,
+                  checkpoint_name(lse, RESIDUAL_NAME))
+
+
+def _blocked_ce_bwd(block, res, g):
+    """Each block's logits made again, then dh and that block's rows of dW;
+    one block of logits alive at a time.  dW is written in W's dtype: each
+    row is written once (the rows of the last block that an earlier one
+    took get zeros added)."""
+    h, w, label, lse = res
+    n, start_of = _ce_blocks(w.shape[0], block)
+    g = g.astype(jnp.float32)
+
+    def body(i, carry):
+        dh, dw = carry
+        start = start_of(i)
+        wb, z = _ce_logits(h, w, start, block)
+        col = start + jnp.arange(block, dtype=jnp.int32)
+        dz = jnp.exp(z - lse[:, None]) - (col[None, :] == label[:, None])
+        dz = jnp.where((col >= i * block)[None, :], dz * g[:, None], 0.0)
+        dz = dz.astype(h.dtype)
+        dh = dh + jnp.dot(dz, wb, preferred_element_type=jnp.float32)
+        dwb = lax.dot_general(dz, h, (((0,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+        rows = lax.dynamic_slice_in_dim(dw, start, block, axis=0)
+        dw = lax.dynamic_update_slice_in_dim(
+            dw, rows + dwb.astype(dw.dtype), start, axis=0)
+        return dh, dw
+
+    dh, dw = lax.fori_loop(0, n, body, (
+        jnp.zeros(h.shape, jnp.float32), jnp.zeros_like(w)))
+    return dh.astype(h.dtype), dw, None
+
+
+_blocked_ce.defvjp(_blocked_ce_fwd, _blocked_ce_bwd)
+
+
+@register("_contrib_blocked_cross_entropy",
+          input_names=("data", "weight", "label"),
+          aliases=("blocked_cross_entropy",), f32_inputs=(2,))
+def _blocked_cross_entropy(p, h, w, label):
+    """Cross-entropy of the logits h W^T against `label`, never holding
+    them: data (..., D), weight (V, D) (an output head, or a tied
+    embedding), label (...) ids in [0, V) -> (...) float32, each token's
+    log-sum-exp over V less its label's logit.
+
+    Walks V in blocks of `CE_BLOCK` rows with a running max and sum in
+    float32; each block's product runs in the operands' dtype with float32
+    sums.  The backward pass makes each block's logits again for dh and
+    that block's rows of dW, so no more than one block of logits (N x
+    CE_BLOCK float32) is alive in either pass, against the N x V of a plain
+    log-softmax.  The log-sum-exp (N,) is kept between the two.  The
+    label's gradient is zero."""
+    lead = h.shape[:-1]
+    with jax.named_scope("blocked_cross_entropy"):
+        out = _blocked_ce(h.reshape(-1, h.shape[-1]), w,
+                          label.reshape(-1).astype(jnp.int32),
+                          min(CE_BLOCK, w.shape[0]))
+    return out.reshape(lead)
 
 
 @register("_contrib_exit_distribution", input_names=("gate", "mass"),
