@@ -171,26 +171,21 @@ class MoEFeedForward(HybridBlock):
 
     scored: the block has no router weight; its second input is the
     router's logits (..., num_experts), computed by the caller (an MLP
-    router), and `moe_ffn_scored` routes by them.  skip: the last of the
-    `num_experts` choices is a skip that no device holds; `load` is then
-    (held + 3,), a slot for the skipped tokens before the buffer's."""
+    router), and `moe_ffn_scored` routes by them; the last of the
+    `num_experts` choices is then a skip that no device holds, and `load`
+    is (held + 3,), a slot for the skipped tokens before the buffer's."""
 
     def __init__(self, dim, ffn_dim, num_experts, top_k, held_experts=None,
                  first_expert=0, shared_experts=1, routed_scale=1.0,
                  norm_topk=True, router="sigmoid", activation="silu",
-                 scored=False, skip=False, **kw):
+                 scored=False, **kw):
         super().__init__(**kw)
         held = num_experts if held_experts is None else held_experts
-        self._scored, self.skip_choice = scored, skip
+        self.scored = scored
         self._moe = dict(num_experts=num_experts, top_k=top_k,
                          first=first_expert, held=held, scale=routed_scale,
                          norm_topk=norm_topk, router=router,
                          activation=activation)
-        if scored:
-            self._moe["skip"] = skip
-        elif skip:
-            raise ValueError("MoEFeedForward: a skip choice needs the "
-                             "router's scores (scored=True)")
         with self.name_scope():
             if not scored:
                 self.router_weight = self.params.get(
@@ -205,7 +200,7 @@ class MoEFeedForward(HybridBlock):
             self.down_weight = self.params.get(
                 "down_weight", shape=(held, ffn_dim, dim))
             self.load = self.params.get(
-                "load", shape=(held + 2 + skip,), init="zeros",
+                "load", shape=(held + 2 + scored,), init="zeros",
                 grad_req="null", differentiable=False)
             self.shared = GatedFeedForward(
                 dim, ffn_dim * shared_experts, prefix="shared_") \
@@ -221,7 +216,7 @@ class MoEFeedForward(HybridBlock):
                        select_bias=None, gate_weight=None, up_weight=None,
                        down_weight=None, load=None):
         experts = (select_bias, gate_weight, up_weight, down_weight, load)
-        if self._scored:
+        if self.scored:
             y = F.moe_ffn_scored(x, routed_by, *experts, **self._moe)
         elif routed_by is None:
             y = F.moe_ffn(x, router_weight, *experts, **self._moe)
@@ -642,7 +637,7 @@ class ZayaLM(HybridBlock):
             return MoEFeedForward(
                 dim, expert_ffn_dim, num_experts + 1, top_k, held_experts,
                 first_expert, shared_experts=0, norm_topk=False,
-                router="softmax_topk", scored=True, skip=True, prefix=prefix)
+                router="softmax_topk", scored=True, prefix=prefix)
 
         with self.name_scope():
             self.tok = nn.Embedding(vocab, dim, prefix="tok_")

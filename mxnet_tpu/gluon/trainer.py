@@ -366,9 +366,13 @@ class Trainer:
         gc = getattr(self._kv, "_gc", None)
         with span("mx.trainer.allreduce", cat="kvstore", mem=True), \
                 _memory.memory_scope("grad_bucket"):
-            flats = bk.flatten([g.handle for g in grads])
+            flats = bk.flatten([g.handle for g in grads], whole=gc is None)
             ctx = grads[0].context
-            buckets = [NDArray(f, ctx) for f in flats]
+            # a bucket of one gradient is that gradient's own array: its
+            # NDArray goes on, so the ledger does not count it twice
+            own = {id(g.handle): g for g in grads}
+            buckets = [own[id(f)] if id(f) in own else NDArray(f, ctx)
+                       for f in flats]
             if gc is not None:
                 if self._residuals is None:
                     self._residuals = self._init_residuals(bk)

@@ -338,10 +338,14 @@ class GradBucketer:
         self.sizes = tuple(sizes)
         lay, sig_ = self.layout, self.sig
 
-        def mx_kv_flatten(gs):
-            return [jnp.concatenate([gs[p].reshape(-1) for p in bucket])
-                    if len(bucket) > 1 else gs[bucket[0]].reshape(-1)
-                    for bucket in lay]
+        def flattener(buckets):
+            def mx_kv_flatten(gs):
+                return [jnp.concatenate([gs[p].reshape(-1) for p in bucket])
+                        if len(bucket) > 1 else gs[bucket[0]].reshape(-1)
+                        for bucket in buckets]
+            return mx_kv_flatten
+
+        mx_kv_flatten = flattener(lay)
 
         def mx_kv_unflatten(flats):
             out = [None] * len(sig_)
@@ -361,14 +365,36 @@ class GradBucketer:
         self.unflatten_inline = mx_kv_unflatten
         self._flatten = jax.jit(mx_kv_flatten)
         self._unflatten = jax.jit(mx_kv_unflatten)
+        # the buckets `flatten` copies: those of several gradients (and of
+        # a scalar's); a bucket of one array is that gradient itself
+        self._several = tuple(b for b, bucket in enumerate(lay)
+                              if len(bucket) > 1 or not sig_[bucket[0]][0])
+        self._flatten_several = jax.jit(
+            flattener([lay[b] for b in self._several]))
 
     @hot_path
-    def flatten(self, grads: List) -> List:
-        """Raw jax arrays in sig order -> flat bucket arrays (one dispatch)."""
+    def flatten(self, grads: List, whole: bool = True) -> List:
+        """Raw jax arrays in sig order -> the buckets, in one dispatch.  A
+        bucket of several gradients is one flat array; a bucket of one is
+        that gradient as it is, not copied: a view (bucket, 0, shape)
+        slices it over its size along the first axis and reshapes, which
+        reads the whole array.  At the chip's memory the copy of the large
+        gradients was what stalled a step (PERF.md, Open questions 24).
+        `whole=False` makes every bucket flat (the 2-bit reduce's flat
+        residuals need that)."""
         if _metrics.ENABLED:
-            _metrics.XLA_LAUNCHES.inc(kind="allreduce")
             _metrics.ALLREDUCE_BUCKETS.set(len(self.layout))
-        return self._flatten(grads)
+        if not whole:
+            if _metrics.ENABLED:
+                _metrics.XLA_LAUNCHES.inc(kind="allreduce")
+            return self._flatten(grads)
+        out = [grads[bucket[0]] for bucket in self.layout]
+        if self._several:
+            if _metrics.ENABLED:
+                _metrics.XLA_LAUNCHES.inc(kind="allreduce")
+            for b, flat in zip(self._several, self._flatten_several(grads)):
+                out[b] = flat
+        return out
 
     @hot_path
     def unflatten(self, flats: List) -> List:

@@ -807,7 +807,7 @@ def _read_states(blocks, attr):
 def refresh_moe() -> None:
     """Pull the load counters of every live expert layer (one stacked
     device read; a row is each held expert's assignments, absent ones',
-    skipped ones' where the layer has a skip choice, the buffer's rows)
+    skipped ones' where the layer is scored, the buffer's rows)
     into MOE_ASSIGNMENTS, MOE_BUFFER_ROWS and
     MOE_LOAD_MAX_OVER_MEAN."""
     import numpy as np
@@ -820,12 +820,14 @@ def refresh_moe() -> None:
         delta = row - last if last is not None and (row >= last).all() \
             else row
         _moe_layers[block] = row
-        # a layer with a skip choice has one slot more, before the buffer's
-        held = row.shape[0] - 2 - bool(getattr(block, "skip_choice", False))
+        # a scored layer has a skip choice: one slot more, before the
+        # buffer's
+        skip = bool(getattr(block, "scored", False))
+        held = row.shape[0] - 2 - skip
         live = float(delta[:held].sum())
         MOE_ASSIGNMENTS.inc(live, where="held")
         MOE_ASSIGNMENTS.inc(float(delta[held]), where="absent")
-        if held + 2 < row.shape[0]:
+        if skip:
             MOE_ASSIGNMENTS.inc(float(delta[held + 1]), where="skipped")
         MOE_BUFFER_ROWS.inc(live, kind="live")
         MOE_BUFFER_ROWS.inc(float(delta[-1]), kind="processed")

@@ -6,7 +6,7 @@ MLP router that carries a state from layer to layer, a mixture-of-experts
 feed-forward that is told which experts it holds and how its model routes
 (sigmoid scores with a selection bias, or a softmax over the chosen logits;
 a SiLU or a ReLU gate; the router reading the experts' input, a tensor of
-its own, or scores the block computed, with a skip choice or none), and a
+its own, or scores the block computed, whose last choice is a skip), and a
 cross-entropy over an output matrix that walks the vocabulary in blocks
 (`blocked_cross_entropy`).
 
@@ -512,15 +512,15 @@ _MOE_ARGS = [Arg("num_experts", int, required=True),
              Arg("router", str, "sigmoid"), Arg("activation", str, "silu")]
 
 
-def _moe(p, x, routed_by, router_w, bias, gate_w, up_w, down_w, load,
-         skip=False):
+def _moe(p, x, routed_by, router_w, bias, gate_w, up_w, down_w, load):
     """The expert ops' body: the experts read `x`, the router `routed_by`
     (scored by `router_w`, or, where that is None, the router's logits
-    (..., num_experts) themselves).  `skip`: the last of the `num_experts`
-    choices is no expert at all, held nowhere: a token that takes it adds
-    nothing, and `load` has a slot for it."""
+    (..., num_experts) themselves, whose last choice is a skip: no expert
+    at all, held nowhere; a token that takes it adds nothing, and `load`
+    has a slot for it)."""
     E, k, first, held = (p["num_experts"], p["top_k"], p["first"],
                          p["held"])
+    skip = router_w is None
     if gate_w.shape[0] != held or not 0 <= first <= E - skip - held:
         raise ValueError(f"moe_ffn: held={held} first={first} of {E} "
                          f"experts against {gate_w.shape[0]} stacked")
@@ -630,19 +630,18 @@ def _moe_ffn_routed_by(p, x, routed_by, router_w, bias, gate_w, up_w, down_w,
           input_names=("data", "scores", "select_bias", "gate_weight",
                        "up_weight", "down_weight", "load"),
           aliases=("moe_ffn_scored",), aux_inputs=[2, 6], f32_inputs=(2, 6),
-          args=_MOE_ARGS + [Arg("skip", bool, False)])
+          args=_MOE_ARGS)
 def _moe_ffn_scored(p, x, scores, bias, gate_w, up_w, down_w, load):
     """`moe_ffn` for a layer whose router is not one matrix: `scores`
     (..., num_experts), as many tokens as `data`, are the router's logits
     as the block computed them (an MLP router), and the op routes by them
     as `moe_ffn` routes by its own product ('softmax_topk' without
     `norm_topk`: the chosen experts' softmax probability over all choices,
-    not renormalised).  `skip`: the last of the `num_experts` choices is a
-    skip, held on no chip, which adds nothing; `load` is then (held + 3,):
-    each held expert's rows, absent experts', skipped tokens', the
-    buffer's.  Everything else as `moe_ffn`."""
-    return _moe(p, x, scores, None, bias, gate_w, up_w, down_w, load,
-                skip=p["skip"])
+    not renormalised).  The last of the `num_experts` choices is a skip,
+    held on no chip, which adds nothing; `load` is (held + 3,): each held
+    expert's rows, absent experts', skipped tokens', the buffer's.
+    Everything else as `moe_ffn`."""
+    return _moe(p, x, scores, None, bias, gate_w, up_w, down_w, load)
 
 
 def _mlp_router(p, x, state, down_w, down_b, scale, gamma, w1, w2, w3):

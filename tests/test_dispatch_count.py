@@ -497,6 +497,34 @@ def test_grad_bucketer_round_trip():
             np.asarray(flats[b][off:off + size]).reshape(shape), arrs[k])
 
 
+def test_grad_bucketer_hands_a_lone_gradient_on_uncopied():
+    """A bucket of one array is that gradient itself (no copy: at the
+    chip's memory the copy stalled the step); buckets of several, and a
+    scalar's, are flat; `whole=False` flattens every bucket, as the 2-bit
+    reduce needs; the views and unflatten read both forms alike."""
+    from mxnet_tpu.kvstore import GradBucketer
+    import jax.numpy as jnp
+    rs = np.random.RandomState(4)
+    arrs = [jnp.asarray(rs.normal(0, 1, s).astype("f"))
+            for s in [(6, 5), (3,), (2,), (4, 2, 3), ()]]
+    bk = GradBucketer([(a.shape, str(a.dtype)) for a in arrs],
+                      cap_bytes=40)
+    # (6, 5) and (4, 2, 3) alone; (3,) + (2,) together; the scalar alone
+    assert bk.layout == ((0,), (1, 2), (3,), (4,))
+    flats = bk.flatten(arrs)
+    assert flats[0] is arrs[0] and flats[2] is arrs[3]
+    assert flats[1].shape == (5,) and flats[3].shape == (1,)
+    assert all(f.ndim == 1 for f in bk.flatten(arrs, whole=False))
+    for got in (flats, bk.flatten(arrs, whole=False)):
+        for a, o in zip(arrs, bk.unflatten(got)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(o))
+        for k, (b, off, shape) in enumerate(bk.views):
+            size = int(np.prod(shape)) if shape else 1
+            np.testing.assert_array_equal(
+                np.asarray(got[b][off:off + size]).reshape(shape),
+                np.asarray(arrs[k]))
+
+
 def test_multi_bucket_fused_vs_legacy_agreement(monkeypatch):
     """A tiny MXNET_BUCKET_SIZE_MB forces one bucket per parameter —
     the multi-bucket allreduce path must agree with the legacy per-key

@@ -233,7 +233,7 @@ def _share(logits, g, gate, up, down, first, held):
     e1 = logits.shape[-1]
     p = {"num_experts": e1, "top_k": 1, "first": first, "held": held,
          "scale": 1.0, "norm_topk": False, "router": "softmax_topk",
-         "activation": "silu", "skip": True}
+         "activation": "silu"}
     sl = slice(first, first + held)
     y, _bias, load = ops._moe_ffn_scored(
         p, g, logits, jnp.zeros(e1), gate[sl], up[sl], down[sl],
@@ -272,7 +272,7 @@ def test_the_skip_lands_in_its_own_counter(monkeypatch):
     monkeypatch.setattr(metrics, "_moe_layers", weakref.WeakKeyDictionary())
     ffn = decoder.MoEFeedForward(D, 24, 5, 1, held_experts=2, shared_experts=0,
                                  norm_topk=False, router="softmax_topk",
-                                 scored=True, skip=True, prefix="f_")
+                                 scored=True, prefix="f_")
     ffn.initialize(mx.init.Normal(0.1), ctx=mx.cpu())
     assert ffn.load.shape == (5,)
     logits = np.zeros((2, 6, 5), "f")
