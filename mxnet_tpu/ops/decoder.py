@@ -32,7 +32,9 @@ chosen on the device; the longer is `T * top_k`.  Either
 way the rows are gathered straight from the tokens and each token reads
 its results back from the buffer, so the gathers, masks and casts around
 the products cost what the buffer costs, and nothing is as long as
-`T * top_k` rows of width D unless the buffer is.
+`T * top_k` rows of width D unless the buffer is.  A layer routed by
+scores with a skip (`moe_ffn_scored`) lays its buffer out so that the
+products visit the same tiles whatever the routing (`_steady_rows`).
 """
 from __future__ import annotations
 
@@ -323,16 +325,18 @@ def _sorted_rows(key, held):
     return order, sizes
 
 
-def _grouped_ffn(xs, groups, gate_w, up_w, down_w, act):
+def _grouped_ffn(xs, groups, gate_w, up_w, down_w, act, live=None):
     """The three grouped products (`act` on the gate's) over the ragged
-    split of the buffer `xs` whose first sum(groups) rows are live."""
+    split of the buffer `xs` whose rows flagged `live` (by default the
+    first sum(groups)) hold (token, choice) pairs."""
     # The tail of the buffer belongs to no held expert.  The TPU's grouped
     # product leaves rows it does not visit as they were in memory (read on
     # the chip: values up to 5 in the tail), on the way back too, so the
     # tail is cut off on both sides of the products: what comes out of them
     # there is not a result, and what flows back into them is not a gradient.
-    live = (jnp.arange(xs.shape[0], dtype=jnp.int32)
-            < jnp.sum(groups))[:, None]
+    if live is None:
+        live = jnp.arange(xs.shape[0], dtype=jnp.int32) < jnp.sum(groups)
+    live = live[:, None]
     xs = jnp.where(live, xs, jnp.zeros((), xs.dtype))
     mid = act(lax.ragged_dot(xs, gate_w, groups)) \
         * lax.ragged_dot(xs, up_w, groups)
@@ -439,6 +443,45 @@ def _expert_rows(h, key, w, gate_w, up_w, down_w, act=jax.nn.silu, *,
     ys = _grouped_ffn(_token_rows(h, order, slot, live), sizes[:held],
                       gate_w, up_w, down_w, act)
     return _combine(ys, w, order, slot, live), jnp.append(sizes, buffer)
+
+
+def steady_buffer(rows, held):
+    """The buffer of `_steady_rows`: the `rows` pairs and a spare row for
+    each held expert, in whole tiles of the grouped product."""
+    return -(-(rows + held) // GROUPED_TILE_ROWS) * GROUPED_TILE_ROWS
+
+
+def _steady_rows(h, key, w, gate_w, up_w, down_w, act=jax.nn.silu, *,
+                 buffer):
+    """`_expert_rows` over a buffer whose grouped products cost the same
+    whatever the routing: each held expert's rows are followed by one spare
+    row, so no group is empty and each of the held - 1 boundaries between
+    groups falls inside a tile (on a tile's edge once in 512), and the last
+    group runs to the end of the buffer, `steady_buffer(rows, held)` rows.
+    The products then visit every tile and one more a boundary.  On the
+    ragged split of `_expert_rows` the tiles visited follow the rows the
+    held experts got and how many groups are empty: one layer of the zaya
+    cell's shape took 2.9 to 5.0 ms forward and backward over fifteen
+    routings, this layout 5.4 (TPU v5 lite, PERF.md section 6).  Spare and
+    tail rows are gaps: gathered from token 0 and cut off on both sides of
+    the products."""
+    held, top_k = gate_w.shape[0], w.shape[1]
+    rows = key.shape[0]
+    order, sizes = _sorted_rows(key, held)
+    k = key[order]
+    # sorted row j of held expert k sits k spare rows further on
+    place = jnp.where(k < held, jnp.arange(rows, dtype=jnp.int32) + k,
+                      buffer)
+    slot = jnp.zeros_like(order).at[order].set(
+        place, unique_indices=True).reshape(-1, top_k)
+    source = jnp.zeros((buffer,), jnp.int32).at[place].set(
+        order, mode="drop", unique_indices=True)
+    filled = jnp.zeros((buffer,), bool).at[place].set(True, mode="drop")
+    groups = (sizes[:held] + 1).at[held - 1].add(
+        buffer - held - jnp.sum(sizes[:held], dtype=sizes.dtype))
+    ys = _grouped_ffn(_token_rows(h, source, slot, buffer), groups,
+                      gate_w, up_w, down_w, act, live=filled)
+    return _combine(ys, w, source, slot, buffer), jnp.append(sizes, buffer)
 
 
 # The outputs of one layer's three grouped products are rows x (2 F + D)
@@ -551,22 +594,30 @@ def _moe(p, x, routed_by, router_w, bias, gate_w, up_w, down_w, load):
     rows = key.shape[0]
     required = rows * held / E
     _metrics.MOE_ROWS.set(required, kind="required")
-    # a product that skips the tiles its group sizes leave empty: the rows
-    # it visits in expectation under even routing, every held expert's
-    # split rounded out to whole tiles, never more than the buffer
-    _metrics.MOE_ROWS.set(
-        min(rows, required + held * GROUPED_TILE_ROWS), kind="multiplied")
-    kept = rows * (gate_w.shape[2] + up_w.shape[2] + down_w.shape[2]) \
+    if skip:
+        # one choice a token and about half of them held: the products
+        # visit every tile of the steady buffer and one more a boundary,
+        # one tile more than the most the ragged split can cost
+        experts, rungs = _steady_rows, (steady_buffer(rows, held),)
+        _metrics.MOE_ROWS.set(rungs[0] + (held - 1) * GROUPED_TILE_ROWS,
+                              kind="multiplied")
+    else:
+        experts, rungs = _expert_rows, buffer_rungs(rows, held, E)
+        # a product that skips the tiles its group sizes leave empty: the
+        # rows it visits in expectation under even routing, every held
+        # expert's split rounded out to whole tiles, never more than the
+        # buffer
+        _metrics.MOE_ROWS.set(
+            min(rows, required + held * GROUPED_TILE_ROWS), kind="multiplied")
+    experts = functools.partial(experts, act=ACTIVATIONS[p["activation"]])
+    kept = rungs[-1] * (gate_w.shape[2] + up_w.shape[2] + down_w.shape[2]) \
         * x.dtype.itemsize
     if kept > KEEP_BYTES_MAX:
-        y, sizes = _run_again_in_backward(
-            functools.partial(_expert_rows,
-                              act=ACTIVATIONS[p["activation"]]),
-            buffer_rungs(rows, held, E))(h, key, w, gate_w, up_w, down_w)
+        y, sizes = _run_again_in_backward(experts, rungs)(
+            h, key, w, gate_w, up_w, down_w)
     else:
         y, sizes = jax.checkpoint(functools.partial(
-            _expert_rows, act=ACTIVATIONS[p["activation"]], buffer=rows))(
-                h, key, w, gate_w, up_w, down_w)
+            experts, buffer=rungs[-1]))(h, key, w, gate_w, up_w, down_w)
     if skip:  # of the rows held nowhere, those that chose no expert
         skipped = jnp.sum(idx == E - 1, dtype=sizes.dtype)
         sizes = jnp.concatenate([sizes[:held], sizes[held:held + 1] - skipped,
@@ -639,8 +690,10 @@ def _moe_ffn_scored(p, x, scores, bias, gate_w, up_w, down_w, load):
     `norm_topk`: the chosen experts' softmax probability over all choices,
     not renormalised).  The last of the `num_experts` choices is a skip,
     held on no chip, which adds nothing; `load` is (held + 3,): each held
-    expert's rows, absent experts', skipped tokens', the buffer's.
-    Everything else as `moe_ffn`."""
+    expert's rows, absent experts', skipped tokens', the buffer's.  The
+    buffer is `steady_buffer(T * top_k, held)` rows, each held expert's
+    rows followed by a spare one (`_steady_rows`), so the grouped products
+    cost the same whatever the routing.  Everything else as `moe_ffn`."""
     return _moe(p, x, scores, None, bias, gate_w, up_w, down_w, load)
 
 

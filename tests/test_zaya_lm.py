@@ -267,6 +267,60 @@ def test_two_chips_shares_add_up_to_the_uncut_layer():
         assert load[:held].sum() + load[held] + load[held + 1] == 40
 
 
+_ROUTINGS = {"even": [6, 6, 6, 6], "one_expert": [30, 0, 0, 0],
+             "all_held": [10, 20, 5, 5], "none_held": [0, 0, 0, 0],
+             "two_empty": [0, 17, 0, 9]}
+
+
+@pytest.mark.parametrize("routing", sorted(_ROUTINGS))
+def test_steady_buffer_equals_the_ragged_split(routing, monkeypatch):
+    """`_steady_rows` gives what `_expert_rows` gives, outputs and every
+    gradient, whatever the routing: empty experts, every pair held, none
+    held.  Its groups are never empty and cover the whole buffer, so the
+    tiles the grouped products visit do not follow the routing."""
+    monkeypatch.setattr(ops, "GROUPED_TILE_ROWS", 8)
+    held, top_k, T, Dm, Fm = 4, 1, 40, 16, 24
+    counts = _ROUTINGS[routing]
+    rs = np.random.RandomState(len(routing))
+    key = np.concatenate([np.full(c, e) for e, c in enumerate(counts)]
+                         + [np.full(T * top_k - sum(counts), held)])
+    key = jnp.asarray(rs.permutation(key).astype("int32"))
+    h = jnp.asarray(rs.randn(T, Dm).astype("f"))
+    w = jnp.asarray(rs.rand(T, top_k).astype("f"))
+    mats = [jnp.asarray(rs.randn(held, a, b).astype("f") * 0.2)
+            for a, b in ((Dm, Fm), (Dm, Fm), (Fm, Dm))]
+    dy = jnp.asarray(rs.randn(T, Dm).astype("f"))
+    buffer = ops.steady_buffer(T * top_k, held)
+    assert buffer == 48 and buffer % 8 == 0
+
+    groups = []
+    grouped = ops._grouped_ffn
+    monkeypatch.setattr(ops, "_grouped_ffn", lambda xs, g, *a, **kw: (
+        groups.append(np.asarray(g)), grouped(xs, g, *a, **kw))[1])
+
+    def run(fn, **kw):
+        out, pull = jax.vjp(lambda h, w, *m: fn(h, key, w, *m, **kw)[0],
+                            h, w, *mats)
+        return (out,) + pull(dy), fn(h, key, w, *mats, **kw)[1]
+
+    with jax.default_matmul_precision("highest"):
+        got, sizes = run(ops._steady_rows, buffer=buffer)
+        steady_groups = groups[-1]
+        want, want_sizes = run(ops._expert_rows, buffer=T * top_k)
+    for a, b in zip(got, want):
+        _close(a, b, rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(sizes[:held + 1], want_sizes[:held + 1])
+    assert int(sizes[-1]) == buffer
+    # every group holds its expert's rows and a spare one; the last runs
+    # to the end of the buffer
+    assert (steady_groups[:-1] == np.asarray(counts[:-1]) + 1).all()
+    assert steady_groups.sum() == buffer and steady_groups.min() >= 1
+    if sum(counts):
+        assert np.abs(np.asarray(got[0])).max() > 0
+    else:
+        np.testing.assert_array_equal(got[0], 0.0)
+
+
 def test_the_skip_lands_in_its_own_counter(monkeypatch):
     import weakref
     monkeypatch.setattr(metrics, "_moe_layers", weakref.WeakKeyDictionary())
@@ -284,8 +338,11 @@ def test_the_skip_lands_in_its_own_counter(monkeypatch):
               for w in ("held", "absent", "skipped")}
     ffn(nd.array(np.random.RandomState(0).randn(2, 6, D).astype("f")),
         nd.array(logits))
+    # the last slot: the steady buffer's rows (12 pairs, 2 spare rows, one
+    # tile)
     np.testing.assert_array_equal(ffn.load.data().asnumpy(),
-                                  [2, 0, 3, 7, 12])
+                                  [2, 0, 3, 7, ops.steady_buffer(12, 2)])
+    assert ops.steady_buffer(12, 2) == ops.GROUPED_TILE_ROWS
     metrics.refresh_moe()
     got = {w: metrics.MOE_ASSIGNMENTS.get(where=w) - before[w]
            for w in before}
