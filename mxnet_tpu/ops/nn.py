@@ -11,11 +11,11 @@ SVMOutput).  Conv/matmul map directly onto the MXU via
 from __future__ import annotations
 
 import functools
-import itertools as _itertools
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax._src.lax.windowed_reductions import _select_and_scatter_add
 
 from ..base import Arg, MXNetError
 from .. import layout as _layout
@@ -212,42 +212,43 @@ def _pooling(p, x):
         window = (1, 1) + k
         strides = (1, 1) + stride
         padding = ((0, 0), (0, 0)) + tuple(lo_hi)
-    out = _pool_impl(p, x, n, sp, k, stride, lo_hi, window, strides,
-                     padding, cl)
+    out = _pool_impl(p, x, k, stride, lo_hi, window, strides, padding, cl)
     return out if pre_cl else (_layout.from_cl(out) if cl else out)
 
 
-def _pool_impl(p, x, n, sp, k, stride, lo_hi, window, strides, padding, cl):
+def _window_max(x, window, strides, padding):
+    lowest = (-jnp.inf if jnp.issubdtype(x.dtype, jnp.floating)
+              else jnp.iinfo(x.dtype).min)
+    return lax.reduce_window(x, jnp.asarray(lowest, x.dtype), lax.max,
+                             window, strides, padding)
+
+
+_max_pool = jax.custom_vjp(_window_max, nondiff_argnums=(1, 2, 3))
+
+
+def _max_pool_fwd(x, window, strides, padding):
+    return _window_max(x, window, strides, padding), x
+
+
+def _max_pool_bwd(window, strides, padding, x, g):
+    # each window's cotangent goes whole to its FIRST maximum in window
+    # order (`ge` keeps the earlier of equals): MXNet's pooling backward
+    # and jax.grad of reduce_window.  XLA's SelectAndScatter, which packs
+    # nothing into 64 bits, so the TPU takes it under jax_enable_x64 (the
+    # JVP of reduce_window(max), select_and_gather_add, it refuses).
+    return (_select_and_scatter_add(g, x, lax.ge_p, window, strides,
+                                    padding),)
+
+
+_max_pool.defvjp(_max_pool_fwd, _max_pool_bwd)
+
+
+def _pool_impl(p, x, k, stride, lo_hi, window, strides, padding, cl):
     if p["pool_type"] == "max":
-        # Patch-stack max instead of lax.reduce_window(max): the
-        # select_and_gather_add gradient packs values into 64-bit pairs,
-        # which the TPU backend rejects under jax_enable_x64; static
-        # strided slices + reduce_max differentiate cleanly and XLA
-        # fuses them.
-        init = -jnp.inf if jnp.issubdtype(x.dtype, jnp.floating) \
-            else jnp.iinfo(x.dtype).min
-        vol = 1
-        for ki in k:
-            vol *= ki
-        if vol > 64:
-            # large kernels (SPP-style): patch-stack would emit vol slices
-            # and a vol-times-output buffer; fall back to reduce_window
-            # (grad unsupported on TPU+x64, but these never appear in
-            # trained backbones)
-            return lax.reduce_window(x, jnp.asarray(init, x.dtype), lax.max,
-                                     window, strides, padding)
-        xp = jnp.pad(x, padding, constant_values=jnp.asarray(init, x.dtype))
-        out_sz = [(xp.shape[sp + i] - k[i]) // stride[i] + 1
-                  for i in range(n)]
-        parts = []
-        for offs in _itertools.product(*[range(ki) for ki in k]):
-            spatial = tuple(
-                slice(offs[i], offs[i] + (out_sz[i] - 1) * stride[i] + 1,
-                      stride[i]) for i in range(n))
-            idx = (slice(None),) + spatial + (slice(None),) if cl \
-                else (slice(None), slice(None)) + spatial
-            parts.append(xp[idx])
-        return jnp.max(jnp.stack(parts), axis=0)
+        # an integer input has no gradient to give
+        pool = _max_pool if jnp.issubdtype(x.dtype, jnp.floating) \
+            else _window_max
+        return pool(x, window, strides, padding)
     denom = 1
     for d in k:
         denom *= d

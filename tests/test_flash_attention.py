@@ -377,6 +377,34 @@ def test_kernels_compile_for_the_v5e_at_the_cells_shapes(
     assert f"f32[{2 * H},1,{T}]" "{2,1,0:T(1,128)" in text    # B*H*T floats
 
 
+def test_stem_max_pool_compiles_for_the_v5e_without_a_stack(one_v5e_chip):
+    """The ResNet stem's pooling (3 x 3, stride 2, pad 1, bfloat16, NCHW) at
+    a thirty-second of the cells' batch: forward and backward are one
+    reduce-window and one select-and-scatter, and the temporaries stay
+    under twice the input's bytes, where a nine-way stack of the padded
+    windows held six times them.  This file describes the chip for every
+    test that needs it (one process may load the TPU's library)."""
+    from mxnet_tpu.ops.registry import get_op
+    pool = get_op("Pooling")
+    p = dict(pool.normalize(dict(kernel=(3, 3), stride=(2, 2), pad=(1, 1),
+                                 pool_type="max")))
+
+    def step(x, g):
+        y, pull = jax.vjp(lambda a: pool.fn(p, a), x)
+        return y, pull(g)[0]
+
+    x = jax.ShapeDtypeStruct((8, 64, 112, 112), jnp.bfloat16,
+                             sharding=one_v5e_chip)
+    g = jax.ShapeDtypeStruct((8, 64, 56, 56), jnp.bfloat16,
+                             sharding=one_v5e_chip)
+    compiled = jax.jit(step).lower(x, g).compile()
+    text = compiled.as_text()
+    assert text.count(" select-and-scatter(") == 1
+    assert text.count(" reduce-window(") == 1
+    x_bytes = 8 * 64 * 112 * 112 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * x_bytes
+
+
 @pytest.mark.parametrize("BH, D", [(64, 64), (40, 256)])
 def test_tile_rule_at_the_cells_shapes(BH, D):
     """T 2048 in bfloat16 at the two cells' head sizes: divisors of T,

@@ -217,6 +217,43 @@ def test_fc_grad_consistency():
                                    err_msg=k)
 
 
+def test_stem_max_pool_grad_consistency():
+    """The ResNet stem's pooling (3 x 3, stride 2, pad 1) in bfloat16 at
+    (8, 64, 112, 112) after a ReLU, so that many windows tie at zero:
+    values and input gradient on the chip are the CPU's, and both are a
+    float32 `lax.reduce_window`'s under `jax.vjp` (each window's cotangent
+    whole at its first maximum) to bfloat16's rounding."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    net = sym.Pooling(v(), kernel=(3, 3), stride=(2, 2), pad=(1, 1),
+                      pool_type="max", name="pool")
+    rs = np.random.RandomState(0)
+    x = np.maximum(rs.normal(0, 1, (8, 64, 112, 112)), 0).astype("f")
+    g = rs.normal(0, 1, (8, 64, 56, 56)).astype("f")
+    results = []
+    for ctx in (mx.cpu(), _accel()):
+        exe = net.simple_bind(ctx, grad_req="write",
+                              type_dict={"data": "bfloat16"}, data=x.shape)
+        exe.arg_dict["data"][:] = x
+        exe.forward(is_train=True)
+        exe.backward([mx.nd.array(g, ctx=ctx, dtype="bfloat16")])
+        results.append((exe.outputs[0].asnumpy().astype("f"),
+                        exe.grad_dict["data"].asnumpy().astype("f")))
+    (y_a, dx_a), (y_b, dx_b) = results
+    np.testing.assert_array_equal(y_a, y_b)
+    np.testing.assert_allclose(dx_a, dx_b, rtol=TOL, atol=TOL)
+    seen = [mx.nd.array(a, dtype="bfloat16").asnumpy().astype("f")
+            for a in (x, g)]
+    with jax.default_device(jax.devices("cpu")[0]):
+        y_ref, pull = jax.vjp(lambda a: lax.reduce_window(
+            a, -jnp.inf, lax.max, (1, 1, 3, 3), (1, 1, 2, 2),
+            ((0, 0), (0, 0), (1, 1), (1, 1))), jnp.asarray(seen[0]))
+        dx_ref = np.asarray(pull(jnp.asarray(seen[1]))[0])
+    np.testing.assert_array_equal(y_b, np.asarray(y_ref))
+    np.testing.assert_allclose(dx_b, dx_ref, rtol=TOL, atol=TOL)
+
+
 def test_resnet50_fwd_bwd_consistency():
     """The flagship: ResNet-50 forward loss and parameter grads on the
     real chip match the CPU reference — with the chip's matmuls at full
